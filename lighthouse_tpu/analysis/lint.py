@@ -212,6 +212,7 @@ _STATE_TRANSITION_CALLS = {
     "process_chain_segment",
     "process_attestation_batch",
     "process_aggregate",
+    "process_aggregate_batch",
     "process_voluntary_exit",
     "process_proposer_slashing",
     "process_attester_slashing",

@@ -182,9 +182,11 @@ class AttestationVerifier:
 
     # -- aggregated ----------------------------------------------------------
 
-    def verify_aggregated(self, signed_aggregate) -> VerifiedAggregatedAttestation:
-        """Three signature sets per aggregate: selection proof, aggregator
-        signature, aggregate attestation (batch.rs:78-108)."""
+    def build_aggregated(self, signed_aggregate):
+        """Every gossip check of an aggregate except its signatures:
+        returns (VerifiedAggregatedAttestation, its three signature sets —
+        selection proof, aggregator signature, aggregate attestation,
+        batch.rs:78-108)."""
         chain = self.chain
         message = signed_aggregate.message
         aggregate = message.aggregate
@@ -230,14 +232,49 @@ class AttestationVerifier:
                 state, indexed, chain.spec, chain.E
             ),
         ]
-        if not bls.verify_signature_sets(sets):
-            raise AttestationError("invalid aggregate signatures")
-        chain.observed_aggregators.observe(
-            data.target.epoch, message.aggregator_index
-        )
-        return VerifiedAggregatedAttestation(
+        verified = VerifiedAggregatedAttestation(
             signed_aggregate=signed_aggregate, indexed_attestation=indexed
         )
+        return verified, sets
+
+    def _observe_aggregator(self, verified):
+        message = verified.signed_aggregate.message
+        self.chain.observed_aggregators.observe(
+            message.aggregate.data.target.epoch, message.aggregator_index
+        )
+
+    def batch_verify_aggregated(self, signed_aggregates) -> list:
+        """One RLC batch across every aggregate's three signature sets
+        (batch.rs batch_verify_aggregated_attestations); on failure, each
+        aggregate's sets are verified alone to find the bad ones. Returns a
+        list of VerifiedAggregatedAttestation | Exception, one per input —
+        a rejected aggregate never drops the valid ones beside it.
+
+        The result equals verifying the aggregates one after another: an
+        aggregator is observed only once its aggregate verified, so a later
+        aggregate of the same aggregator is rejected as seen only if an
+        earlier one was accepted."""
+        prepared = []
+        results: list = [None] * len(signed_aggregates)
+        for i, agg in enumerate(signed_aggregates):
+            try:
+                prepared.append((i, *self.build_aggregated(agg)))
+            except Exception as e:  # noqa: BLE001 — per-item result
+                results[i] = e
+        every_set = [s for (_, _, sets) in prepared for s in sets]
+        batch_ok = bool(every_set) and bls.verify_signature_sets(every_set)
+        seen = self.chain.observed_aggregators
+        for i, verified, sets in prepared:
+            message = verified.signed_aggregate.message
+            epoch = message.aggregate.data.target.epoch
+            if seen.is_known(epoch, message.aggregator_index):
+                results[i] = AttestationError("aggregator already seen this epoch")
+            elif batch_ok or bls.verify_signature_sets(sets):
+                self._observe_aggregator(verified)
+                results[i] = verified
+            else:
+                results[i] = AttestationError("invalid aggregate signatures")
+        return results
 
 
 TARGET_AGGREGATORS_PER_COMMITTEE = 16

@@ -1135,13 +1135,25 @@ class BeaconChain:
         return results
 
     def process_aggregate(self, signed_aggregate):
-        verified = self.attestation_verifier.verify_aggregated(signed_aggregate)
+        """One aggregate: a batch of one; raises its rejection."""
+        (result,) = self.process_aggregate_batch([signed_aggregate])
+        if isinstance(result, Exception):
+            raise result
+        return result
+
+    def process_aggregate_batch(self, signed_aggregates) -> list:
+        """Verify aggregates as one signature batch, then apply each valid
+        one; returns a VerifiedAggregatedAttestation or the Exception per
+        input."""
+        results = self.attestation_verifier.batch_verify_aggregated(
+            signed_aggregates
+        )
         with self.import_lock.acquire_write():
-            self.apply_attestation_to_fork_choice(verified.indexed_attestation)
-            self.op_pool.insert_attestation(
-                signed_aggregate.message.aggregate
-            )
-        return verified
+            for agg, res in zip(signed_aggregates, results):
+                if not isinstance(res, Exception):
+                    self.apply_attestation_to_fork_choice(res.indexed_attestation)
+                    self.op_pool.insert_attestation(agg.message.aggregate)
+        return results
 
     def apply_attestation_to_fork_choice(self, indexed):
         if self.slasher_service is not None:

@@ -62,8 +62,10 @@ def cmd_beacon_node(args):
     import time
 
     from .client import ClientBuilder, ClientConfig
+    from .utils.compile_cache import enable_compile_cache
     from .utils.logging import get_logger
 
+    enable_compile_cache()  # before the first device kernel is traced
     log = get_logger("lighthouse_tpu.bn")
     spec, E = _load_spec(args.spec)
     from dataclasses import replace

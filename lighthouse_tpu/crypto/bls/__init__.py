@@ -64,9 +64,10 @@ SECRET_KEY_BYTES_LEN = 32
 RAND_BITS = 64
 
 # The ONE device-lane chunk default: both the node's `tpu` backend
-# (LIGHTHOUSE_TPU_BLS_CHUNK) and bench.py's BENCH_BLS_CHUNK read it. 32 is
-# the round-5 verdict value — the 128-chunk cold compile never fit a bench
-# window on the 1-core image; see BENCH_NOTES.md "Full-size BLS shapes".
+# (LIGHTHOUSE_TPU_BLS_CHUNK) and bench.py's BENCH_BLS_CHUNK read it, and
+# the device verifier pads every smaller batch up to it, so one set of
+# compiled kernels serves every batch; see BENCH_NOTES.md "Full-size BLS
+# shapes".
 DEFAULT_DEVICE_CHUNK = 32
 
 INFINITY_PUBLIC_KEY = bytes([0xC0]) + bytes(47)
@@ -929,13 +930,11 @@ class _TpuBackend(_HostBackend):
     and the multi-pairing all on device. Batches are processed in
     bounded-shape chunks (LIGHTHOUSE_TPU_BLS_CHUNK, default
     DEFAULT_DEVICE_CHUNK = 32 — the same value bench.py's BENCH_BLS_CHUNK
-    defaults to) so kernel compiles stay minutes, not hours, and the
-    compile cache is reused across batch sizes. Falls back — loudly,
-    once — to the partial device path (RLC scalar-muls + host pairing,
-    ops/bls381) and then to pure host on failure."""
+    defaults to) so every chunk reuses one set of compiled kernels. A
+    device failure raises: choosing the host is a configuration, never a
+    reaction to a failure."""
 
     name = "tpu"
-    _warned = False
 
     def verify_signature_sets(self, sets, rng=None) -> bool:
         import os as _os
@@ -943,37 +942,15 @@ class _TpuBackend(_HostBackend):
         sets = list(sets)
         if not sets:
             return super().verify_signature_sets(sets, rng)
-        try:
-            from ...ops import bls381 as device
-        except Exception:
-            device = None
-        if device is None or not getattr(device, "AVAILABLE", False):
-            return super().verify_signature_sets(sets, rng)
-        try:
-            from ...ops.bls381_verify import verify_signature_sets_device_full
+        from ...ops.bls381_verify import verify_signature_sets_device_full
 
-            chunk = int(
-                _os.environ.get(
-                    "LIGHTHOUSE_TPU_BLS_CHUNK", str(DEFAULT_DEVICE_CHUNK)
-                )
-            ) or len(sets)
-            for i in range(0, len(sets), chunk):
-                if not verify_signature_sets_device_full(
-                    sets[i:i + chunk], rng
-                ):
-                    return False
-            return True
-        except Exception as e:  # noqa: BLE001 — e.g. remote-compile failure
-            if not _TpuBackend._warned:
-                _TpuBackend._warned = True
-                from ...utils.logging import get_logger
-
-                get_logger("lighthouse_tpu.bls").warning(
-                    "full device BLS path failed; falling back to the "
-                    "partial device path",
-                    error=str(e)[:200],
-                )
-            return device.verify_signature_sets_device(sets, rng)
+        chunk = int(
+            _os.environ.get("LIGHTHOUSE_TPU_BLS_CHUNK", str(DEFAULT_DEVICE_CHUNK))
+        ) or len(sets)
+        for i in range(0, len(sets), chunk):
+            if not verify_signature_sets_device_full(sets[i:i + chunk], rng):
+                return False
+        return True
 
 
 _BACKENDS = {

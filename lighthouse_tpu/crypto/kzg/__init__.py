@@ -107,7 +107,8 @@ def fft_fr(coeffs: list[int], inverse: bool = False) -> list[int]:
 # Trusted setup
 # ---------------------------------------------------------------------------
 
-_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+# the insecure dev setup is derived once and kept beside the checkout
+_SETUP_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".kzg_cache"
 
 
 class TrustedSetup:
@@ -131,13 +132,19 @@ class TrustedSetup:
     @classmethod
     def from_json(cls, path: str | os.PathLike) -> "TrustedSetup":
         """Standard trusted_setup.json: hex g1_lagrange (48B compressed) +
-        g2_monomial (96B compressed)."""
+        g2_monomial (96B compressed). The file lists the Lagrange points
+        in natural domain order; they are kept bit-reversal permuted, as
+        the spec's `blob_to_kzg_commitment` uses them
+        (`bit_reversal_permutation(KZG_SETUP_G1_LAGRANGE)`) and as the
+        evaluation domain `roots_brp` is laid out."""
         with open(path) as f:
             data = json.load(f)
-        g1 = [
-            g1_from_bytes(bytes.fromhex(h.removeprefix("0x")))
-            for h in data["g1_lagrange"]
-        ]
+        g1 = _bit_reverse_permute(
+            [
+                g1_from_bytes(bytes.fromhex(h.removeprefix("0x")))
+                for h in data["g1_lagrange"]
+            ]
+        )
         g2 = [
             g2_from_bytes(bytes.fromhex(h.removeprefix("0x")))
             for h in data["g2_monomial"][:2]
@@ -148,7 +155,7 @@ class TrustedSetup:
     def insecure_dev(cls, n: int = FIELD_ELEMENTS_PER_BLOB) -> "TrustedSetup":
         """Deterministic dev setup with a KNOWN tau — full functionality,
         zero security. Disk-cached (affine ints) for instant reload."""
-        cache = _CACHE_DIR / f"kzg_dev_setup_{n}.json"
+        cache = _SETUP_CACHE_DIR / f"kzg_dev_setup_{n}.json"
         if cache.exists():
             try:
                 with open(cache) as f:
@@ -182,7 +189,7 @@ class TrustedSetup:
         g1 = [pt_mul(FQ, G1_GEN, s) for s in lag_brp]
         g2 = [G2_GEN, pt_mul(FQ2, G2_GEN, tau)]
         try:
-            _CACHE_DIR.mkdir(exist_ok=True)
+            _SETUP_CACHE_DIR.mkdir(exist_ok=True)
             with open(cache, "w") as f:
                 json.dump(
                     {
@@ -309,8 +316,7 @@ def _g1_msm(scalars: list[int], points: list, window: int = 8) -> tuple:
 class _DeviceKzg:
     """Lazy per-setup device residency: the Lagrange points and the
     bit-reversed domain live on device across calls; kernels come from
-    ops/{fr,msm,bls381_pairing}. Any failure marks the context dead and
-    the Kzg engine falls back to host (loudly, once)."""
+    ops/{fr,msm,bls381_pairing}."""
 
     def __init__(self, setup: TrustedSetup):
         self.setup = setup
@@ -345,8 +351,9 @@ class _DeviceKzg:
         from ...ops.fr import barycentric_eval_batch, fr_from_device, fr_to_device
 
         m = len(evals_lists)
-        # pad the blob axis to a power-of-two bucket: few compiled shapes
-        mb = 1
+        # pad the blob axis to a power-of-two bucket of at least a block's
+        # worth of blobs: single openings and block batches share a shape
+        mb = 8
         while mb < m:
             mb *= 2
         padded = list(evals_lists) + [evals_lists[0]] * (mb - m)
@@ -409,54 +416,16 @@ class Kzg:
         self.setup = setup if setup is not None else TrustedSetup.default()
         if device is None:
             device = os.environ.get("LIGHTHOUSE_TPU_DEVICE_KZG") == "1"
-        self._dev: _DeviceKzg | None = None
-        self._dev_warned = False
-        if device:
-            try:
-                self._dev = _DeviceKzg(self.setup)
-            except Exception as e:  # noqa: BLE001 — e.g. remote-compile failure
-                self._device_fallback("init", e)
-
-    @staticmethod
-    def _strict_device() -> bool:
-        return os.environ.get("LIGHTHOUSE_TPU_STRICT_DEVICE") == "1"
-
-    def _device_fallback(self, stage: str, e: Exception):
-        """Device path failed: count it (a fallback must never be
-        invisible — a shape regression on the chip would otherwise
-        silently turn TPU-native DA into host bigint math), log once, and
-        under LIGHTHOUSE_TPU_STRICT_DEVICE=1 refuse to fall back at all."""
-        from ...metrics import inc_counter
-
-        inc_counter("kzg_device_fallback_total", stage=stage)
-        if self._strict_device():
-            self._dev = None
-            raise KzgError(
-                f"device KZG failed at {stage} and "
-                f"LIGHTHOUSE_TPU_STRICT_DEVICE=1 forbids host fallback: {e}"
-            ) from e
-        if not self._dev_warned:
-            self._dev_warned = True
-            from ...utils.logging import get_logger
-
-            get_logger("lighthouse_tpu.kzg").warning(
-                "device KZG path failed; falling back to host",
-                stage=stage,
-                error=str(e)[:200],
-            )
-        self._dev = None
+        # a device engine that fails raises: the host engine is chosen by
+        # configuration (device=False), never as a reaction to a failure
+        self._dev: _DeviceKzg | None = _DeviceKzg(self.setup) if device else None
 
     def _device_call(self, fn, *args):
-        """Run a device-path closure; on failure, disable the device path
-        (observably — see _device_fallback) and return None so callers
-        fall back to host."""
+        """Run a device-path closure, or return None (host engine) when no
+        device is configured. Device errors propagate."""
         if self._dev is None:
             return None
-        try:
-            return fn(self._dev, *args)
-        except Exception as e:  # noqa: BLE001 — e.g. remote-compile failure
-            self._device_fallback("call", e)
-            return None
+        return fn(self._dev, *args)
 
     # -- commitments ----------------------------------------------------------
 
@@ -622,7 +591,7 @@ class Kzg:
             zs.append(_fr_from_bytes(z))
             c_pts.append(g1_from_bytes(commitment))
             pi_pts.append(g1_from_bytes(proof))
-        # all evaluations in one fused device kernel (host fallback inside)
+        # all evaluations in one fused device kernel on the device engine
         ys = self._evaluate_many(evals_lists, zs)
         # spec verify_kzg_proof_batch: one r from the transcript, scalars are
         # its powers (polynomial-commitments.md; c-kzg byte-exact layout)

@@ -948,12 +948,8 @@ class BeaconApi:
         from ..ssz.core import List as SszList
 
         aggs = SszList[t.SignedAggregateAndProof, 1024].deserialize(data)
-        errors = []
-        for agg in aggs:
-            try:
-                self.chain.process_aggregate(agg)
-            except Exception as e:  # noqa: BLE001
-                errors.append(e)
+        results = self.chain.process_aggregate_batch(list(aggs))
+        errors = [r for r in results if isinstance(r, Exception)]
         if errors and len(errors) == len(aggs):
             raise ApiError(400, f"all aggregates rejected: {errors[0]}")
         return 200

@@ -43,9 +43,6 @@ R2 = (R_MONT * R_MONT) % P
 # N' = -p^{-1} mod R (full-width Montgomery constant)
 NPRIME = (-pow(P, -1, R_MONT)) % R_MONT
 
-AVAILABLE = True
-
-
 def int_to_limbs(x: int, n: int = NLIMB) -> np.ndarray:
     return np.array([(x >> (BASE * i)) & MASK for i in range(n)], dtype=np.int32)
 
@@ -61,7 +58,6 @@ _R2_LIMBS = int_to_limbs(R2)
 _ONE_MONT = int_to_limbs(R_MONT % P)  # 1 in Montgomery form
 # 2^384 - p (for branchless compare/subtract via complement addition)
 _PBAR_LIMBS = int_to_limbs(R_MONT - P)
-_ZERO = np.zeros(NLIMB, dtype=np.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -69,40 +65,42 @@ _ZERO = np.zeros(NLIMB, dtype=np.int32)
 # ---------------------------------------------------------------------------
 
 
-def _conv_full(a, b):
-    """Full product convolution: [..., N] × [..., N] → [..., 2N-1].
-    Outer product + anti-diagonal sums keeps everything MXU/VPU friendly."""
+def _conv(a, b, out_cols: int):
+    """Limb convolution c_k = Σ_i a_i·b_(k−i) for k < out_cols, as N
+    multiply-adds of a limb of `a` against a shifted copy of `b` (products
+    < 2^16, sums < N·2^16: exact in int32). On a v5e this beats summing
+    the [N, N] outer product's anti-diagonals by an int32 matmul against a
+    0/1 matrix (int32 is not an MXU type there): a [512, 48] Montgomery
+    product took 12.8 µs with all three of its convolutions done this way,
+    50.1 µs with all three by the 0/1 matmul (one TPU v5e chip). `_mont_mul`
+    uses it for the variable × variable product only; its two products by
+    a constant go through `_conv_const`."""
     n = a.shape[-1]
-    outer = a[..., :, None] * b[..., None, :]  # [..., N, N] int32 (fits: 2^16)
-    return _antidiagonal_sums(outer, 2 * n - 1)
-
-
-def _conv_low(a, b):
-    """Low-half convolution: product mod 2^(8N) — diagonals 0..N-1 only
-    (carries go strictly upward, so truncating before normalize is exact)."""
-    n = a.shape[-1]
-    outer = a[..., :, None] * b[..., None, :]
-    return _antidiagonal_sums(outer, n)
+    bp = jnp.pad(b, [(0, 0)] * (b.ndim - 1) + [(n - 1, out_cols)])
+    acc = a[..., :1] * bp[..., n - 1 : n - 1 + out_cols]
+    for i in range(1, n):
+        acc = acc + a[..., i : i + 1] * bp[..., n - 1 - i : n - 1 - i + out_cols]
+    return acc
 
 
 @functools.cache
-def _adiag_matrix(n: int, out_cols: int) -> np.ndarray:
-    """[N*N, out_cols] 0/1 matrix mapping outer-product entries to
-    diagonals (out_cols < 2N-1 truncates to the low diagonals — a mod-2^(8c)
-    product). Cached as numpy — a jnp constant cached across traces would
-    leak tracers."""
-    m = np.zeros((n * n, out_cols), dtype=np.int32)
+def _toeplitz(limbs: tuple, out_cols: int) -> np.ndarray:
+    """[N, out_cols] int32 T with T[i, i+j] = limbs[j]: x @ T convolves x
+    with the constant limbs, truncated to out_cols (a mod-2^(8·out_cols)
+    product when out_cols ≤ N). Cached as numpy — a jnp constant cached
+    across traces would leak tracers."""
+    n = len(limbs)
+    t = np.zeros((n, out_cols), dtype=np.int32)
     for i in range(n):
-        for j in range(n):
+        for j, v in enumerate(limbs):
             if i + j < out_cols:
-                m[i * n + j, i + j] = 1
-    return m
+                t[i, i + j] = v
+    return t
 
 
-def _antidiagonal_sums(outer, out_cols: int):
-    n = outer.shape[-1]
-    flat = outer.reshape(*outer.shape[:-2], n * n)
-    return flat @ jnp.asarray(_adiag_matrix(n, out_cols))  # int32 matmul
+def _conv_const(x, limbs: np.ndarray, out_cols: int):
+    """Convolution with a constant operand: one small int32 matmul."""
+    return x @ jnp.asarray(_toeplitz(tuple(int(v) for v in limbs), out_cols))
 
 
 def _shift_carries(v):
@@ -114,22 +112,39 @@ def _shift_carries(v):
     )
 
 
+_CHUNK = 24  # limb carry bits packed per int32 word (sums stay < 2^25)
+
+
 def _resolve_carries(v):
-    """Exact normalization for limbs in [0, 256]: carry-lookahead via an
-    associative (generate, propagate) scan over the limb axis — a chain of
-    255s before a 256 resolves in one log-depth pass instead of O(n)
-    ripple passes."""
-    g = v >= 256  # generates a carry
-    p = v == 255  # propagates an incoming carry
+    """Exact normalization for limbs in [0, 256] by one integer addition.
 
-    def combine(a, b):
-        # a is closer to the LSB; carry out of the pair = b.g | (b.p & a.g)
-        return (b[0] | (b[1] & a[0]), a[1] & b[1])
+    A limb generates a carry when it is 256 and propagates one when it is
+    255: the carry pattern of the binary sum X + Y with x_i = g_i | p_i and
+    y_i = g_i. The limb bits are packed 24 to an int32 word, the words are
+    added with their carries rippled word to word, and (X + Y) ^ X ^ Y
+    unpacks to the carry into every limb — a chain of 255s resolves in a
+    constant number of ops (no log-depth scan, no O(n) ripple)."""
+    n = v.shape[-1]
+    k = -(-n // _CHUNK)
+    g = v >> BASE
+    x = g | (v == MASK).astype(v.dtype)
+    pad = [(0, 0)] * (v.ndim - 1) + [(0, k * _CHUNK - n)]
+    shifts = jnp.arange(_CHUNK, dtype=v.dtype)
 
-    G, _ = lax.associative_scan(combine, (g, p), axis=-1)
-    carry_in = jnp.concatenate(
-        [jnp.zeros_like(G[..., :1]), G[..., :-1]], axis=-1
-    ).astype(v.dtype)
+    def pack(bits):
+        bits = jnp.pad(bits, pad).reshape(*v.shape[:-1], k, _CHUNK)
+        return jnp.sum(bits << shifts, axis=-1)
+
+    xw, yw = pack(x), pack(g)
+    sums = []
+    cin = jnp.zeros_like(xw[..., 0])
+    for c in range(k):
+        s = xw[..., c] + yw[..., c] + cin
+        sums.append(s)
+        cin = s >> _CHUNK
+    carries = jnp.stack(sums, axis=-1) ^ xw ^ yw
+    carry_in = (carries[..., None] >> shifts) & 1
+    carry_in = carry_in.reshape(*v.shape[:-1], k * _CHUNK)[..., :n]
     return (v + carry_in) & MASK
 
 
@@ -149,31 +164,59 @@ def _carry_normalize(x, out_len: int, shrink_passes: int = 3):
     return _resolve_carries(v)
 
 
-def _cond_sub_p(x):
-    """x normalized, value in [0, 2p) → x mod p, branchless: s = x + (2^384
-    - p); bit 384 of s is set iff x ≥ p, and then s's low 384 bits are x-p."""
-    s = x + jnp.asarray(_PBAR_LIMBS)  # limbs ≤ 510
-    s = _carry_normalize(s, NLIMB + 1, shrink_passes=2)
-    ge = s[..., NLIMB] > 0
-    return jnp.where(ge[..., None], s[..., :NLIMB], x)
+def _cond_sub(x, mbar):
+    """x normalized, value in [0, 2m) → x mod m, branchless: s = x + (2^(8n)
+    - m); bit 8n of s is set iff x ≥ m, and then s's low limbs are x - m."""
+    n = x.shape[-1]
+    s = _carry_normalize(x + mbar, n + 1, shrink_passes=2)  # limbs ≤ 510
+    return jnp.where(s[..., n:] > 0, s[..., :n], x)
+
+
+def _mont_mul(a, b, m_limbs, nprime_limbs, mbar):
+    """Montgomery product a·b·R⁻¹ mod m over n-limb operands (R = 2^(8n)):
+    t = a·b; q = (t mod R)·N' mod R; u = (t + q·m)/R; conditional subtract.
+    t + q·m is normalized once from the raw convolution coefficients
+    (each < n·2^16, their sum < 2^23), and only t's low half is normalized
+    separately, to feed q."""
+    n = a.shape[-1]
+    t = _conv(a, b, 2 * n - 1)  # [..., 2n-1], coeffs < n·2^16
+    t_lo = _carry_normalize(t[..., :n], n)  # t mod R
+    q = _carry_normalize(_conv_const(t_lo, nprime_limbs, n), n)  # mod R
+    # t + q·m < 2Rm: fits 2n limbs; its low n limbs are zero by
+    # construction of q, so /R is a limb shift.
+    s = _carry_normalize(t + _conv_const(q, m_limbs, 2 * n - 1), 2 * n)
+    return _cond_sub(s[..., n:], mbar)
+
+
+def _mod_add(a, b, mbar):
+    """(a + b) mod m: both candidates, a+b and a+b+(2^(8n)-m), normalized
+    in one stacked pass; bit 8n of the second says which one is reduced."""
+    n = a.shape[-1]
+    x = a + b
+    v = _carry_normalize(jnp.stack([x, x + mbar]), n + 1, shrink_passes=2)
+    return jnp.where(v[1, ..., n:] > 0, v[1, ..., :n], v[0, ..., :n])
+
+
+def _mod_sub(a, b, unit, unit_plus_m):
+    """(a − b) mod m via the complement: d = a + (2^(8n)−1−b) + 1 =
+    a − b + 2^(8n). Both d and d + m are normalized in one stacked pass;
+    bit 8n of d is set iff a ≥ b, and then d's low limbs are a − b."""
+    n = a.shape[-1]
+    d = a + (MASK - b)
+    v = _carry_normalize(
+        jnp.stack([d + unit, d + unit_plus_m]), n + 1, shrink_passes=2
+    )
+    return jnp.where(v[0, ..., n:] > 0, v[0, ..., :n], v[1, ..., :n])
+
+
+_UNIT = int_to_limbs(1)
+_UNIT_PLUS_P = _UNIT + _P_LIMBS  # limbwise; normalized with the sum
 
 
 def mont_mul(a, b):
     """Montgomery product a·b·R⁻¹ mod p. Inputs/outputs: [..., 48] int32,
     limbs < 2^8, value < p."""
-    t = _conv_full(a, b)  # [..., 95], coeffs < 48·2^16 < 2^22
-    t = _carry_normalize(t, 2 * NLIMB)  # 96 normalized limbs
-    t_lo = t[..., :NLIMB]
-    m = _conv_low(t_lo, jnp.asarray(_NPRIME_LIMBS))  # mod R: low half only
-    m = _carry_normalize(m, NLIMB)
-    mp = _carry_normalize(
-        _conv_full(m, jnp.asarray(_P_LIMBS)), 2 * NLIMB
-    )
-    # t + m·p < 2Rp < 2^767: fits 96 limbs; low 48 limbs are zero by
-    # construction of m, so /R is a limb shift.
-    s = _carry_normalize(t + mp, 2 * NLIMB, shrink_passes=2)
-    u = s[..., NLIMB:]
-    return _cond_sub_p(u)
+    return _mont_mul(a, b, _P_LIMBS, _NPRIME_LIMBS, jnp.asarray(_PBAR_LIMBS))
 
 
 def to_mont(x_limbs):
@@ -186,19 +229,11 @@ def from_mont(x_limbs):
 
 
 def mod_add(a, b):
-    v = _carry_normalize(a + b, NLIMB, shrink_passes=2)  # < 2p < 2^384
-    return _cond_sub_p(v)
+    return _mod_add(a, b, jnp.asarray(_PBAR_LIMBS))
 
 
 def mod_sub(a, b):
-    """a - b mod p via complement: a + (2^384 - b) + p - 2^384; the 2^384
-    bit of the normalized sum is always set (a-b+p ≥ 0), drop it."""
-    comp_b = MASK - b  # 2^384 - b = ~b + 1 (limbwise complement, +1 below)
-    v = a + comp_b + jnp.asarray(_P_LIMBS)
-    v = v.at[..., 0].add(1)
-    v = _carry_normalize(v, NLIMB + 1, shrink_passes=2)
-    # v = (a - b + p) + 2^384, and a-b+p < 2p < 2^384 ⇒ limb 48 == 1
-    return _cond_sub_p(v[..., :NLIMB])
+    return _mod_sub(a, b, jnp.asarray(_UNIT), jnp.asarray(_UNIT_PLUS_P))
 
 
 # ---------------------------------------------------------------------------
@@ -245,41 +280,48 @@ class DevFq:
 
 
 class DevFq2:
-    """Fq2 ops over [..., 2, 48] limb arrays (c0 + c1·u, u² = -1)."""
+    """Fq2 ops over [..., 2, 48] limb arrays (c0 + c1·u, u² = -1). Each op
+    works on both coefficients at once: one mont_mul per product (the
+    three Karatsuba products ride a stacked axis), one mod_add/mod_sub per
+    sum — a few large ops instead of many small ones keeps the compiled
+    program small."""
 
     @staticmethod
     def add(a, b):
-        return jnp.stack(
-            [mod_add(a[..., 0, :], b[..., 0, :]), mod_add(a[..., 1, :], b[..., 1, :])],
-            axis=-2,
-        )
+        return mod_add(a, b)
 
     @staticmethod
     def sub(a, b):
-        return jnp.stack(
-            [mod_sub(a[..., 0, :], b[..., 0, :]), mod_sub(a[..., 1, :], b[..., 1, :])],
-            axis=-2,
-        )
+        return mod_sub(a, b)
 
     @staticmethod
     def mul(a, b):
+        a, b = jnp.broadcast_arrays(a, b)
         a0, a1 = a[..., 0, :], a[..., 1, :]
         b0, b1 = b[..., 0, :], b[..., 1, :]
-        t0 = mont_mul(a0, b0)
-        t1 = mont_mul(a1, b1)
-        c0 = mod_sub(t0, t1)
-        cross = mont_mul(mod_add(a0, a1), mod_add(b0, b1))
-        c1 = mod_sub(mod_sub(cross, t0), t1)
-        return jnp.stack([c0, c1], axis=-2)
+        sums = mod_add(jnp.stack([a0, b0], -2), jnp.stack([a1, b1], -2))
+        t = mont_mul(
+            jnp.stack([a0, a1, sums[..., 0, :]], -2),
+            jnp.stack([b0, b1, sums[..., 1, :]], -2),
+        )
+        t0, t1, cross = t[..., 0, :], t[..., 1, :], t[..., 2, :]
+        d = mod_sub(jnp.stack([t0, cross], -2), jnp.stack([t1, t0], -2))
+        c1 = mod_sub(d[..., 1, :], t1)
+        return jnp.stack([d[..., 0, :], c1], -2)
 
     @staticmethod
     def sqr(a):
-        return DevFq2.mul(a, a)
+        """(a0+a1)(a0−a1) + 2·a0·a1·u."""
+        a0, a1 = a[..., 0, :], a[..., 1, :]
+        t = mont_mul(
+            jnp.stack([mod_add(a0, a1), a0], -2),
+            jnp.stack([mod_sub(a0, a1), a1], -2),
+        )
+        return jnp.stack([t[..., 0, :], mod_add(t[..., 1, :], t[..., 1, :])], -2)
 
     @staticmethod
     def neg(a):
-        zero = jnp.zeros_like(a)
-        return DevFq2.sub(zero, a)
+        return mod_sub(jnp.zeros_like(a), a)
 
     @staticmethod
     def zeros(shape):
@@ -294,56 +336,57 @@ class DevFq2:
         return jnp.where(c[..., None, None], a, b)
 
 
+def _stack(*xs):
+    return jnp.stack(jnp.broadcast_arrays(*xs), axis=0)
+
+
 # ---------------------------------------------------------------------------
 # Generic Jacobian point ops (branchless; infinity encoded as Z == 0)
 # ---------------------------------------------------------------------------
 
 
 def pt_double(F, pt):
+    """Jacobian doubling (dbl-2009-l). Independent products share one
+    stacked F.mul call: three multiplication stages in all."""
     x, y, z = pt
-    a = F.sqr(x)
-    b = F.sqr(y)
-    c = F.sqr(b)
-    d = F.sub(F.sub(F.sqr(F.add(x, b)), a), c)
-    d = F.add(d, d)
+    a, b, yz = F.mul(_stack(x, y, y), _stack(x, y, z))
+    xb = F.add(x, b)
     e = F.add(F.add(a, a), a)
-    f = F.sqr(e)
+    c, t, f = F.mul(_stack(b, xb, e), _stack(b, xb, e))
+    d = F.sub(F.sub(t, a), c)
+    d = F.add(d, d)
     x3 = F.sub(f, F.add(d, d))
     c8 = F.add(F.add(c, c), F.add(c, c))
     c8 = F.add(c8, c8)
     y3 = F.sub(F.mul(e, F.sub(d, x3)), c8)
-    z3 = F.mul(F.add(y, y), z)
+    z3 = F.add(yz, yz)
     return (x3, y3, z3)
 
 
 def pt_add(F, p1, p2):
     """Branchless Jacobian add handling infinity and doubling cases via
-    selects (device code cannot branch per lane)."""
+    selects (device code cannot branch per lane). Independent products
+    share one stacked F.mul call: five multiplication stages, plus the
+    doubling candidate."""
     x1, y1, z1 = p1
     x2, y2, z2 = p2
     inf1 = F.is_zero(z1)
     inf2 = F.is_zero(z2)
-    z1z1 = F.sqr(z1)
-    z2z2 = F.sqr(z2)
-    u1 = F.mul(x1, z2z2)
-    u2 = F.mul(x2, z1z1)
-    s1 = F.mul(y1, F.mul(z2z2, z2))
-    s2 = F.mul(y2, F.mul(z1z1, z1))
-    h = F.sub(u2, u1)
-    r = F.sub(s2, s1)
+    z1z1, z2z2 = F.mul(_stack(z1, z2), _stack(z1, z2))
+    u1, u2, t2, t1 = F.mul(_stack(x1, x2, z2z2, z1z1), _stack(z2z2, z1z1, z2, z1))
+    s1, s2, z1z2 = F.mul(_stack(y1, y2, z1), _stack(t2, t1, z2))
+    h, r = F.sub(_stack(u2, s2), _stack(u1, s1))
     same_x = F.is_zero(h)
     same_y = F.is_zero(r)
     is_double = same_x & same_y & ~inf1 & ~inf2
     is_inf_result = same_x & ~same_y & ~inf1 & ~inf2
 
-    i = F.sqr(F.add(h, h))
-    j = F.mul(h, i)
-    r2 = F.add(r, r)
-    v = F.mul(u1, i)
-    x3 = F.sub(F.sub(F.sqr(r2), j), F.add(v, v))
-    s1j = F.mul(s1, j)
-    y3 = F.sub(F.mul(r2, F.sub(v, x3)), F.add(s1j, s1j))
-    z3 = F.mul(F.mul(z1, z2), h)
+    h2, r2 = F.add(_stack(h, r), _stack(h, r))
+    i, r2sq = F.mul(_stack(h2, r2), _stack(h2, r2))
+    j, v, z3 = F.mul(_stack(h, u1, z1z2), _stack(i, i, h))
+    x3 = F.sub(F.sub(r2sq, j), F.add(v, v))
+    m, s1j = F.mul(_stack(r2, s1), _stack(F.sub(v, x3), j))
+    y3 = F.sub(m, F.add(s1j, s1j))
     z3 = F.add(z3, z3)
 
     dx, dy, dz = pt_double(F, p1)
@@ -393,6 +436,17 @@ def pt_scalar_mul(F, pt, scalar_bits):
     inf = (one, one, zero)
     acc, _ = lax.fori_loop(0, nbits, body, (inf, pt))
     return acc
+
+
+def fold_sum(F, acc, pt):
+    """acc [n] + Σ_j pt[:, j] (coords [n, k, ...], infinity pads) as one
+    fori_loop of single adds: one point add in the compiled program for
+    any k (a scanned add tree compiles several times slower on TPU)."""
+
+    def body(j, acc):
+        return pt_add(F, acc, tuple(c[:, j] for c in pt))
+
+    return lax.fori_loop(0, pt[0].shape[1], body, acc)
 
 
 # ---------------------------------------------------------------------------
@@ -486,122 +540,3 @@ def batch_g1_scalar_mul(xs, ys, zs, bits):
 @jax.jit
 def batch_g2_scalar_mul(xs, ys, zs, bits):
     return pt_scalar_mul(DevFq2, (xs, ys, zs), bits)
-
-
-@jax.jit
-def g1_sum_reduce(xs, ys, zs):
-    """Tree-reduce a batch of G1 points to a single sum (log2 n adds)."""
-    pt = (xs, ys, zs)
-    n = xs.shape[0]
-    while n > 1:
-        half = n // 2
-        lo = tuple(c[:half] for c in pt)
-        hi = tuple(c[half : half * 2] for c in pt)
-        merged = pt_add(DevFq, lo, hi)
-        if n % 2:
-            pt = tuple(
-                jnp.concatenate([m, c[-1:]], axis=0)
-                for m, c in zip(merged, pt)
-            )
-            n = half + 1
-        else:
-            pt = merged
-            n = half
-    return pt
-
-
-# ---------------------------------------------------------------------------
-# Device-backed verify_signature_sets (the `tpu` backend's batch path)
-# ---------------------------------------------------------------------------
-
-
-def verify_signature_sets_device(sets, rng=None) -> bool:
-    """RLC batch verification with the G1/G2 scalar multiplications on
-    device; subgroup checks and the final multi-pairing remain host-side
-    until the pairing kernel lands. Falls back to plain host verification
-    for tiny batches (dispatch overhead dominates)."""
-    import secrets as _secrets
-
-    from ..crypto import bls
-    from ..crypto.bls12_381 import (
-        FQ,
-        FQ2,
-        G1_GEN,
-        g2_in_subgroup,
-        hash_to_g2,
-        inf,
-        is_inf,
-        pairing_check,
-        pt_add as host_pt_add,
-        pt_neg,
-    )
-    from ..crypto.bls12_381.fields import R as CURVE_R
-
-    sets = list(sets)
-    if len(sets) < 8:
-        return bls._BACKENDS["host"].verify_signature_sets(sets, rng)
-
-    rand = rng if rng is not None else _secrets.SystemRandom()
-    sig_points = []
-    agg_pks = []
-    scalars = []
-    messages = []
-    for s in sets:
-        try:
-            if s.signature.is_infinity():
-                return False
-            sig_pt = s.signature.point()
-            if not g2_in_subgroup(sig_pt):
-                return False
-            pk_pts = [pk.point() for pk in s.pubkeys]
-        except (bls.BlsError, ValueError):
-            return False
-        if not pk_pts:
-            return False
-        agg = inf(FQ)
-        for p in pk_pts:
-            agg = host_pt_add(FQ, agg, p)
-        r = 0
-        while r == 0:
-            r = rand.getrandbits(bls.RAND_BITS)
-        sig_points.append(sig_pt)
-        agg_pks.append(agg)
-        scalars.append(r)
-        messages.append(s.message)
-
-    n = len(sets)
-    # Pad to a power-of-two bucket so jit caches few shapes (the reference
-    # batches gossip work in fixed chunks of 64 for the same reason,
-    # beacon_processor/src/lib.rs:200). Padding scalar 0 → infinity result,
-    # sliced off below.
-    bucket = 8
-    while bucket < n:
-        bucket *= 2
-    pad = bucket - n
-    scalars_p = scalars + [0] * pad
-    pts_pad_g1 = agg_pks + [agg_pks[0]] * pad
-    pts_pad_g2 = sig_points + [sig_points[0]] * pad
-
-    bits = jnp.asarray(scalars_to_bits(scalars_p, bls.RAND_BITS))
-    # G1: scale each aggregated pubkey by its scalar on device
-    g1x, g1y, g1z = g1_points_to_device(pts_pad_g1)
-    scaled_g1 = batch_g1_scalar_mul(g1x, g1y, g1z, bits)
-    scaled_pks = g1_points_from_device(scaled_g1)[:n]
-    # G2: scale each signature, reduce to the aggregate on device
-    g2x, g2y, g2z = g2_points_to_device(pts_pad_g2)
-    scaled_g2 = batch_g2_scalar_mul(g2x, g2y, g2z, bits)
-    scaled_sigs = g2_points_from_device(scaled_g2)[:n]
-
-    agg_sig = inf(FQ2)
-    for sp in scaled_sigs:
-        agg_sig = host_pt_add(FQ2, agg_sig, sp)
-
-    by_message: dict[bytes, object] = {}
-    for msg, spk in zip(messages, scaled_pks):
-        prev = by_message.get(msg)
-        by_message[msg] = spk if prev is None else host_pt_add(FQ, prev, spk)
-
-    pairs = [(pt_neg(FQ, G1_GEN), agg_sig)]
-    for msg, pk_pt in by_message.items():
-        pairs.append((pk_pt, hash_to_g2(msg)))
-    return pairing_check(pairs)

@@ -28,7 +28,6 @@ Design notes:
 
 from __future__ import annotations
 
-import functools
 
 import numpy as np
 import jax
@@ -37,8 +36,8 @@ from jax import lax
 
 from ..crypto.bls12_381 import hash_to_curve as HH
 from ..crypto.bls12_381.fields import P
-from .bls381 import NLIMB, DevFq2, int_to_limbs, mont_mul, mod_add, mod_sub, pt_add
-from .bls381_pairing import _one_fq2, g2_clear_cofactor_device
+from .bls381 import NLIMB, int_to_limbs, mont_mul, mod_add, mod_sub
+from .bls381_pairing import _jit_pt_add_g2, _one_fq2, g2_clear_cofactor_device
 from .bls381_tower import (
     f2_add,
     f2_inv,
@@ -50,6 +49,9 @@ from .bls381_tower import (
     f2_sub,
     fq2_const,
     fq_const,
+    fq_inv_staged,
+    fq_pow_fixed,
+    pow_digits,
 )
 
 # --- constants (Montgomery limb form) --------------------------------------
@@ -67,40 +69,9 @@ _INV27_DEV = fq2_const(HH._INV27)
 _INV2_DEV = fq_const((P + 1) // 2)  # 1/2 mod p
 _ONE_F2_DEV = fq2_const((1, 0))
 
-_POW_BITS_WIDTH = 384  # all Fq exponents padded to one width → ONE compiled scan
+_SQRT_DIGITS = pow_digits((P + 1) // 4)
 
 
-def _bits_of(e: int, width: int = _POW_BITS_WIDTH) -> np.ndarray:
-    return np.array([(e >> i) & 1 for i in range(width)], dtype=np.int32)
-
-
-_SQRT_BITS = _bits_of((P + 1) // 4)
-_PM2_BITS_PAD = _bits_of(P - 2)
-
-
-def fq_pow_fixed(a, bits_np: np.ndarray):
-    """a^e over [..., 48] Montgomery limbs, exponent as an LSB-first bit
-    array. The bits ride as a RUNTIME argument into one jitted scan whose
-    tiny body (2 mont_muls) compiles in seconds and is shared by every
-    exponent of the same width — sqrt chains, Fermat inversions, the lot.
-    (Baking each exponent into its own scan made XLA-CPU compile a fresh
-    while loop per exponent; the mega-graphs took hours on slow hosts.)"""
-    return _fq_pow_var(a, jnp.asarray(bits_np))
-
-
-@jax.jit
-def _fq_pow_var(a, bits):
-    from .bls381 import _ONE_MONT
-
-    one = jnp.broadcast_to(jnp.asarray(_ONE_MONT), a.shape).astype(jnp.int32)
-
-    def body(carry, bit):
-        acc, base = carry
-        acc = jnp.where(bit > 0, mont_mul(acc, base), acc)
-        return (acc, mont_mul(base, base)), None
-
-    (acc, _), _ = lax.scan(body, (one, a), bits)
-    return acc
 
 
 def _fq_is_zero(a):
@@ -173,18 +144,12 @@ def f2_sqrt_device(a):
     is_square False. Mirrors crypto/bls12_381/fields.py:f2_sqrt. Staged as
     small jits around the shared pow scan — one mega-jit here made XLA-CPU
     compile for hours."""
-    sqrt_bits = jnp.asarray(_SQRT_BITS)
     norm = _jit_sqrt_norm(a)
-    n = _fq_pow_var(norm, sqrt_bits)
-    roots = _fq_pow_var(_jit_sqrt_candidates(a, n), sqrt_bits)
+    n = fq_pow_fixed(norm, _SQRT_DIGITS)
+    roots = fq_pow_fixed(_jit_sqrt_candidates(a, n), _SQRT_DIGITS)
     t, two_t = _jit_sqrt_pick_t(a, n, roots)
-    inv_2t = _fq_pow_var(two_t, jnp.asarray(_PM2_BITS_PAD))
+    inv_2t = fq_inv_staged(two_t)
     return _jit_sqrt_finish(a, roots, t, inv_2t)
-
-
-def fq_inv_staged(a):
-    """1/a over Fq limbs via the shared pow scan."""
-    return _fq_pow_var(a, jnp.asarray(_PM2_BITS_PAD))
 
 
 @jax.jit
@@ -297,14 +262,6 @@ def isogeny_to_e2_device(x, y):
     return _jit_iso(x, y, f2_inv_staged(d))
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def _jit_pair_add(px, py, n: int):
-    one2 = _one_fq2((n,))
-    q0 = (px[:n], py[:n], one2)
-    q1 = (px[n:], py[n:], one2)
-    return pt_add(DevFq2, q0, q1)
-
-
 # g2_clear_cofactor_device orchestrates its own staged jits
 
 
@@ -322,7 +279,9 @@ def hash_to_g2_device(u):
     flat = jnp.concatenate([u[:, 0], u[:, 1]], axis=0)
     x, y = map_to_curve_sswu_device(flat)
     px, py = isogeny_to_e2_device(x, y)
-    s = _jit_pair_add(px, py, n)
+    one2 = _one_fq2((n,))
+    # the cofactor-clearing G2 add ([n] lanes) serves this sum too
+    s = _jit_pt_add_g2(px[:n], py[:n], one2, px[n:], py[n:], one2)
     return g2_clear_cofactor_device(s)
 
 

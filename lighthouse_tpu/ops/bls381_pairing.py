@@ -44,7 +44,6 @@ vendored C/assembly):
 
 from __future__ import annotations
 
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -56,11 +55,14 @@ from ..crypto.bls12_381.fields import P, R, X
 from . import bls381_tower as TW
 from .bls381 import (
     NLIMB,
+    _stack,
     DevFq2,
     R_MONT,
     _ONE_MONT,
     fq_to_device,
     int_to_limbs,
+    mod_add,
+    mod_sub,
     mont_mul,
     pt_add,
     pt_double,
@@ -71,24 +73,28 @@ from .bls381_tower import (
     f2_double,
     f2_is_zero,
     f2_mul,
-    f2_mul_fq,
     f2_mul_xi,
     f2_neg,
     f2_select,
-    f2_sqr,
     f2_sub,
     f2_triple,
+    f6_inv_parts,
+    f6_mul,
+    f6_mul_by_v,
+    f6_neg,
+    f6_sqr,
+    f6_sub,
     f12_conj,
     f12_frob,
-    f12_frob2,
-    f12_inv,
     f12_is_one,
     f12_mul,
     f12_ones,
-    f12_pow_bits,
     f12_select,
+    f12_slots,
     f12_sqr,
+    _f12,
     fq2_const,
+    fq_inv_staged,
 )
 
 # ---------------------------------------------------------------------------
@@ -166,33 +172,54 @@ def _line_to_f12(a0, b1, b2):
     return jnp.stack([lo, hi], axis=-4)
 
 
+def _fq_as_f2(s):
+    return jnp.stack([s, jnp.zeros_like(s)], axis=-2)
+
+
 def _dbl_step(T, xp, yp):
-    """Tangent line at T evaluated at P, then T ← 2T. Returns (line, T')."""
-    Xc, Yc, Zc = T
-    XX = f2_sqr(Xc)
-    YY = f2_sqr(Yc)
-    ZZ = f2_sqr(Zc)
-    YZ3 = f2_mul(f2_mul(Yc, Zc), ZZ)             # Y·Z³
-    a0 = f2_mul_xi(f2_neg(f2_mul_fq(f2_double(YZ3), yp)))
-    b1 = f2_sub(f2_double(YY), f2_triple(f2_mul(Xc, XX)))
-    b2 = f2_mul_fq(f2_triple(f2_mul(XX, ZZ)), xp)
-    return _line_to_f12(a0, b1, b2), pt_double(DevFq2, T)
+    """Tangent line at T evaluated at P, then T ← 2T (dbl-2009-l). The
+    line and the doubling share their squares; independent products ride
+    one stacked Fq2 multiplication: three in all."""
+    X, Y, Z = T
+    XX, YY, ZZ, YZ = f2_mul(_stack(X, Y, Z, Y), _stack(X, Y, Z, Z))
+    e = f2_triple(XX)
+    xyy = f2_add(X, YY)
+    YZ3, XXX, XXZZ, c, t, f = f2_mul(
+        _stack(YZ, X, XX, YY, xyy, e), _stack(ZZ, XX, ZZ, YY, xyy, e)
+    )
+    d = f2_double(f2_sub(f2_sub(t, XX), c))
+    x3 = f2_sub(f, f2_double(d))
+    a0, b2, m = f2_mul(
+        _stack(YZ3, f2_triple(XXZZ), e),
+        _stack(_fq_as_f2(yp), _fq_as_f2(xp), f2_sub(d, x3)),
+    )
+    a0 = f2_mul_xi(f2_neg(f2_double(a0)))  # −2·Y·Z³·ξ·yp
+    b1 = f2_sub(f2_double(YY), f2_triple(XXX))  # 2Y² − 3X³
+    y3 = f2_sub(m, f2_double(f2_double(f2_double(c))))
+    return _line_to_f12(a0, b1, b2), (x3, y3, f2_double(YZ))
 
 
-def _add_step(T, q_x, q_y, q_jac_one, xp, yp):
+def _add_step(T, q_x, q_y, xp, yp):
     """Chord line through T and the affine base Q evaluated at P, then
-    T ← T + Q."""
-    Xc, Yc, Zc = T
-    ZZ = f2_sqr(Zc)
-    Z3 = f2_mul(ZZ, Zc)
-    theta = f2_sub(f2_mul(q_y, Z3), Yc)
-    lam = f2_sub(f2_mul(q_x, ZZ), Xc)
-    zlam = f2_mul(Zc, lam)
-    a0 = f2_mul_xi(f2_neg(f2_mul_fq(zlam, yp)))
-    b1 = f2_sub(f2_mul(zlam, q_y), f2_mul(theta, q_x))
-    b2 = f2_mul_fq(theta, xp)
-    T_new = pt_add(DevFq2, T, (q_x, q_y, q_jac_one))
-    return _line_to_f12(a0, b1, b2), T_new
+    T ← T + Q as a mixed addition (Z_Q = 1) whose h and r are the line's
+    λ and θ: five stacked Fq2 multiplications. No lane needs the
+    doubling/inverse special cases of a general add: T = [k]Q with
+    1 < k < |x| < r, and the caller masks infinity lanes."""
+    X, Y, Z = T
+    ZZ, qyZ = f2_mul(_stack(Z, q_y), _stack(Z, Z))
+    u2, s2 = f2_mul(_stack(q_x, qyZ), _stack(ZZ, ZZ))
+    lam, theta = f2_sub(_stack(u2, s2), _stack(X, Y))  # h, r
+    h2, r2 = f2_double(_stack(lam, theta))
+    zlam, i, r2sq, tqx, b2 = f2_mul(
+        _stack(Z, h2, r2, theta, theta), _stack(lam, h2, r2, q_x, _fq_as_f2(xp))
+    )
+    j, v, zqy, zyp = f2_mul(_stack(lam, X, zlam, zlam), _stack(i, i, q_y, _fq_as_f2(yp)))
+    x3 = f2_sub(f2_sub(r2sq, j), f2_double(v))
+    m, yj = f2_mul(_stack(r2, Y), _stack(f2_sub(v, x3), j))
+    y3 = f2_sub(m, f2_double(yj))
+    a0 = f2_mul_xi(f2_neg(zyp))  # −Z·λ·ξ·yp
+    b1 = f2_sub(zqy, tqx)  # Z·λ·y2 − θ·x2
+    return _line_to_f12(a0, b1, b2), (x3, y3, f2_double(zlam))
 
 
 # double-and-add op schedule (host-precomputed): one scan step per group
@@ -225,7 +252,7 @@ def miller_loop_batch(xp, yp, q_x, q_y):
 
     def add_branch(carry):
         T, f = carry
-        line, T2 = _add_step(T, q_x, q_y, one2, xp, yp)
+        line, T2 = _add_step(T, q_x, q_y, xp, yp)
         return (T2, f12_mul(f, line))
 
     def body(carry, op):
@@ -283,63 +310,87 @@ def _pow_x_conj(a):
 _jit_f12_mul = jax.jit(f12_mul)
 _jit_f12_conj = jax.jit(f12_conj)
 _jit_f12_frob = jax.jit(f12_frob)
-_jit_f12_frob2 = jax.jit(f12_frob2)
 
 
 @jax.jit
-def _jit_f12_inv(a):
-    return f12_inv(a)
+def _jit_f12_inv_parts(a):
+    """a⁻¹ down to its one Fq inversion: with t = a0² − v·a1² = c / d,
+    returns (c, d, |d|²)."""
+    a0, a1 = f12_slots(a)
+    c, d = f6_inv_parts(f6_sub(f6_sqr(a0), f6_mul_by_v(f6_sqr(a1))))
+    d0, d1 = d[..., 0, :], d[..., 1, :]
+    return c, d, mod_add(mont_mul(d0, d0), mont_mul(d1, d1))
 
 
 @jax.jit
-def _jit_easy_part(F, Finv):
-    t = f12_mul(f12_conj(F), Finv)  # ^(p⁶−1)
-    return f12_mul(f12_frob2(t), t)  # ^(p²+1): now cyclotomic
+def _jit_f12_inv_finish(a, c, d, ninv):
+    """a⁻¹ = (a0·t⁻¹, −a1·t⁻¹) from the parts and 1/|d|²."""
+    d0, d1 = d[..., 0, :], d[..., 1, :]
+    dinv = jnp.stack(
+        [mont_mul(d0, ninv), mod_sub(jnp.zeros_like(d0), mont_mul(d1, ninv))], -2
+    )
+    tinv = f2_mul(c, dinv[..., None, :, :])
+    m = f6_mul(jnp.stack(f12_slots(a), -4), tinv[..., None, :, :, :])
+    return _f12(m[..., 0, :, :, :], f6_neg(m[..., 1, :, :, :]))
 
 
-@jax.jit
-def _jit_t_cubed_mul(y4, t):
-    return f12_mul(y4, f12_mul(f12_sqr(t), t))
+def _f12_inv_staged(a):
+    """Fq12 inversion whose Fq inversion rides the shared pow loop: two
+    small programs instead of one holding a whole exponentiation."""
+    c, d, n = _jit_f12_inv_parts(a)
+    return _jit_f12_inv_finish(a, c, d, fq_inv_staged(n))
 
 
 def final_exp_cubed(F):
     """F^(3·(p¹²−1)/r) — easy part then the (x−1)²(x+p)(x²+p²−1)+3 chain.
     Cube of the host oracle's final_exponentiation; identical for ==1
-    checks. Python orchestration over staged jits."""
-    t = _jit_easy_part(F, _jit_f12_inv(F))
-    y1 = _jit_f12_conj(_jit_f12_mul(_jit_f12_pow_x(t), t))
-    y2 = _jit_f12_conj(_jit_f12_mul(_jit_f12_pow_x(y1), y1))
-    y3 = _jit_f12_mul(_pow_x_conj(y2), _jit_f12_frob(y2))  # ^(x+p)
+    checks. Python orchestration over a handful of small jits, each
+    compiled once and reused (one program holding the whole chain compiles
+    superlinearly slower than its pieces)."""
+    mul, conj, frob = _jit_f12_mul, _jit_f12_conj, _jit_f12_frob
+    t = mul(conj(F), _f12_inv_staged(F))  # ^(p⁶−1)
+    t = mul(frob(frob(t)), t)  # ^(p²+1): now cyclotomic
+    y1 = conj(mul(_jit_f12_pow_x(t), t))
+    y2 = conj(mul(_jit_f12_pow_x(y1), y1))
+    y3 = mul(_pow_x_conj(y2), frob(y2))  # ^(x+p)
     a = _pow_x_conj(y3)  # y3^x
     b = _pow_x_conj(a)  # y3^(x²)
-    y4 = _jit_f12_mul(_jit_f12_mul(b, _jit_f12_frob2(y3)), _jit_f12_conj(y3))
-    return _jit_t_cubed_mul(y4, t)
+    y4 = mul(mul(b, frob(frob(y3))), conj(y3))
+    return mul(y4, mul(mul(t, t), t))  # y4 · t³
 
 
 @jax.jit
-def _jit_mask(f, p_inf, q_inf):
-    skip = p_inf | q_inf
-    return f12_select(skip, f12_ones(f.shape[:-4]), f)
-
-
 def _mask_and_reduce(f, p_inf, q_inf):
-    """Infinity lanes → identity, then tree-product to [1] Fq12 — one
-    shared batch f12_mul jit per halving level (log n dispatches)."""
-    f = _jit_mask(f, p_inf, q_inf)
-    n = f.shape[0]
-    while n > 1:
-        half = n // 2
-        merged = _jit_f12_mul(f[:half], f[half : 2 * half])
-        if n % 2:
-            merged = jnp.concatenate([merged, f[-1:]], axis=0)
-        f = merged
-        n = f.shape[0]
-    return f
+    """Infinity lanes → identity, then the product of all lanes → [1]
+    Fq12, as one fori_loop of single products (one f12_mul in the
+    compiled program for any lane count)."""
+    skip = p_inf | q_inf
+    f = f12_select(skip, f12_ones(f.shape[:-4]), f)
+
+    def body(j, acc):
+        return f12_mul(acc, lax.dynamic_slice_in_dim(f, j, 1))
+
+    return lax.fori_loop(1, f.shape[0], body, f[:1])
+
+
+#: Miller-loop lanes per call: one BLS chunk's messages plus the signature
+#: pair (crypto.bls.DEFAULT_DEVICE_CHUNK + 1). Smaller batches (the KZG
+#: pair checks) pad up with infinity lanes, so every caller shares one
+#: compiled Miller loop.
+PAIRING_LANES = 33
 
 
 def multi_pairing_check_device(xp, yp, p_inf, q_x, q_y, q_inf):
     """∏ e(P_i, Q_i) == 1 over the batch, entirely on device. Infinity
     lanes contribute the identity (host oracle behavior)."""
+    n = xp.shape[0]
+    pad = -(-n // PAIRING_LANES) * PAIRING_LANES - n
+    if pad:
+        grow = lambda a, v: jnp.concatenate(  # noqa: E731
+            [a, jnp.full((pad, *a.shape[1:]), v, a.dtype)]
+        )
+        xp, yp, q_x, q_y = (grow(a, 0) for a in (xp, yp, q_x, q_y))
+        p_inf, q_inf = grow(p_inf, True), grow(q_inf, True)
     f = _miller_jit(xp, yp, q_x, q_y)
     F = _mask_and_reduce(f, p_inf, q_inf)
     return f12_is_one(final_exp_cubed(F))[0]
@@ -415,16 +466,28 @@ _ATE_BITS_MSB = np.array([int(b) for b in bin(_ATE)[2:]], dtype=np.int32)
 
 
 @jax.jit
+def _jit_psi_is_neg(q_x, q_y, q_inf, X, Y, Z):
+    """ψ(Q) == −(X:Y:Z), compared in Jacobian coordinates (ψ(Q) is
+    affine): x·Z² == X and y·Z³ == −Y with Z ≠ 0; infinity lanes pass."""
+    px, py = psi(q_x, q_y)
+    ZZ, pyZ = f2_mul(_stack(Z, py), _stack(Z, Z))
+    lhs_x, lhs_y = f2_mul(_stack(px, pyZ), _stack(ZZ, ZZ))
+    same = (
+        jnp.all(lhs_x == X, axis=(-1, -2))
+        & jnp.all(f2_add(lhs_y, Y) == 0, axis=(-1, -2))
+        & ~f2_is_zero(Z)
+    )
+    return same | q_inf
+
+
 def g2_subgroup_check_device(q_x, q_y, q_inf):
     """Batched ψ(Q) == [x]Q membership test (64-iteration ladder instead of
-    the host's 255-bit order multiplication). Infinity counts as member."""
-    batch = q_x.shape[:-2]
-    one2 = _one_fq2(batch)
-    T = (q_x, q_y, one2)
-    xq = _ladder_mul_const(T, _ATE_BITS_MSB)          # [|x|]Q
-    px, py = psi(q_x, q_y)
-    s = pt_add(DevFq2, (px, py, one2), xq)            # ψ(Q) + [|x|]Q (x<0)
-    return f2_is_zero(s[2]) | q_inf
+    the host's 255-bit order multiplication; x < 0, so the test is
+    ψ(Q) == −[|x|]Q). Infinity counts as member. The ladder is the
+    compiled kernel cofactor clearing shares."""
+    one2 = _one_fq2(q_x.shape[:-2])
+    xq = _ladder_mul_const((q_x, q_y, one2), _ATE_BITS_MSB)  # [|x|]Q
+    return _jit_psi_is_neg(q_x, q_y, q_inf, *xq)
 
 
 # ---------------------------------------------------------------------------

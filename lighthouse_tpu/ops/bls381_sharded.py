@@ -12,9 +12,10 @@ block_signature_verifier.rs:396-404) with the chunk axis mapped onto the
 device mesh instead of CPU threads.
 
 Point addition is not an arithmetic `psum`, so the reduction is an
-`all_gather` + replicated Jacobian fold (n_devices-1 adds) — negligible
+`all_gather` + replicated Jacobian fold (n_devices adds) — negligible
 next to the 64-iteration ladders and bandwidth-wise just 3·48 int32 limbs
-per device.
+per device. Both sums are fold loops of single adds, so the step holds
+three point operations whatever the shard size.
 """
 
 from __future__ import annotations
@@ -27,26 +28,13 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .bls381 import DevFq, pt_add, pt_scalar_mul
+from .bls381 import DevFq, fold_sum, pt_scalar_mul
 
 
-def _tree_reduce_points(F, pt):
-    """Coords [k, ...] → [1, ...] Jacobian sum (static shapes)."""
-    k = pt[0].shape[0]
-    while k > 1:
-        half = k // 2
-        lo = tuple(c[:half] for c in pt)
-        hi = tuple(c[half : 2 * half] for c in pt)
-        merged = pt_add(F, lo, hi)
-        if k % 2:
-            pt = tuple(
-                jnp.concatenate([m, c[-1:]], axis=0) for m, c in zip(merged, pt)
-            )
-            k = half + 1
-        else:
-            pt = merged
-            k = half
-    return pt
+def _sum_points(F, pt):
+    """Coords [k, ...] → [1, ...] Jacobian sum, one add per fold step."""
+    inf = (pt[0][:1], pt[1][:1], jnp.zeros_like(pt[2][:1]))
+    return fold_sum(F, inf, tuple(c[None] for c in pt))
 
 
 def sharded_rlc_g1_fn(mesh: Mesh):
@@ -55,20 +43,18 @@ def sharded_rlc_g1_fn(mesh: Mesh):
 
     def per_device(xs, ys, zs, bits):
         scaled = pt_scalar_mul(DevFq, (xs, ys, zs), bits)
-        part = _tree_reduce_points(DevFq, scaled)  # [1, 48] each coord
+        part = _sum_points(DevFq, scaled)  # [1, 48] each coord
         gathered = tuple(
             lax.all_gather(c[0], "batch", tiled=False) for c in part
         )  # [n_devices, 48]
-        return _tree_reduce_points(DevFq, gathered)
+        return _sum_points(DevFq, gathered)
 
-    from jax.experimental.shard_map import shard_map
-
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(P("batch"), P("batch"), P("batch"), P("batch")),
         out_specs=(P("batch"), P("batch"), P("batch")),  # identical rows
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

@@ -21,8 +21,8 @@ the signature-set dimension and jit/shard-friendly.
 
 from __future__ import annotations
 
-import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -32,6 +32,7 @@ from ..crypto.bls12_381.fields import P
 from .bls381 import (
     NLIMB,
     R_MONT,
+    DevFq2,
     _ONE_MONT,
     int_to_limbs,
     mod_add,
@@ -64,9 +65,19 @@ _G6_1_DEV = fq2_const(HF._G6_1)
 _G6_2_DEV = fq2_const(HF._G6_2)
 _G12_DEV = fq2_const(HF._G12)
 
-# Fixed exponent bits for Fermat inversion a^(p-2), LSB first.
-_PM2_BITS = np.array([(P - 2) >> i & 1 for i in range((P - 2).bit_length())],
-                     dtype=np.int32)
+_POW_WINDOW = 4
+_POW_DIGITS = 384 // _POW_WINDOW  # every Fq exponent padded to 384 bits
+
+
+def pow_digits(e: int) -> np.ndarray:
+    """MSB-first 4-bit digits of e, padded to the shared 384-bit width."""
+    return np.array(
+        [(e >> (_POW_WINDOW * i)) & 0xF for i in reversed(range(_POW_DIGITS))],
+        dtype=np.int32,
+    )
+
+
+_PM2_DIGITS = pow_digits(P - 2)
 
 
 # ---------------------------------------------------------------------------
@@ -74,46 +85,16 @@ _PM2_BITS = np.array([(P - 2) >> i & 1 for i in range((P - 2).bit_length())],
 # ---------------------------------------------------------------------------
 
 
-def f2_add(a, b):
-    return jnp.stack(
-        [mod_add(a[..., 0, :], b[..., 0, :]), mod_add(a[..., 1, :], b[..., 1, :])],
-        axis=-2,
-    )
-
-
-def f2_sub(a, b):
-    return jnp.stack(
-        [mod_sub(a[..., 0, :], b[..., 0, :]), mod_sub(a[..., 1, :], b[..., 1, :])],
-        axis=-2,
-    )
-
-
-def f2_neg(a):
-    return f2_sub(jnp.zeros_like(a), a)
+f2_add = DevFq2.add
+f2_sub = DevFq2.sub
+f2_neg = DevFq2.neg
+f2_mul = DevFq2.mul
+f2_sqr = DevFq2.sqr
 
 
 def f2_conj(a):
     c1 = mod_sub(jnp.zeros_like(a[..., 1, :]), a[..., 1, :])
     return jnp.stack([a[..., 0, :], c1], axis=-2)
-
-
-def f2_mul(a, b):
-    """Karatsuba: 3 base mults."""
-    a0, a1 = a[..., 0, :], a[..., 1, :]
-    b0, b1 = b[..., 0, :], b[..., 1, :]
-    t0 = mont_mul(a0, b0)
-    t1 = mont_mul(a1, b1)
-    cross = mont_mul(mod_add(a0, a1), mod_add(b0, b1))
-    return jnp.stack(
-        [mod_sub(t0, t1), mod_sub(mod_sub(cross, t0), t1)], axis=-2
-    )
-
-
-def f2_sqr(a):
-    a0, a1 = a[..., 0, :], a[..., 1, :]
-    c0 = mont_mul(mod_add(a0, a1), mod_sub(a0, a1))
-    t = mont_mul(a0, a1)
-    return jnp.stack([c0, mod_add(t, t)], axis=-2)
 
 
 def f2_mul_xi(a):
@@ -122,34 +103,65 @@ def f2_mul_xi(a):
     return jnp.stack([mod_sub(a0, a1), mod_add(a0, a1)], axis=-2)
 
 
-def f2_mul_fq(a, s):
-    """Fq2 × Fq scalar: s shape [..., 48]."""
-    return jnp.stack(
-        [mont_mul(a[..., 0, :], s), mont_mul(a[..., 1, :], s)], axis=-2
-    )
-
-
 def f2_double(a):
-    return f2_add(a, a)
+    return mod_add(a, a)
 
 
 def f2_triple(a):
-    return f2_add(f2_add(a, a), a)
+    return mod_add(mod_add(a, a), a)
+
+
+def fq_pow_digits(a, digits):
+    """a^e over [..., 48] limbs, e as `pow_digits(e)` (a runtime array):
+    fixed 4-bit-window exponentiation — a table of a^0..a^15, then per
+    digit four squarings and one table product, 96 loop steps for a
+    384-bit exponent (no data-dependent control flow)."""
+    one = jnp.broadcast_to(jnp.asarray(_ONE_MONT), a.shape).astype(jnp.int32)
+    table = [one, a]
+    while len(table) < 1 << _POW_WINDOW:
+        m = len(table)
+        top = mont_mul(table[m // 2], table[m // 2])  # a^m
+        table += [top, *mont_mul(top, jnp.stack(table[1:m]))]
+    table = jnp.stack(table)
+
+    def body(i, acc):
+        for _ in range(_POW_WINDOW):
+            acc = mont_mul(acc, acc)
+        return mont_mul(acc, lax.dynamic_index_in_dim(table, digits[i], keepdims=False))
+
+    return lax.fori_loop(0, digits.shape[0], body, one)
 
 
 def fq_inv(a):
-    """Fermat a^(p−2) over [..., 48] limbs — fixed 380-iteration scan with
-    static bits (no data-dependent control flow under jit)."""
-    bits = jnp.asarray(_PM2_BITS)
-    one = jnp.broadcast_to(jnp.asarray(_ONE_MONT), a.shape).astype(jnp.int32)
+    """Fermat a^(p−2) over [..., 48] limbs."""
+    return fq_pow_digits(a, jnp.asarray(_PM2_DIGITS))
 
-    def body(carry, bit):
-        acc, base = carry
-        acc = jnp.where(bit > 0, mont_mul(acc, base), acc)
-        return (acc, mont_mul(base, base)), None
 
-    (acc, _), _ = lax.scan(body, (one, a), bits)
-    return acc
+#: lanes per shared pow call: the largest caller (four sqrt candidates of
+#: two SSWU maps over 2×32 messages) fills it; smaller ones pad up, so
+#: every exponent and every caller runs ONE compiled loop
+POW_LANES = 512
+
+
+def fq_pow_fixed(a, digits_np: np.ndarray):
+    """a^e over [..., 48] Montgomery limbs, exponent as `pow_digits(e)`.
+    The digits ride as a RUNTIME argument and the lanes pad to a multiple
+    of POW_LANES, so one jitted loop serves every exponent and shape —
+    sqrt chains, Fermat inversions, the lot."""
+    flat = a.reshape(-1, a.shape[-1])
+    n = flat.shape[0]
+    pad = -(-n // POW_LANES) * POW_LANES - n
+    flat = jnp.concatenate([flat, jnp.zeros((pad, flat.shape[1]), flat.dtype)])
+    out = _fq_pow_var(flat, jnp.asarray(digits_np))
+    return out[:n].reshape(a.shape)
+
+
+_fq_pow_var = jax.jit(fq_pow_digits)
+
+
+def fq_inv_staged(a):
+    """1/a over Fq limbs via the shared pow loop."""
+    return fq_pow_fixed(a, _PM2_DIGITS)
 
 
 def f2_inv(a):
@@ -186,40 +198,37 @@ def f6_slots(a):
 
 
 def f6_add(a, b):
-    a0, a1, a2 = f6_slots(a)
-    b0, b1, b2 = f6_slots(b)
-    return _f6(f2_add(a0, b0), f2_add(a1, b1), f2_add(a2, b2))
+    return mod_add(a, b)
 
 
 def f6_sub(a, b):
-    a0, a1, a2 = f6_slots(a)
-    b0, b1, b2 = f6_slots(b)
-    return _f6(f2_sub(a0, b0), f2_sub(a1, b1), f2_sub(a2, b2))
+    return mod_sub(a, b)
 
 
 def f6_neg(a):
-    return f6_sub(jnp.zeros_like(a), a)
+    return mod_sub(jnp.zeros_like(a), a)
+
+
+def _st(*xs, axis=-3):
+    return jnp.stack(jnp.broadcast_arrays(*xs), axis=axis)
 
 
 def f6_mul(a, b):
-    """Toom-style 6-mult Fq6 product (mirrors host f6_mul)."""
+    """Toom-style 6-mult Fq6 product (mirrors host f6_mul); the six Fq2
+    products are independent, so they ride one stacked f2_mul."""
+    a, b = jnp.broadcast_arrays(a, b)
     a0, a1, a2 = f6_slots(a)
     b0, b1, b2 = f6_slots(b)
-    t0 = f2_mul(a0, b0)
-    t1 = f2_mul(a1, b1)
-    t2 = f2_mul(a2, b2)
-    c0 = f2_add(
-        t0,
-        f2_mul_xi(f2_sub(f2_mul(f2_add(a1, a2), f2_add(b1, b2)), f2_add(t1, t2))),
+    sa = f2_add(_st(a0, a1, a0), _st(a1, a2, a2))  # a0+a1, a1+a2, a0+a2
+    sb = f2_add(_st(b0, b1, b0), _st(b1, b2, b2))
+    t = f2_mul(jnp.concatenate([a, sa], -3), jnp.concatenate([b, sb], -3))
+    t0, t1, t2, x01, x12, x02 = (t[..., i, :, :] for i in range(6))
+    d = f2_sub(_st(x12, x01, x02), f2_add(_st(t1, t0, t0), _st(t2, t1, t2)))
+    xi = f2_mul_xi(_st(d[..., 0, :, :], t2))
+    return f2_add(
+        _st(t0, d[..., 1, :, :], d[..., 2, :, :]),
+        _st(xi[..., 0, :, :], xi[..., 1, :, :], t1),
     )
-    c1 = f2_add(
-        f2_sub(f2_mul(f2_add(a0, a1), f2_add(b0, b1)), f2_add(t0, t1)),
-        f2_mul_xi(t2),
-    )
-    c2 = f2_add(
-        f2_sub(f2_mul(f2_add(a0, a2), f2_add(b0, b2)), f2_add(t0, t2)), t1
-    )
-    return _f6(c0, c1, c2)
 
 
 def f6_sqr(a):
@@ -231,7 +240,9 @@ def f6_mul_by_v(a):
     return _f6(f2_mul_xi(a2), a0, a1)
 
 
-def f6_inv(a):
+def f6_inv_parts(a):
+    """a⁻¹ = c / d over Fq6: the adjugate c [..., 3, 2, 48] and the Fq2
+    denominator d."""
     a0, a1, a2 = f6_slots(a)
     c0 = f2_sub(f2_sqr(a0), f2_mul_xi(f2_mul(a1, a2)))
     c1 = f2_sub(f2_mul_xi(f2_sqr(a2)), f2_mul(a0, a1))
@@ -239,17 +250,18 @@ def f6_inv(a):
     denom = f2_add(
         f2_mul(a0, c0), f2_mul_xi(f2_add(f2_mul(a2, c1), f2_mul(a1, c2)))
     )
-    t = f2_inv(denom)
-    return _f6(f2_mul(c0, t), f2_mul(c1, t), f2_mul(c2, t))
+    return _f6(c0, c1, c2), denom
+
+
+def f6_inv(a):
+    c, denom = f6_inv_parts(a)
+    return f2_mul(c, f2_inv(denom)[..., None, :, :])
 
 
 def f6_frob(a):
-    a0, a1, a2 = f6_slots(a)
-    return _f6(
-        f2_conj(a0),
-        f2_mul(f2_conj(a1), jnp.asarray(_G6_1_DEV)),
-        f2_mul(f2_conj(a2), jnp.asarray(_G6_2_DEV)),
-    )
+    c = f2_conj(a)
+    g = jnp.asarray(np.stack([_G6_1_DEV, _G6_2_DEV]))
+    return jnp.concatenate([c[..., :1, :, :], f2_mul(c[..., 1:, :, :], g)], -3)
 
 
 # ---------------------------------------------------------------------------
@@ -272,30 +284,28 @@ def f12_ones(batch_shape) -> jnp.ndarray:
 
 
 def f12_add(a, b):
-    a0, a1 = f12_slots(a)
-    b0, b1 = f12_slots(b)
-    return _f12(f6_add(a0, b0), f6_add(a1, b1))
+    return mod_add(a, b)
 
 
 def f12_mul(a, b):
+    """Karatsuba over Fq6: the three Fq6 products ride one stacked f6_mul."""
+    a, b = jnp.broadcast_arrays(a, b)
     a0, a1 = f12_slots(a)
     b0, b1 = f12_slots(b)
-    t0 = f6_mul(a0, b0)
-    t1 = f6_mul(a1, b1)
-    c0 = f6_add(t0, f6_mul_by_v(t1))
-    c1 = f6_sub(f6_sub(f6_mul(f6_add(a0, a1), f6_add(b0, b1)), t0), t1)
-    return _f12(c0, c1)
+    t = f6_mul(
+        jnp.stack([a0, a1, f6_add(a0, a1)], -4),
+        jnp.stack([b0, b1, f6_add(b0, b1)], -4),
+    )
+    t0, t1, x = t[..., 0, :, :, :], t[..., 1, :, :, :], t[..., 2, :, :, :]
+    s = f6_add(jnp.stack([t0, t0], -4), jnp.stack([f6_mul_by_v(t1), t1], -4))
+    return _f12(s[..., 0, :, :, :], f6_sub(x, s[..., 1, :, :, :]))
 
 
 def f12_sqr(a):
-    a0, a1 = f12_slots(a)
-    t = f6_mul(a0, a1)
-    c0 = f6_sub(
-        f6_sub(f6_mul(f6_add(a0, a1), f6_add(a0, f6_mul_by_v(a1))), t),
-        f6_mul_by_v(t),
-    )
-    c1 = f6_add(t, t)
-    return _f12(c0, c1)
+    """a·a through f12_mul: the dedicated squaring formula saves a third
+    of the Fq6 products, but its program takes the TPU compiler over twice
+    as long, and at these lane counts the products are not the cost."""
+    return f12_mul(a, a)
 
 
 def f12_conj(a):
@@ -310,16 +320,9 @@ def f12_inv(a):
 
 
 def f12_frob(a):
-    a0, a1 = f12_slots(a)
-    b0 = f6_frob(a0)
-    b1 = f6_frob(a1)
-    g = jnp.asarray(_G12_DEV)
-    b1 = _f6(*[f2_mul(c, g) for c in f6_slots(b1)])
-    return _f12(b0, b1)
-
-
-def f12_frob2(a):
-    return f12_frob(f12_frob(a))
+    b = f6_frob(a)
+    b1 = f2_mul(b[..., 1, :, :, :], jnp.asarray(_G12_DEV))
+    return _f12(b[..., 0, :, :, :], b1)
 
 
 def f12_select(c, a, b):
@@ -330,22 +333,6 @@ def f12_select(c, a, b):
 def f12_is_one(a):
     """Per-lane check a == 1 (Montgomery one in slot [0,0,0])."""
     return jnp.all(a == f12_ones(a.shape[:-4]), axis=(-1, -2, -3, -4))
-
-
-def f12_pow_bits(a, bits: np.ndarray):
-    """a^e for a FIXED exponent given as LSB-first bit array (host numpy).
-    Square-and-multiply scan: branchless per-iteration select keeps the
-    graph small (vs static unrolling) while the trip count stays static."""
-    bits_d = jnp.asarray(bits.astype(np.int32))
-    one = f12_ones(a.shape[:-4])
-
-    def body(carry, bit):
-        acc, base = carry
-        acc = jnp.where(bit > 0, f12_mul(acc, base), acc)
-        return (acc, f12_sqr(base)), None
-
-    (acc, _), _ = lax.scan(body, (one, a), bits_d)
-    return acc
 
 
 # ---------------------------------------------------------------------------

@@ -19,10 +19,11 @@ with EVERY group/field operation on device:
   7. Miller loops + final exp     — one multi-pairing (bls381_pairing)
 
 The host's remaining jobs: point decompression (bytes → ints, cached on the
-PublicKey/Signature wrappers), RLC scalar sampling, and batch-shape
-bucketing (powers of two, so jit caches a handful of shapes — the reference
-batches gossip work in fixed chunks of 64 for the same reason,
-beacon_processor/src/lib.rs:200).
+PublicKey/Signature wrappers), RLC scalar sampling, and padding every
+batch to one shape (the set axis to the device chunk, committees to whole
+AGG_LANES slices), so one set of compiled kernels serves every batch —
+the reference batches gossip work in fixed chunks of 64 for the same
+reason, beacon_processor/src/lib.rs:200.
 """
 
 from __future__ import annotations
@@ -30,28 +31,28 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ..crypto.bls12_381.fields import P
 from .bls381 import (
     NLIMB,
     DevFq,
-    DevFq2,
     fq_to_device,
     g1_points_to_device,
     batch_g1_scalar_mul,
     batch_g2_scalar_mul,
+    fold_sum,
     mont_mul,
-    pt_add,
     scalars_to_bits,
 )
 from .bls381_htc import (
-    f2_inv_staged,
+    _jit_f2_norm,
+    _jit_f2_scale_inv,
     fq_inv_staged,
     hash_to_g2_device,
     messages_to_field_device,
 )
 from .bls381_pairing import (
+    _jit_pt_add_g2,
     g1_affine_to_device,
     g2_affine_to_device,
     g2_subgroup_check_device,
@@ -63,51 +64,43 @@ from .bls381_pairing import (
 # ---------------------------------------------------------------------------
 
 
-def _tree_reduce_scan(F, pt):
-    """Tree-sum points along axis 1 (coords [n, k, ...], k a power of two,
-    infinity pads) → [n, ...].
-
-    Shape-stable formulation: one lax.scan whose body does a single
-    [n, k/2]-lane batched pt_add of even/odd columns and re-pads with
-    infinity — the buffer shape never changes, so the whole reduction is
-    ONE compiled scan (unrolling the tree into straight-line adds, or one
-    jit per level, both made XLA-CPU compiles explode)."""
-    k = pt[0].shape[1]
-    assert k & (k - 1) == 0, "tree reduce needs a power-of-two lane count"
-    if k == 1:
-        return tuple(c[:, 0] for c in pt)
-    depth = (k - 1).bit_length()
-
-    def body(buf, _):
-        lo = tuple(c[:, 0::2] for c in buf)
-        hi = tuple(c[:, 1::2] for c in buf)
-        merged = pt_add(F, lo, hi)  # [n, k/2, ...]
-        # re-pad to [n, k]: infinity (z=0) lanes are absorbed by pt_add
-        buf = tuple(
-            jnp.concatenate([m, jnp.zeros_like(m)], axis=1) for m in merged
-        )
-        return buf, None
-
-    buf, _ = lax.scan(body, pt, None, length=depth)
-    return tuple(c[:, 0] for c in buf)
+#: committee/group lanes summed per aggregation call: every committee size
+#: runs through ONE compiled [n, AGG_LANES] kernel, looped over the
+#: committee axis in fixed-width slices
+AGG_LANES = 16
 
 
-_jit_tree_reduce_g1 = jax.jit(
-    lambda xs, ys, zs: _tree_reduce_scan(DevFq, (xs, ys, zs))
-)
-_jit_tree_reduce_g2 = jax.jit(
-    lambda xs, ys, zs: _tree_reduce_scan(DevFq2, (xs, ys, zs))
-)
+@jax.jit
+def _jit_agg_step(ax, ay, az, xs, ys, zs):
+    """acc [n] + Σ_k pts [n, AGG_LANES] (infinity pads) → [n]."""
+    return fold_sum(DevFq, (ax, ay, az), (xs, ys, zs))
 
 
 def g1_segment_sum(xs, ys, zs):
-    """[n, k] padded G1 points (infinity pads) → [n] sums."""
-    return _jit_tree_reduce_g1(xs, ys, zs)
+    """[n, k] padded G1 points (infinity pads, k a multiple of AGG_LANES)
+    → [n] sums, in k / AGG_LANES calls of one compiled kernel."""
+    n, k = xs.shape[:2]
+    assert k % AGG_LANES == 0, "pad the committee axis to AGG_LANES"
+    acc = (xs[:, 0], ys[:, 0], jnp.zeros_like(zs[:, 0]))  # infinity
+    for j in range(0, k, AGG_LANES):
+        sl = slice(j, j + AGG_LANES)
+        acc = _jit_agg_step(*acc, xs[:, sl], ys[:, sl], zs[:, sl])
+    return acc
 
 
 def g2_sum_reduce(xs, ys, zs):
-    """Tree-reduce a batch of G2 points to a single sum ([n] → [1])."""
-    return _jit_tree_reduce_g2(xs[None], ys[None], zs[None])
+    """Sum a batch of G2 points ([n] → [1]) as a halving tree; every level
+    is one call of the shared [n]-lane G2 add kernel, with the lanes past
+    the live half set to infinity (z = 0)."""
+    n = xs.shape[0]
+    assert n & (n - 1) == 0, "power-of-two lane count"
+    pt = (xs, ys, zs)
+    while n > 1:
+        n //= 2
+        lo = tuple(c.at[n:].set(0) for c in pt)
+        hi = tuple(jnp.roll(c, -n, axis=0).at[n:].set(0) for c in pt)
+        pt = _jit_pt_add_g2(*lo, *hi)
+    return tuple(c[:1] for c in pt)
 
 
 @jax.jit
@@ -117,11 +110,6 @@ def _jit_g1_affine_from_inv(x, y, z, zinv):
     ay = mont_mul(y, mont_mul(zinv2, zinv))
     inf = jnp.all(z == 0, axis=-1)
     return ax, ay, inf
-
-
-def g1_jac_to_affine(x, y, z):
-    """Batched Jacobian→affine over Fq: returns (ax, ay, inf_mask)."""
-    return _jit_g1_affine_from_inv(x, y, z, fq_inv_staged(z))
 
 
 @jax.jit
@@ -135,21 +123,20 @@ def _jit_g2_affine_from_inv(x, y, z, zinv):
     return ax, ay, inf
 
 
-def g2_jac_to_affine(x, y, z):
-    """Batched Jacobian→affine over Fq2 (coords [..., 2, 48])."""
-    return _jit_g2_affine_from_inv(x, y, z, f2_inv_staged(z))
-
-
 # ---------------------------------------------------------------------------
 # Host-side staging
 # ---------------------------------------------------------------------------
 
 
-def _bucket(n: int, floor: int = 8) -> int:
+def _bucket(n: int, floor: int) -> int:
     b = floor
     while b < n:
         b *= 2
     return b
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _affine_int(pt):
@@ -251,7 +238,10 @@ def verify_signature_sets_device_full(sets, rng=None) -> bool:
         messages.append(s.message)
 
     n = len(sets)
-    nb = _bucket(n)
+    # one shape for every chunk the node sends: the set axis pads to the
+    # device chunk, messages pad to the set count, committees and message
+    # groups to whole AGG_LANES slices
+    nb = _bucket(n, floor=bls.DEFAULT_DEVICE_CHUNK)
 
     # --- G2 subgroup checks on all signatures (device) ---
     sig_pad = sig_affs + [None] * (nb - n)
@@ -261,7 +251,7 @@ def verify_signature_sets_device_full(sets, rng=None) -> bool:
         return False
 
     # --- per-set pubkey aggregation (device, padded committee axis) ---
-    kmax = _bucket(max(len(r) for r in pk_rows), floor=1)
+    kmax = _round_up(max(len(r) for r in pk_rows), AGG_LANES)
     grid = [row + [None] * (kmax - len(row)) for row in pk_rows]
     grid += [[None] * kmax] * (nb - n)
     gx, gy, gz = _g1_affine_grid_to_device(grid)
@@ -288,8 +278,8 @@ def verify_signature_sets_device_full(sets, rng=None) -> bool:
         groups.setdefault(m, []).append(i)
     msgs = list(groups)
     m_count = len(msgs)
-    mb = _bucket(m_count, floor=1)
-    gmax = _bucket(max(len(v) for v in groups.values()), floor=1)
+    mb = nb
+    gmax = _round_up(max(len(v) for v in groups.values()), AGG_LANES)
     if nb > n:
         # lane nb-1 is a padded set (scalar 0 ladder → infinity): reuse it
         # as the gather pad slot.
@@ -318,17 +308,18 @@ def verify_signature_sets_device_full(sets, rng=None) -> bool:
     neg_g1 = to_affine(FQ, pt_neg(FQ, G1_GEN))
     ngx, ngy, ng_inf = g1_affine_to_device([neg_g1])
 
-    pk_ax, pk_ay, pk_inf = g1_jac_to_affine(*msg_pk)
+    # Jacobian → affine for every pairing input with ONE shared Fq
+    # inversion: the G1 z's and the norms of the G2 z's ride one pow call
+    q = tuple(jnp.concatenate([a, h], axis=0) for a, h in zip(agg_sig, hm))
+    inv = fq_inv_staged(jnp.concatenate([msg_pk[2], _jit_f2_norm(q[2])]))
+    pk_ax, pk_ay, pk_inf = _jit_g1_affine_from_inv(*msg_pk, inv[:mb])
     # mask out padded message lanes
-    lane_pad = np.arange(mb) >= m_count
-    pk_inf = pk_inf | jnp.asarray(lane_pad)
-    hm_ax, hm_ay, hm_inf = g2_jac_to_affine(*hm)
-    sig_ax, sig_ay, sig_inf = g2_jac_to_affine(*agg_sig)
+    pk_inf = pk_inf | jnp.asarray(np.arange(mb) >= m_count)
+    qx2, qy2, q_inf2 = _jit_g2_affine_from_inv(
+        *q, _jit_f2_scale_inv(q[2], inv[mb:])
+    )
 
     xp = jnp.concatenate([ngx, pk_ax], axis=0)
     yp = jnp.concatenate([ngy, pk_ay], axis=0)
     p_inf = jnp.concatenate([ng_inf, pk_inf], axis=0)
-    qx2 = jnp.concatenate([sig_ax, hm_ax], axis=0)
-    qy2 = jnp.concatenate([sig_ay, hm_ay], axis=0)
-    q_inf2 = jnp.concatenate([sig_inf, hm_inf], axis=0)
     return bool(multi_pairing_check_device(xp, yp, p_inf, qx2, qy2, q_inf2))

@@ -6,12 +6,9 @@ updates as ONE jitted pass over flat uint64 arrays — integer-only
 (consensus-grade, no floats), shape-stable per validator count, epoch
 scalars traced (no per-epoch recompiles).
 
-uint64 requires JAX x64 mode, which is process-global and changes trace
-cache keys for unrelated kernels. Importing this module therefore enables
-x64 for the WHOLE process — use it from a dedicated process (the
-LIGHTHOUSE_TPU_DEVICE_EPOCH_SWEEP=1 node flag, the parity tests'
-subprocess, or a bench fork), never from one sharing compiles with the
-uint32 crypto kernels.
+uint64 needs JAX's x64 mode. `epoch_sweep` turns it on only for its own
+trace and call (`with jax.enable_x64(True)`), so the uint32/int32 crypto
+kernels that share the process keep their default dtypes.
 
 Parity contract: bit-exact equality with the numpy sweep in
 state_processing/altair.py for every input where the u64 overflow guard
@@ -22,11 +19,8 @@ that guard and keep such states on the host bigint path.
 from __future__ import annotations
 
 import jax
-
-jax.config.update("jax_enable_x64", True)
-
-import jax.numpy as jnp  # noqa: E402
-from jax import jit  # noqa: E402
+import jax.numpy as jnp
+import numpy as np
 
 # altair participation flag weights (TIMELY_SOURCE/TARGET/HEAD)
 PARTICIPATION_FLAG_WEIGHTS = (14, 26, 14)
@@ -35,8 +29,17 @@ TIMELY_TARGET_FLAG_INDEX = 1
 TIMELY_HEAD_FLAG_INDEX = 2
 
 
-@jit
-def epoch_sweep(
+def epoch_sweep(*arrays):
+    """Run the sweep with x64 on for this call only; returns numpy
+    (new_balances, new_scores) uint64 arrays."""
+    with jax.enable_x64(True):
+        new_balances, new_scores = _epoch_sweep(*arrays)
+        # contiguous: a device array may come back with strided host layout
+        return np.ascontiguousarray(new_balances), np.ascontiguousarray(new_scores)
+
+
+@jax.jit
+def _epoch_sweep(
     effective_balance,  # [n] u64
     slashed,  # [n] bool
     activation_epoch,  # [n] u64

@@ -34,9 +34,9 @@ from ..crypto.bls12_381.fields import R as FR_MOD
 from .bls381 import (
     BASE,
     MASK,
-    _carry_normalize,
-    _conv_full,
-    _conv_low,
+    _mod_add,
+    _mod_sub,
+    _mont_mul,
 )
 
 NLIMB_FR = 32  # 32 × 8-bit limbs = 256 bits
@@ -67,36 +67,21 @@ _INV_EXP_BITS = np.array(
 )
 
 
-def _cond_sub_r(x):
-    """x normalized in [0, 2r) → x mod r (same trick as bls381._cond_sub_p)."""
-    s = x + jnp.asarray(_RBAR_LIMBS)
-    s = _carry_normalize(s, NLIMB_FR + 1, shrink_passes=2)
-    ge = s[..., NLIMB_FR] > 0
-    return jnp.where(ge[..., None], s[..., :NLIMB_FR], x)
+_UNIT_FR = _int_to_limbs(1)
+_UNIT_PLUS_R = _UNIT_FR + _R_LIMBS  # limbwise; normalized with the sum
 
 
 def fr_mul(a, b):
     """Montgomery product a·b·R⁻¹ mod r over [..., 32] int32 limbs."""
-    t = _conv_full(a, b)
-    t = _carry_normalize(t, 2 * NLIMB_FR)
-    m = _conv_low(t[..., :NLIMB_FR], jnp.asarray(_NPRIME_LIMBS))
-    m = _carry_normalize(m, NLIMB_FR)
-    mp = _carry_normalize(_conv_full(m, jnp.asarray(_R_LIMBS)), 2 * NLIMB_FR)
-    s = _carry_normalize(t + mp, 2 * NLIMB_FR, shrink_passes=2)
-    return _cond_sub_r(s[..., NLIMB_FR:])
+    return _mont_mul(a, b, _R_LIMBS, _NPRIME_LIMBS, jnp.asarray(_RBAR_LIMBS))
 
 
 def fr_add(a, b):
-    v = _carry_normalize(a + b, NLIMB_FR, shrink_passes=2)
-    return _cond_sub_r(v)
+    return _mod_add(a, b, jnp.asarray(_RBAR_LIMBS))
 
 
 def fr_sub(a, b):
-    comp_b = MASK - b
-    v = a + comp_b + jnp.asarray(_R_LIMBS)
-    v = v.at[..., 0].add(1)
-    v = _carry_normalize(v, NLIMB_FR + 1, shrink_passes=2)
-    return _cond_sub_r(v[..., :NLIMB_FR])
+    return _mod_sub(a, b, jnp.asarray(_UNIT_FR), jnp.asarray(_UNIT_PLUS_R))
 
 
 def fr_inv(a):

@@ -32,11 +32,20 @@ def _sha256_pairs_inline(nodes):
 
 
 def _reduce_to_root(nodes, depth: int):
-    """Hash [2^depth, 8] down to [1, 8] with a static loop (depth is a
-    compile-time constant — XLA unrolls into `depth` batched compressions)."""
-    for _ in range(depth):
-        nodes = _sha256_pairs_inline(nodes)
-    return nodes
+    """Hash [2^depth, 8] down to [1, 8]: one fori_loop over the levels on a
+    fixed-shape buffer (each level hashes the whole buffer's pairs and
+    re-pads with zeros; row 0.. holds the live level), so the program
+    holds one compression pair whatever the depth. An unrolled level loop
+    compiles one program per level into the step."""
+    if depth == 0:
+        return nodes
+    half = nodes.shape[0] // 2
+
+    def level(_, buf):
+        parents = _sha256_pairs_inline(buf)  # [half, 8]
+        return jnp.concatenate([parents, jnp.zeros_like(parents)], axis=0)
+
+    return lax.fori_loop(0, depth, level, nodes)[:1]
 
 
 def sharded_merkle_root_fn(mesh: Mesh, per_device_leaves: int, n_devices: int):
@@ -56,14 +65,12 @@ def sharded_merkle_root_fn(mesh: Mesh, per_device_leaves: int, n_devices: int):
         )  # [n_devices, 8]
         return _reduce_to_root(roots, top_depth)  # [1, 8]
 
-    from jax.experimental.shard_map import shard_map
-
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=P("batch", None),
         out_specs=P("batch", None),  # each device emits the (identical) root
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

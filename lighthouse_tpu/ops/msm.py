@@ -2,22 +2,24 @@
 
 The commitment-scale MSM (Σ sᵢ·Pᵢ, 4096 Lagrange setup points per blob —
 crypto/kzg/src/lib.rs:110 `blob_to_kzg_commitment`, SURVEY §2.7-2/§7
-step 2) bucketized exactly like blst's Pippenger, laid out TPU-first:
+step 2), bucketized like blst's Pippenger and laid out so that every
+compiled program holds only a few point additions:
 
-  * scalar digit decomposition + per-window counting sort happen on the
-    HOST (numpy argsort over [nwin, n] uint8 digits — microseconds, and
-    the scalars live on the host anyway);
-  * the device does what it is good at: one gather to put each window's
-    points in bucket order, a log-depth SEGMENTED tree scan (the bucket
-    sums of a counting-sorted array are segment sums — computed with
-    `lax.associative_scan` over the standard segmented-add monoid,
-    vectorized point adds all the way down), a reverse suffix scan for
-    the Σ j·Bⱼ running-sum trick, and four doublings per window for the
-    Horner combine.
+  * the HOST splits each scalar into 4-bit window digits and writes, for
+    every (window, nonzero digit) bucket, the indices of its points into a
+    fixed-shape table [windows × 15 buckets, slots] padded with an
+    infinity index (numpy — the scalars live on the host anyway). A
+    bucket fuller than `slots` (skewed scalars) spills into further
+    rounds of the same table shape;
+  * the DEVICE gathers the table's points and folds each bucket's slots
+    with one add per step (960 bucket lanes), then forms Σ_b b·B_b per
+    window by the running-sum trick and combines the windows by Horner
+    (four doublings per window).
 
-Per 4096-point MSM with 4-bit windows: 64 windows × (~2·log n segmented
-combines + ~8 small lane ops + 4 doublings) — ~500k lane point-adds of
-work at log sequential depth, vs 2M for per-point ladders.
+Shapes depend only on the number of points, so one set of compiled
+programs serves every call on a setup. (An associative-scan formulation
+of the bucket sums unrolls dozens of point additions into one program and
+does not compile in bounded time for the TPU.)
 
 Points are Jacobian [n, 48] Montgomery limb arrays (ops/bls381 layout);
 infinity is Z == 0, so masking is free.
@@ -25,180 +27,97 @@ infinity is Z == 0, so masking is free.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .bls381 import (
-    NLIMB,
-    DevFq,
-    _ONE_MONT,
-    g1_points_from_device,
-    pt_add,
-    pt_double,
-)
+from .bls381 import NLIMB, DevFq, fold_sum, g1_points_from_device, pt_add, pt_double
 
 WINDOW = 4  # digit bits; 64 windows cover 255-bit Fr scalars
 NBITS = 256  # scalars are reduced mod r < 2^255; one spare window bit
+NWIN = NBITS // WINDOW
+NBUCKETS = (1 << WINDOW) - 1  # nonzero digits
 
 
-def _host_digit_prep(scalars, window: int):
-    """digits → (order, seg_start, last_idx, present) numpy arrays."""
-    n = len(scalars)
-    nwin = (NBITS + window - 1) // window
-    ndig = 1 << window
-    digits = np.zeros((nwin, n), dtype=np.int32)
-    for i, s in enumerate(scalars):
-        for w in range(nwin):
-            digits[w, i] = (s >> (w * window)) & (ndig - 1)
-    order = np.argsort(digits, axis=1, kind="stable").astype(np.int32)
-    sd = np.take_along_axis(digits, order, axis=1)
-    seg_start = np.zeros((nwin, n), dtype=bool)
-    seg_start[:, 0] = True
-    seg_start[:, 1:] = sd[:, 1:] != sd[:, :-1]
-    # last occurrence of each nonzero digit d in the sorted row
-    last_idx = np.zeros((nwin, ndig - 1), dtype=np.int32)
-    present = np.zeros((nwin, ndig - 1), dtype=bool)
-    for w in range(nwin):
-        row = sd[w]
-        # searchsorted: row is ascending; last index of d = right_bound - 1
-        rb = np.searchsorted(row, np.arange(1, ndig), side="right")
-        lb = np.searchsorted(row, np.arange(1, ndig), side="left")
-        present[w] = rb > lb
-        last_idx[w] = np.maximum(rb - 1, 0)
-    return order, seg_start, last_idx, present
+def _slots(n: int) -> int:
+    """Table width: ~1.5× the mean bucket load, a power of two ≥ 8."""
+    want = max(8, -(-3 * n // (2 * NBUCKETS)))
+    return 1 << (want - 1).bit_length()
 
 
-def _seg_combine(a, b):
-    """Segmented-sum monoid: (flag, point) pairs; b is closer to the end."""
-    fa, xa, ya, za = a
-    fb, xb, yb, zb = b
-    added = pt_add(DevFq, (xa, ya, za), (xb, yb, zb))
-    x = DevFq.select(fb, xb, added[0])
-    y = DevFq.select(fb, yb, added[1])
-    z = DevFq.select(fb, zb, added[2])
-    return (fa | fb, x, y, z)
+def bucket_tables(scalars, n: int) -> np.ndarray:
+    """[rounds, NWIN·NBUCKETS, slots] int32 point indices; index n is the
+    infinity pad. Row w·NBUCKETS + (d−1) lists the points whose window-w
+    digit is d."""
+    raw = b"".join(int(s).to_bytes(NBITS // 8, "little") for s in scalars)
+    by = np.frombuffer(raw, dtype=np.uint8).reshape(n, NBITS // 8)
+    digits = np.stack([by & 0xF, by >> 4], axis=-1).reshape(n, NWIN).T  # [w, n]
+    slots = _slots(n)
+    order = np.argsort(digits, axis=1, kind="stable")
+    sd = np.take_along_axis(digits, order, axis=1).astype(np.int64)
+    # rank of each sorted entry inside its (window, digit) bucket
+    first = np.stack(
+        [np.searchsorted(row, row, side="left") for row in sd]
+    )  # first index of this entry's digit value in the sorted row
+    rank = np.arange(n)[None, :] - first
+    live = sd > 0
+    rounds = max(1, int(-(-(rank[live].max(initial=-1) + 1) // slots)))
+    table = np.full((rounds, NWIN * NBUCKETS, slots), n, dtype=np.int32)
+    w_idx = np.broadcast_to(np.arange(NWIN)[:, None], sd.shape)
+    r = rank[live]
+    table[r // slots, w_idx[live] * NBUCKETS + sd[live] - 1, r % slots] = order[live]
+    return table
 
 
-def _inf_like(shape):
-    one = jnp.broadcast_to(jnp.asarray(_ONE_MONT), (*shape, NLIMB)).astype(
-        jnp.int32
-    )
+def _infinity(shape):
+    from .bls381 import _ONE_MONT
+
+    one = jnp.broadcast_to(jnp.asarray(_ONE_MONT), (*shape, NLIMB)).astype(jnp.int32)
     return (one, one, jnp.zeros((*shape, NLIMB), dtype=jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("window",))
-def msm_pippenger_device(xs, ys, zs, order, seg_start, last_idx, present,
-                         window: int = WINDOW):
-    """Σ sᵢ·Pᵢ. Point arrays [n, 48]; index arrays from _host_digit_prep.
-    Returns a single Jacobian point ([48], [48], [48])."""
-    nwin = order.shape[0]
-    ndig_m1 = last_idx.shape[1]
-
-    def body(i, acc):
-        w = nwin - 1 - i  # MSB window first (Horner)
-        for _ in range(window):
-            acc = pt_double(DevFq, acc)
-        # gather this window's points into bucket (counting-sorted) order
-        idx = order[w]
-        pw = (
-            jnp.take(xs, idx, axis=0),
-            jnp.take(ys, idx, axis=0),
-            jnp.take(zs, idx, axis=0),
-        )
-        flags = seg_start[w]
-        f, bx, by, bz = lax.associative_scan(
-            _seg_combine, (flags, *pw), axis=0
-        )
-        # bucket sums = scan value at each segment's last element
-        li = last_idx[w]
-        bkt = (
-            jnp.take(bx, li, axis=0),
-            jnp.take(by, li, axis=0),
-            jnp.take(bz, li, axis=0),
-        )
-        pres = present[w]
-        bkt = (
-            bkt[0],
-            bkt[1],
-            DevFq.select(pres, bkt[2], jnp.zeros_like(bkt[2])),
-        )
-        # Σ j·Bⱼ via the running-sum trick: reverse inclusive scan then sum
-        def add_combine(a, b):
-            return pt_add(DevFq, a, b)
-
-        running = lax.associative_scan(add_combine, bkt, axis=0, reverse=True)
-        # tree-sum the running sums (ndig-1 lanes, pad to power of two)
-        pad = 1
-        while pad < ndig_m1:
-            pad *= 2
-        if pad != ndig_m1:
-            pinf = _inf_like((pad - ndig_m1,))
-            running = tuple(
-                jnp.concatenate([r, p], axis=0) for r, p in zip(running, pinf)
-            )
-        m = pad
-        while m > 1:
-            half = m // 2
-            running = pt_add(
-                DevFq,
-                tuple(c[:half] for c in running),
-                tuple(c[half : 2 * half] for c in running),
-            )
-            m = half
-        wsum = tuple(c[0] for c in running)
-        return pt_add(DevFq, acc, wsum)
-
-    acc = tuple(c[0] for c in _inf_like((1,)))
-    return lax.fori_loop(0, nwin, body, acc)
-
-
-def g1_msm_pippenger(scalars, points_dev, window: int = WINDOW):
-    order, seg_start, last_idx, present = _host_digit_prep(scalars, window)
-    x, y, z = msm_pippenger_device(
-        *points_dev,
-        jnp.asarray(order),
-        jnp.asarray(seg_start),
-        jnp.asarray(last_idx),
-        jnp.asarray(present),
-        window=window,
+@jax.jit
+def _bucket_round(ax, ay, az, xs, ys, zs, table):
+    """acc [L] + the points table [L, S] names (index n = infinity)."""
+    pts = tuple(
+        jnp.concatenate([c, jnp.zeros_like(c[:1])], axis=0) for c in (xs, ys, zs)
     )
-    return g1_points_from_device((x[None], y[None], z[None]))[0]
+    return fold_sum(DevFq, (ax, ay, az), tuple(jnp.take(c, table, axis=0) for c in pts))
 
 
-def g1_msm_ladder(scalars, points_dev):
-    """Ladder MSM: per-point 256-bit double-and-add (ops/bls381
-    batch_g1_scalar_mul) then one log-depth tree sum. ~4× the point-add
-    work of Pippenger but a tiny, already-cached kernel graph — the
-    robust default while Pippenger's larger graph compiles only where a
-    real compile service exists (see LIGHTHOUSE_TPU_MSM)."""
-    from .bls381 import batch_g1_scalar_mul, g1_sum_reduce, scalars_to_bits
+@jax.jit
+def _window_combine(bx, by, bz):
+    """Bucket sums [NWIN, NBUCKETS] (digit d at column d−1) → Σ sᵢ·Pᵢ [1]."""
+    nwin, nb = bx.shape[:2]
 
-    bits = jnp.asarray(scalars_to_bits(scalars, NBITS))
-    scaled = batch_g1_scalar_mul(*points_dev, bits)
-    x, y, z = g1_sum_reduce(*scaled)
-    return g1_points_from_device((x, y, z))[0]
+    def running_sum(k, carry):
+        running, total = carry
+        col = tuple(
+            lax.dynamic_index_in_dim(c, nb - 1 - k, axis=1, keepdims=False)
+            for c in (bx, by, bz)
+        )
+        running = pt_add(DevFq, running, col)
+        return running, pt_add(DevFq, total, running)
+
+    inf = _infinity((nwin,))
+    _, wsum = lax.fori_loop(0, nb, running_sum, (inf, inf))  # Σ_d d·B_d
+
+    def horner(i, acc):
+        acc = lax.fori_loop(0, WINDOW, lambda _, a: pt_double(DevFq, a), acc)
+        w = tuple(lax.dynamic_slice_in_dim(c, nwin - 1 - i, 1) for c in wsum)
+        return pt_add(DevFq, acc, w)
+
+    return lax.fori_loop(0, nwin, horner, _infinity((1,)))
 
 
-def g1_msm_device(scalars, points_dev, window: int = WINDOW):
+def g1_msm_device(scalars, points_dev):
     """Host entry: scalars (list[int] mod r) × device points → host
     Jacobian int tuple. `points_dev` = (xs, ys, zs) [n, 48] arrays (keep
-    the setup resident on device across calls — see TrustedSetup).
-    Implementation: LIGHTHOUSE_TPU_MSM = pippenger | ladder (default
-    pippenger on a real accelerator, ladder on the CPU test platform
-    where the bucketized kernel's compile takes tens of minutes)."""
-    import os
-
-    choice = os.environ.get("LIGHTHOUSE_TPU_MSM")
-    if choice is None:
-        import jax
-
-        choice = (
-            "ladder" if jax.default_backend() == "cpu" else "pippenger"
-        )
-    if choice == "pippenger":
-        return g1_msm_pippenger(scalars, points_dev, window)
-    return g1_msm_ladder(scalars, points_dev)
+    the setup resident on device across calls — see TrustedSetup)."""
+    n = points_dev[0].shape[0]
+    acc = _infinity((NWIN * NBUCKETS,))
+    for table in bucket_tables(scalars, n):
+        acc = _bucket_round(*acc, *points_dev, jnp.asarray(table))
+    b = tuple(c.reshape(NWIN, NBUCKETS, NLIMB) for c in acc)
+    return g1_points_from_device(_window_combine(*b))[0]

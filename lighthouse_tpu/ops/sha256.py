@@ -127,9 +127,11 @@ def merkle_tree_levels(leaves):
     # Finish on host (batched host hasher; ≤ _HOST_TOP rows per level).
     from ..utils.sha256_batch import hash_rows
 
+    # order="C": a device array may come back with strided host layout,
+    # and the byte view below needs a contiguous row
     host = np.asarray(nodes)
     while host.shape[0] > 1:
-        rows = host.astype(">u4").view(np.uint8).reshape(-1, 64)
+        rows = host.astype(">u4", order="C").view(np.uint8).reshape(-1, 64)
         host = (
             np.ascontiguousarray(hash_rows(rows))
             .view(">u4")
@@ -164,7 +166,7 @@ def device_hash_rows(pairs: np.ndarray) -> np.ndarray:
         np.ascontiguousarray(pairs).view(">u4").astype(np.uint32).reshape(m, 16)
     )
     dig = np.asarray(sha256_pairs(words))[:m]
-    return dig.astype(">u4").view(np.uint8).reshape(m, 32)
+    return dig.astype(">u4", order="C").view(np.uint8).reshape(m, 32)
 
 
 def bytes_to_words(data: bytes) -> np.ndarray:

@@ -65,7 +65,13 @@ _REBUILD_FRACTION = 0.5
 _DEVICE_BUILD_THRESHOLD = 1 << 11
 
 # instrumentation (read by the perf_smoke suite and the bench breakdown)
-_STATS = {"rows_hashed": 0, "full_extracts": 0, "sparse_updates": 0, "rebuilds": 0}
+_STATS = {
+    "rows_hashed": 0,
+    "full_extracts": 0,
+    "sparse_updates": 0,
+    "rebuilds": 0,
+    "device_builds": 0,
+}
 
 
 def stats() -> dict:
@@ -81,12 +87,9 @@ def _hash_rows(pairs: np.ndarray) -> np.ndarray:
 def _device_tree_enabled() -> bool:
     if os.environ.get("LIGHTHOUSE_TPU_DEVICE_TREE") != "1":
         return False
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend() != "cpu"
-    except Exception:  # noqa: BLE001 — no jax: host path
-        return False
+    return jax.default_backend() != "cpu"
 
 
 def _build_layers(leaves: np.ndarray) -> list[np.ndarray]:
@@ -104,25 +107,23 @@ def _build_layers(leaves: np.ndarray) -> list[np.ndarray]:
         # read-only flag of) the caller's buffer
         leaves = np.array(leaves, dtype=np.uint8, copy=True)
     if full >= _DEVICE_BUILD_THRESHOLD and _device_tree_enabled():
-        try:
-            import jax
+        import jax
 
-            from ..ops.sha256 import bytes_to_words, merkle_tree_levels
+        from ..ops.sha256 import bytes_to_words, merkle_tree_levels
 
-            words = bytes_to_words(leaves.tobytes())
-            levels = merkle_tree_levels(jax.device_put(words))
-            # levels: [root, ..., leaves] as [m, 8] u32 big-endian words
-            return [
-                # astype(copy=True, order="C") guarantees a fresh contiguous
-                # array — device_get may hand back strided views
-                np.asarray(jax.device_get(lv))
-                .astype(">u4", order="C")
-                .view(np.uint8)
-                .reshape(-1, 32)
-                for lv in reversed(levels)
-            ]
-        except Exception:  # noqa: BLE001 — device refused: host batched path
-            pass
+        _STATS["device_builds"] += 1
+        words = bytes_to_words(leaves.tobytes())
+        levels = merkle_tree_levels(jax.device_put(words))
+        # levels: [root, ..., leaves] as [m, 8] u32 big-endian words
+        return [
+            # astype(copy=True, order="C") guarantees a fresh contiguous
+            # array — device_get may hand back strided views
+            np.asarray(jax.device_get(lv))
+            .astype(">u4", order="C")
+            .view(np.uint8)
+            .reshape(-1, 32)
+            for lv in reversed(levels)
+        ]
     layers = [leaves]
     cur = leaves
     while cur.shape[0] > 1:

@@ -959,9 +959,8 @@ def process_historical_summaries_update(state, E):
 
 def _device_sweep_enabled() -> bool:
     """LIGHTHOUSE_TPU_DEVICE_EPOCH_SWEEP=1 routes the fused rewards/
-    inactivity pass through the jitted device kernel (ops/epoch_sweep).
-    Importing that module enables JAX x64 process-wide, so the flag
-    belongs on dedicated node/bench processes (see the module docstring)."""
+    inactivity pass through the jitted device kernel (ops/epoch_sweep),
+    which scopes JAX's x64 mode to its own call."""
     import os
 
     return os.environ.get("LIGHTHOUSE_TPU_DEVICE_EPOCH_SWEEP") == "1"
@@ -987,10 +986,10 @@ def _device_sweep_applicable(state, arrays: EpochArrays, spec, E) -> bool:
 def _device_rewards_and_inactivity(state, spec: ChainSpec, E, fork: ForkName, arrays):
     """Fused device pass replacing process_inactivity_updates +
     process_rewards_and_penalties_altair (bit-exact parity is enforced by
-    tests/test_device_epoch_sweep.py in an isolated x64 process)."""
+    tests/test_device_epoch_sweep.py)."""
     import numpy as _np
 
-    from ..ops.epoch_sweep import epoch_sweep  # enables x64 on import
+    from ..ops.epoch_sweep import epoch_sweep
     from .per_epoch import get_finality_delay
 
     current = get_current_epoch(state, E)
@@ -1038,8 +1037,8 @@ def _device_rewards_and_inactivity(state, spec: ChainSpec, E, fork: ForkName, ar
     # ONE bulk device→host transfer each (per-element int() would sync
     # once per validator); the store helpers diff against the resident
     # columns so only changed rows hit the persistent lists
-    arrays.store_inactivity_scores(state, _np.asarray(new_scores))
-    arrays.store_balances(state, _np.asarray(new_balances))
+    arrays.store_inactivity_scores(state, new_scores)
+    arrays.store_balances(state, new_balances)
 
 
 def process_epoch_altair(state, spec: ChainSpec, E, fork: ForkName):

@@ -1,10 +1,14 @@
 """Shared JAX persistent-compile-cache configuration + observability.
 
-Pairing-class kernels take minutes to compile on this image's XLA-CPU;
-every entry point (tests, bench, driver dryrun) must point at the same
-on-disk cache so compiles amortize across processes. Keep the settings
-here — the one place — and call `enable_compile_cache()` before kernels
-are traced.
+Pairing-class kernels take minutes to compile cold; every entry point
+(the node, tests, bench, chip_smoke.py) points at the same on-disk cache
+so compiles amortize across processes. Keep the settings here — the one
+place — and call `enable_compile_cache()` before kernels are traced.
+
+Where the cache lives: `JAX_COMPILATION_CACHE_DIR` when the environment
+sets it (JAX reads it itself, and no directory is set in code), otherwise
+the fixed `<repo>/.jax_cache`. The path is part of what makes a cache hit,
+so it never moves with the working directory.
 
 Observability: `track_device_compile(kernel)` wraps a first (compiling)
 invocation in a `device_compile` trace span and classifies it as a cache
@@ -51,16 +55,26 @@ _COMPILE_SECONDS = REGISTRY.counter(
 _COMPILE_SECONDS.inc(0)
 
 
-def enable_compile_cache(cache_dir: str | None = None):
+def enable_compile_cache():
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir or CACHE_DIR)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs", MIN_PERSIST_SECS
     )
 
 
-def _cache_entries(cache_dir: str) -> int:
+def active_cache_dir() -> str | None:
+    """The persistent cache directory JAX uses now (None when off)."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir or None
+
+
+def _cache_entries(cache_dir: str | None) -> int:
+    if cache_dir is None:
+        return 0
     try:
         return len(os.listdir(cache_dir))
     except OSError:
@@ -68,7 +82,7 @@ def _cache_entries(cache_dir: str) -> int:
 
 
 @contextmanager
-def track_device_compile(kernel: str, cache_dir: str | None = None):
+def track_device_compile(kernel: str):
     """Wrap a warmup/first invocation of a device kernel: opens a
     `device_compile` span (so the compile shows up inside whatever trace
     is active — the device bench partials' compile phase) and counts a
@@ -82,7 +96,7 @@ def track_device_compile(kernel: str, cache_dir: str | None = None):
     instrumented path exists for."""
     from .tracing import span
 
-    cache_dir = cache_dir or CACHE_DIR
+    cache_dir = active_cache_dir()
     before = _cache_entries(cache_dir)
     t0 = time.perf_counter()
     with span("device_compile", kernel=kernel):

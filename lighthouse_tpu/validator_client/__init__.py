@@ -331,15 +331,10 @@ class LocalBeaconNode(BeaconNodeInterface):
         return self.chain.get_aggregated_attestation(data)
 
     def publish_aggregates(self, signed_aggregates):
-        """Per-item: one rejected aggregate (e.g. the aggregator-seen
-        dedup) must not drop the valid ones behind it."""
-        out = []
-        for agg in signed_aggregates:
-            try:
-                out.append(self.chain.process_aggregate(agg))
-            except Exception as e:  # noqa: BLE001
-                out.append(e)
-        return out
+        """One signature batch for the slot's aggregates; results stay
+        per item — one rejected aggregate (e.g. the aggregator-seen dedup)
+        never drops the valid ones beside it."""
+        return self.chain.process_aggregate_batch(signed_aggregates)
 
     def attester_duties(self, epoch: int, indices) -> list:
         """Bulk duties via the epoch duty table (inverse shuffling +

@@ -430,3 +430,35 @@ def test_chain_altair_real_crypto():
         assert h.chain.justified_checkpoint.epoch >= 2
     finally:
         bls.set_backend("fake_crypto")
+
+
+def test_device_sweep_scopes_x64_to_its_call():
+    """The uint64 epoch sweep turns x64 on for its own call only: the
+    uint32/int32 crypto kernels sharing the process keep their dtypes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from lighthouse_tpu.ops import bls381
+    from lighthouse_tpu.ops.epoch_sweep import epoch_sweep
+
+    n = 8
+    u64 = lambda v: np.full(n, v, dtype=np.uint64)  # noqa: E731
+    balances, scores = epoch_sweep(
+        u64(32 * 10**9),
+        np.zeros(n, dtype=bool),
+        u64(0),
+        u64(2**64 - 1),
+        u64(2**64 - 1),
+        np.full(n, 0b111, dtype=np.uint8),
+        u64(0),
+        u64(32 * 10**9),
+        np.array([1, 2, 64, n * 32, 0, 4, 16, 4 * 2**24, 10**9], dtype=np.uint64),
+    )
+    assert balances.dtype == np.uint64 and scores.dtype == np.uint64
+    assert (balances > 32 * 10**9).all()  # full participation earns rewards
+    assert not jax.config.jax_enable_x64
+    limbs = jnp.asarray(bls381.fq_to_device([1, 2]))
+    out = jax.eval_shape(bls381.mont_mul, limbs, limbs)
+    assert limbs.dtype == jnp.int32 and out.dtype == jnp.int32
+    assert jnp.asarray(np.arange(3, dtype=np.int64)).dtype == jnp.int32

@@ -403,3 +403,45 @@ def test_altair_and_electra_states_use_cache_and_match_plain_roots():
         cp.balances[0] = 1
         assert cp.hash_tree_root() != snap
         assert state.hash_tree_root() == snap
+
+
+def _big_leaves(seed: int) -> np.ndarray:
+    from lighthouse_tpu.ssz import cached_tree_hash as cth
+
+    rng = np.random.default_rng(seed)
+    n = cth._DEVICE_BUILD_THRESHOLD
+    return rng.integers(0, 256, size=(n, 32), dtype=np.uint8)
+
+
+def test_device_tree_build_matches_host_layers(monkeypatch):
+    """The device lane (forced on here, on whatever backend runs the
+    tests) builds the same layers as the batched host hasher, and counts
+    itself."""
+    from lighthouse_tpu.ssz import cached_tree_hash as cth
+
+    leaves = _big_leaves(21)
+    host = cth._build_layers(leaves)
+    monkeypatch.setattr(cth, "_device_tree_enabled", lambda: True)
+    before = cth.stats()["device_builds"]
+    dev = cth._build_layers(leaves)
+    assert cth.stats()["device_builds"] == before + 1
+    assert len(dev) == len(host)
+    for d, h in zip(dev, host):
+        assert np.array_equal(d, h)
+
+
+def test_device_tree_build_failure_raises(monkeypatch):
+    """A device failure in the tree build surfaces; the host hasher never
+    quietly takes over."""
+    from lighthouse_tpu.ops import sha256
+    from lighthouse_tpu.ssz import cached_tree_hash as cth
+
+    def refuse(leaves):
+        raise RuntimeError("device tree kernel refused")
+
+    monkeypatch.setattr(cth, "_device_tree_enabled", lambda: True)
+    monkeypatch.setattr(sha256, "merkle_tree_levels", refuse)
+    rows = cth.stats()["rows_hashed"]
+    with pytest.raises(RuntimeError, match="refused"):
+        cth._build_layers(_big_leaves(22))
+    assert cth.stats()["rows_hashed"] == rows

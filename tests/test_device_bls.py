@@ -81,18 +81,6 @@ def test_g1_batch_scalar_mul():
 
 
 @pytest.mark.slow
-def test_g1_sum_reduce():
-    rng = random.Random(3)
-    pts = [pt_mul(FQ, G1_GEN, rng.randrange(1, 10**9)) for _ in range(8)]
-    xs, ys, zs = D.g1_points_to_device(pts)
-    got = D.g1_points_from_device(D.g1_sum_reduce(xs, ys, zs))[0]
-    want = pts[0]
-    for p in pts[1:]:
-        want = pt_add(FQ, want, p)
-    assert pt_eq(FQ, got, want)
-
-
-@pytest.mark.slow
 def test_g2_batch_scalar_mul():
     rng = random.Random(4)
     pts = [pt_mul(FQ2, G2_GEN, rng.randrange(1, 10**9)) for _ in range(8)]
@@ -109,14 +97,15 @@ def test_device_verify_signature_sets():
     import hashlib
 
     from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.ops.bls381_verify import verify_signature_sets_device_full
 
     bls.set_backend("host")
     kps = bls.interop_keypairs(8)
     msg = hashlib.sha256(b"device batch").digest()
     sets = [bls.SignatureSet.single(kp.sk.sign(msg), kp.pk, msg) for kp in kps]
-    assert D.verify_signature_sets_device(sets, random.Random(5))
+    assert verify_signature_sets_device_full(sets, random.Random(5))
     bad = list(sets)
     bad[3] = bls.SignatureSet.single(
         sets[4].signature, sets[3].pubkeys[0], sets[3].message
     )
-    assert not D.verify_signature_sets_device(bad, random.Random(6))
+    assert not verify_signature_sets_device_full(bad, random.Random(6))

@@ -1,97 +1,54 @@
 """Device epoch sweep parity (single_pass.rs:20 on device, SURVEY §7.3).
 
 The fused jitted rewards/inactivity pass must be BIT-EXACT against the
-numpy reference sweep. x64 mode is process-global, so the device run
-happens in an isolated subprocess (same pattern as the multichip
-dryrun); the oracle runs here."""
+numpy reference sweep. The sweep scopes x64 to its own call, so both
+sides run in this process."""
 
-import json
-import os
-import subprocess
-import sys
 from dataclasses import replace
 
 import pytest
 
-from lighthouse_tpu.crypto import bls
 from lighthouse_tpu.beacon_chain.harness import BeaconChainHarness
-from lighthouse_tpu.types.chain_spec import minimal_spec
-from lighthouse_tpu.types.eth_spec import MinimalEthSpec as E
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# every test in this file is tier-2: device sweep — slow XLA-CPU compile.
-# tests/conftest.py enforces this marker at collection time.
-pytestmark = pytest.mark.slow
-
-_SUBPROC = r"""
-import json, os, sys
-sys.path.insert(0, sys.argv[1])
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
-os.environ["LIGHTHOUSE_TPU_DEVICE_EPOCH_SWEEP"] = "1"
-import jax
-jax.config.update("jax_platforms", "cpu")
-from dataclasses import replace
 from lighthouse_tpu.crypto import bls
-bls.set_backend("fake_crypto")
-from lighthouse_tpu.beacon_chain.harness import BeaconChainHarness
-from lighthouse_tpu.types.chain_spec import minimal_spec
-from lighthouse_tpu.types.eth_spec import MinimalEthSpec as E
 from lighthouse_tpu.state_processing import per_slot_processing
 from lighthouse_tpu.state_processing.altair import (
     EpochArrays,
     _device_sweep_applicable,
     _device_sweep_enabled,
 )
+from lighthouse_tpu.types.chain_spec import minimal_spec
+from lighthouse_tpu.types.eth_spec import MinimalEthSpec as E
 
-assert _device_sweep_enabled()
-spec = replace(minimal_spec(), altair_fork_epoch=0)
-h = BeaconChainHarness(spec, E, validator_count=16)
-h.extend_chain(3 * E.SLOTS_PER_EPOCH)  # real participation + an epoch miss mix
-st = h.chain.head_state.copy()
-# the device path must ACTUALLY run — a vacuous numpy-vs-numpy pass
-# would hide real divergence
-assert _device_sweep_applicable(st, EpochArrays(st, E), spec, E)
-# cross the next epoch boundary: epoch processing runs the DEVICE sweep
-target = (st.slot // E.SLOTS_PER_EPOCH + 1) * E.SLOTS_PER_EPOCH
-while st.slot < target:
-    per_slot_processing(st, spec, E)
-print(json.dumps({
-    "root": st.hash_tree_root().hex(),
-    "balances": [int(b) for b in st.balances][:4],
-    "scores": [int(s) for s in st.inactivity_scores][:4],
-}))
-"""
+# every test in this file is tier-2: device sweep — slow XLA-CPU compile.
+# tests/conftest.py enforces this marker at collection time.
+pytestmark = pytest.mark.slow
 
 
-@pytest.mark.slow
-def test_device_sweep_bit_exact_vs_numpy():
+def _cross_epoch(st, spec):
+    target = (st.slot // E.SLOTS_PER_EPOCH + 1) * E.SLOTS_PER_EPOCH
+    while st.slot < target:
+        per_slot_processing(st, spec, E)
+    return st
+
+
+def test_device_sweep_bit_exact_vs_numpy(monkeypatch):
     bls.set_backend("fake_crypto")
     spec = replace(minimal_spec(), altair_fork_epoch=0)
     h = BeaconChainHarness(spec, E, validator_count=16)
-    h.extend_chain(3 * E.SLOTS_PER_EPOCH)
-    st = h.chain.head_state.copy()
-    from lighthouse_tpu.state_processing import per_slot_processing
+    h.extend_chain(3 * E.SLOTS_PER_EPOCH)  # real participation + a miss mix
+    base = h.chain.head_state
 
-    target = (st.slot // E.SLOTS_PER_EPOCH + 1) * E.SLOTS_PER_EPOCH
-    while st.slot < target:
-        per_slot_processing(st, spec, E)  # numpy sweep (flag unset here)
-    oracle_root = st.hash_tree_root().hex()
+    monkeypatch.delenv("LIGHTHOUSE_TPU_DEVICE_EPOCH_SWEEP", raising=False)
+    oracle = _cross_epoch(base.copy(), spec)  # numpy sweep
 
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    res = subprocess.run(
-        [sys.executable, "-c", _SUBPROC, REPO],
-        capture_output=True,
-        text=True,
-        timeout=600,
-        env=env,
-        cwd=REPO,
-    )
-    assert res.returncode == 0, res.stderr[-2000:]
-    doc = json.loads(res.stdout.strip().splitlines()[-1])
-    assert doc["root"] == oracle_root, (
-        f"device sweep diverged: {doc} vs numpy root {oracle_root}"
-    )
-    assert doc["balances"] == [int(b) for b in st.balances][:4]
-    assert doc["scores"] == [int(s) for s in st.inactivity_scores][:4]
+    monkeypatch.setenv("LIGHTHOUSE_TPU_DEVICE_EPOCH_SWEEP", "1")
+    assert _device_sweep_enabled()
+    st = base.copy()
+    # the device path must ACTUALLY run — a vacuous numpy-vs-numpy pass
+    # would hide real divergence
+    assert _device_sweep_applicable(st, EpochArrays(st, E), spec, E)
+    dev = _cross_epoch(st, spec)
+
+    assert dev.hash_tree_root() == oracle.hash_tree_root()
+    assert list(dev.balances) == list(oracle.balances)
+    assert list(dev.inactivity_scores) == list(oracle.inactivity_scores)

@@ -35,20 +35,10 @@ def setup():
 
 
 @pytest.fixture(scope="module")
-def dev_kzg(setup, monkeypatch_module):
-    monkeypatch_module.setenv("LIGHTHOUSE_TPU_MSM", "ladder")
+def dev_kzg(setup):
     k = Kzg(setup, device=True)
     assert k._dev is not None
     return k
-
-
-@pytest.fixture(scope="module")
-def monkeypatch_module():
-    from _pytest.monkeypatch import MonkeyPatch
-
-    m = MonkeyPatch()
-    yield m
-    m.undo()
 
 
 # ---------------------------------------------------------------------------
@@ -150,31 +140,27 @@ def _host_msm(scalars, points):
     return acc
 
 
-def test_msm_ladder_matches_host(setup, monkeypatch):
+@pytest.mark.parametrize(
+    "kind", ["random", "zero_lanes", "one_skewed_bucket", "extremes"]
+)
+def test_msm_matches_host(setup, kind):
+    """The bucketized kernel on every scalar shape it must survive: zero
+    lanes, every scalar in one bucket (spills into extra table rounds),
+    and r−1 / 1."""
     from lighthouse_tpu.ops.bls381 import g1_points_to_device
-    from lighthouse_tpu.ops.msm import g1_msm_device
-
-    monkeypatch.setenv("LIGHTHOUSE_TPU_MSM", "ladder")
-    pts = setup.g1_lagrange[:8]
-    dev = g1_points_to_device(pts)
-    scalars = [rng.randrange(FR_MODULUS) for _ in range(8)]
-    scalars[3] = 0  # zero lane must not poison the sum
-    got = g1_msm_device(scalars, dev)
-    assert pt_eq(FQ, got, _host_msm(scalars, pts))
-
-
-@pytest.mark.slow
-def test_msm_pippenger_matches_host(setup):
-    """The bucketized kernel (big graph — slow XLA-CPU compile, hence
-    slow-marked; the TPU bench path exercises it warm)."""
-    from lighthouse_tpu.ops.bls381 import g1_points_to_device
-    from lighthouse_tpu.ops.msm import g1_msm_pippenger
+    from lighthouse_tpu.ops.msm import bucket_tables, g1_msm_device
 
     pts = setup.g1_lagrange
     dev = g1_points_to_device(pts)
-    scalars = [rng.randrange(FR_MODULUS) for _ in range(N)]
-    scalars[0] = 0
-    got = g1_msm_pippenger(scalars, dev)
+    scalars = {
+        "random": [rng.randrange(FR_MODULUS) for _ in range(N)],
+        "zero_lanes": [0] * 3 + [rng.randrange(FR_MODULUS) for _ in range(N - 3)],
+        "one_skewed_bucket": [12345] * N,
+        "extremes": [FR_MODULUS - 1] * (N // 2) + [1] * (N // 2),
+    }[kind]
+    if kind == "one_skewed_bucket":
+        assert bucket_tables(scalars, N).shape[0] > 1
+    got = g1_msm_device(scalars, dev)
     assert pt_eq(FQ, got, _host_msm(scalars, pts))
 
 

@@ -185,45 +185,73 @@ def test_da_checker_flow(kzg):
 
 
 # ---------------------------------------------------------------------------
-# device fallback observability + strict mode (LIGHTHOUSE_TPU_STRICT_DEVICE)
+# a device engine fails loudly: no host fallback, at init or per call
 # ---------------------------------------------------------------------------
 
 
 class _ExplodingDev:
-    def boom(self):
-        raise RuntimeError("simulated remote-compile failure")
+    def msm(self, *args):
+        raise RuntimeError("simulated device kernel failure")
+
+    evaluate_batch = quotient = pairing_check = msm
 
 
-def test_device_fallback_is_counted_and_disables_device(kzg, monkeypatch):
-    from lighthouse_tpu.metrics import REGISTRY
-
-    monkeypatch.delenv("LIGHTHOUSE_TPU_STRICT_DEVICE", raising=False)
-    counter = REGISTRY.counter("kzg_device_fallback_total")
-    before = counter.value(stage="call")
-    kzg._dev = _ExplodingDev()
-    kzg._dev_warned = False
-    assert kzg._device_call(lambda d: d.boom()) is None  # host fallback
-    assert counter.value(stage="call") == before + 1
-    assert kzg._dev is None  # device path disabled after the failure
-    assert kzg.verify_blob_kzg_proof_device_stats() == {"device": False}
+@pytest.fixture
+def exploding_kzg():
+    k = Kzg(TrustedSetup.insecure_dev(N), device=False)
+    k._dev = _ExplodingDev()
+    return k
 
 
-def test_device_fallback_strict_mode_raises(kzg, monkeypatch):
-    from lighthouse_tpu.metrics import REGISTRY
+def test_device_failure_on_commit_raises(exploding_kzg):
+    with pytest.raises(RuntimeError, match="simulated device"):
+        exploding_kzg.blob_to_kzg_commitment(_blob(2))
+    # the device engine stays configured: a failure never demotes it
+    assert isinstance(exploding_kzg._dev, _ExplodingDev)
+    assert exploding_kzg.verify_blob_kzg_proof_device_stats() == {"device": True}
 
-    monkeypatch.setenv("LIGHTHOUSE_TPU_STRICT_DEVICE", "1")
-    counter = REGISTRY.counter("kzg_device_fallback_total")
-    before = counter.value(stage="call")
-    kzg._dev = _ExplodingDev()
-    with pytest.raises(KzgError, match="STRICT_DEVICE"):
-        kzg._device_call(lambda d: d.boom())
-    assert counter.value(stage="call") == before + 1  # still observable
+
+def test_device_failure_on_batch_verify_raises(kzg, exploding_kzg):
+    blobs = [_blob(i) for i in range(5, 7)]
+    cs = [kzg.blob_to_kzg_commitment(b) for b in blobs]
+    proofs = [kzg.compute_blob_kzg_proof(b, c) for b, c in zip(blobs, cs)]
+    with pytest.raises(RuntimeError, match="simulated device"):
+        exploding_kzg.verify_blob_kzg_proof_batch(blobs, cs, proofs)
+
+
+def test_device_failure_at_init_raises(monkeypatch):
+    import lighthouse_tpu.crypto.kzg as kzg_mod
+
+    def refuse(setup):
+        raise RuntimeError("simulated device init failure")
+
+    monkeypatch.setattr(kzg_mod, "_DeviceKzg", refuse)
+    with pytest.raises(RuntimeError, match="device init"):
+        Kzg(TrustedSetup.insecure_dev(N), device=True)
+
+
+def test_device_call_noop_when_no_device(kzg):
+    # no device configured at all: the host engine serves the call
     assert kzg._dev is None
-
-
-def test_device_call_noop_when_no_device(kzg, monkeypatch):
-    monkeypatch.setenv("LIGHTHOUSE_TPU_STRICT_DEVICE", "1")
-    kzg._dev = None
-    # no device configured at all is NOT a fallback event: strict mode
-    # only guards a device path that was supposed to be live
     assert kzg._device_call(lambda d: d.boom()) is None
+
+
+def test_mainnet_setup_commits_x_to_tau():
+    """p(X) = X, given by its evaluations on the bit-reversed domain,
+    commits to [τ]G1: the ceremony's Lagrange points line up with the
+    evaluation domain (e([C], G2) == e(G1, [τ]G2))."""
+    from lighthouse_tpu.crypto.bls12_381 import (
+        FQ,
+        G1_GEN,
+        G2_GEN,
+        g1_from_bytes,
+        pairing_check,
+        pt_neg,
+    )
+
+    ts = TrustedSetup.default()
+    if ts.n != 4096:
+        pytest.skip("no ceremony file reachable")
+    blob = b"".join(w.to_bytes(32, "big") for w in ts.roots_brp)
+    c = g1_from_bytes(Kzg(ts).blob_to_kzg_commitment(blob))
+    assert pairing_check([(c, G2_GEN), (pt_neg(FQ, G1_GEN), ts.g2_monomial[1])])

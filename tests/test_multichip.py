@@ -1,9 +1,9 @@
 """Multi-device sharding tests on the 8-device virtual CPU mesh.
 
 conftest.py provisions 8 virtual CPU devices via
---xla_force_host_platform_device_count, the same mechanism the driver's
-dryrun uses (SURVEY.md §2.9: ICI batch sharding is the TPU-native analog of
-the reference's rayon batch parallelism)."""
+--xla_force_host_platform_device_count (SURVEY.md §2.9: ICI batch sharding
+is the TPU-native analog of the reference's rayon batch parallelism); on
+chips, `python chip_smoke.py --four-chips` runs the same kernels."""
 
 import hashlib
 
@@ -60,11 +60,12 @@ def test_sharded_merkle_input_actually_sharded(eight_devices):
     assert len(leaves.sharding.device_set) == n_devices
 
 
-def test_dryrun_multichip_entrypoint():
-    """The driver-facing entry must be green end-to-end (VERDICT r1 weak #1)."""
+def test_dryrun_multichip_entrypoint(eight_devices):
+    """The multi-chip dry-run entry is green end-to-end, in process."""
+    import os
     import sys
 
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     from __graft_entry__ import dryrun_multichip
 
     dryrun_multichip(8)

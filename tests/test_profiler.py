@@ -550,12 +550,14 @@ def test_compile_cache_tracking(tmp_path, monkeypatch):
     secs0 = cc._COMPILE_SECONDS.value()
     cache = tmp_path / "jc"
     cache.mkdir()
+    # the tracker reads the directory JAX is actually using
+    monkeypatch.setattr(cc, "active_cache_dir", lambda: str(cache))
     # no new cache entry → hit
-    with cc.track_device_compile("unit_kernel", cache_dir=str(cache)):
+    with cc.track_device_compile("unit_kernel"):
         pass
     assert cc._HITS.value() == hits0 + 1
     # cache dir grows inside the block → miss + compile seconds
-    with cc.track_device_compile("unit_kernel", cache_dir=str(cache)):
+    with cc.track_device_compile("unit_kernel"):
         (cache / "entry").write_text("x")
         time.sleep(0.01)
     assert cc._MISSES.value() == miss0 + 1
@@ -564,6 +566,29 @@ def test_compile_cache_tracking(tmp_path, monkeypatch):
     assert {"hits", "misses", "compile_seconds"} <= set(stats)
     # the warmup rode a device_compile span (standard metrics path)
     assert REGISTRY.histogram("trace_span_seconds_device_compile").count >= 2
+
+
+@pytest.mark.parametrize("env_dir", [None, "/placed/from/outside"])
+def test_compile_cache_dir_placement(env_dir, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX (no directory
+    is set in code); otherwise the cache sits at the fixed repo path."""
+    import jax
+
+    from lighthouse_tpu.utils import compile_cache as cc
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", env_dir)
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        cc.enable_compile_cache()
+        want = env_dir or cc.CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == want
+        assert cc.active_cache_dir() == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 # -- overhead guard ----------------------------------------------------------

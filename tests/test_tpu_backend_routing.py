@@ -1,21 +1,18 @@
 """_TpuBackend batch-verification routing: the FULL device verifier is
-the primary path, batches are chunked to bounded shapes, a failing chunk
-fails the batch, and kernel failures fall back (loudly, once) to the
-partial device path. Kernel correctness itself is covered by the device
-suites; this pins the wiring."""
+the only path, batches are chunked to bounded shapes, a failing chunk
+fails the batch, and a kernel failure raises instead of falling back.
+Kernel correctness itself is covered by the device suites; this pins the
+wiring."""
 
 import pytest
 
-import lighthouse_tpu.ops.bls381 as ops_device
 import lighthouse_tpu.ops.bls381_verify as ops_full
 from lighthouse_tpu.crypto import bls
 from lighthouse_tpu.crypto.bls import _TpuBackend
 
 
 @pytest.fixture
-def tpu_available(monkeypatch):
-    monkeypatch.setattr(ops_device, "AVAILABLE", True, raising=False)
-    monkeypatch.setattr(_TpuBackend, "_warned", False)
+def backend():
     return _TpuBackend()
 
 
@@ -26,8 +23,7 @@ def _sets(n):
     return [bls.SignatureSet(sig, [kps[0].pk], msg) for _ in range(n)]
 
 
-def test_full_path_chunks_batches(tpu_available, monkeypatch):
-    backend = tpu_available
+def test_full_path_chunks_batches(backend, monkeypatch):
     calls = []
 
     def fake_full(sets, rng=None):
@@ -42,8 +38,7 @@ def test_full_path_chunks_batches(tpu_available, monkeypatch):
     assert calls == [4, 4, 2]  # bounded shapes, full coverage
 
 
-def test_failing_chunk_fails_the_batch(tpu_available, monkeypatch):
-    backend = tpu_available
+def test_failing_chunk_fails_the_batch(backend, monkeypatch):
     calls = []
 
     def fake_full(sets, rng=None):
@@ -58,27 +53,25 @@ def test_failing_chunk_fails_the_batch(tpu_available, monkeypatch):
     assert len(calls) == 2  # short-circuits after the failing chunk
 
 
-def test_kernel_failure_falls_back_to_partial_path(tpu_available, monkeypatch):
-    backend = tpu_available
+def test_kernel_failure_raises(backend, monkeypatch):
+    host_calls = []
 
     def exploding_full(sets, rng=None):
-        raise RuntimeError("remote_compile: response body closed")
+        raise RuntimeError("device kernel failed to compile")
 
-    partial = []
     monkeypatch.setattr(
         ops_full, "verify_signature_sets_device_full", exploding_full
     )
     monkeypatch.setattr(
-        ops_device,
-        "verify_signature_sets_device",
-        lambda sets, rng=None: partial.append(len(sets)) or True,
+        bls._HostBackend,
+        "verify_signature_sets",
+        lambda self, sets, rng=None: host_calls.append(len(sets)) or True,
     )
-    assert backend.verify_signature_sets(_sets(5)) is True
-    assert partial == [5]  # the partial device path served the batch
-    assert _TpuBackend._warned  # and the failure was logged loudly
+    with pytest.raises(RuntimeError, match="failed to compile"):
+        backend.verify_signature_sets(_sets(5))
+    assert host_calls == []  # the host never served the batch
 
 
-def test_empty_batch_uses_host_semantics(tpu_available):
-    backend = tpu_available
+def test_empty_batch_uses_host_semantics(backend):
     host = bls._BACKENDS["host"]
     assert backend.verify_signature_sets([]) == host.verify_signature_sets([])

@@ -180,6 +180,80 @@ def test_vc_aggregation_duties():
         bls.set_backend("fake_crypto")
 
 
+def _slot_aggregates(monkeypatch, n_min=3):
+    """One slot's SignedAggregateAndProofs from a real-crypto VC, captured
+    before the chain sees them."""
+    spec = replace(minimal_spec(), altair_fork_epoch=0)
+    # 32 validators: one 4-member committee per slot, every member selected
+    h = BeaconChainHarness(spec, E, validator_count=32)
+    vc = ValidatorClient(h.chain, h.keypairs, spec, E)
+    captured: list = []
+    monkeypatch.setattr(
+        vc.node, "publish_aggregates", lambda aggs: captured.extend(aggs) or []
+    )
+    for slot in range(1, 5):
+        h.slot_clock.set_slot(slot)
+        vc.block_service.propose_if_due(slot)
+        vc.attestation_service.attest(slot, h.chain.head_root)
+        captured.clear()
+        vc.attestation_service.aggregate_if_selected(slot)
+        if len(captured) >= n_min:
+            return h, list(captured)
+    pytest.skip("no slot with enough selected aggregators")
+
+
+@pytest.mark.parametrize(
+    "tamper", [None, "bad_signature", "duplicate", "duplicate_after_bad"]
+)
+def test_aggregate_batch_is_one_signature_batch(monkeypatch, tamper):
+    """The slot's aggregates verify as ONE signature batch; a bad or
+    duplicated aggregate is rejected alone, the rest still apply — as if
+    they were verified one after another."""
+    bls.set_backend("host")
+    try:
+        h, aggs = _slot_aggregates(monkeypatch)
+        if tamper == "bad_signature":
+            # a well-formed signature over the wrong message
+            aggs[0].signature = aggs[0].message.selection_proof
+        elif tamper == "duplicate":
+            aggs.append(aggs[0])
+        elif tamper == "duplicate_after_bad":
+            # the aggregator's first aggregate fails its signature check, so
+            # its second (valid) one is not a duplicate
+            aggs.append(aggs[0].copy())
+            aggs[0].signature = aggs[0].message.selection_proof
+        calls = []
+        verify = bls.verify_signature_sets
+        monkeypatch.setattr(
+            bls,
+            "verify_signature_sets",
+            lambda sets, rng=None: calls.append(len(sets)) or verify(sets, rng),
+        )
+        results = h.chain.process_aggregate_batch(aggs)
+        rejected = [i for i, r in enumerate(results) if isinstance(r, Exception)]
+        if tamper is None:
+            assert rejected == [] and calls == [3 * len(aggs)]
+        elif tamper == "bad_signature":
+            assert rejected == [0]
+            assert calls[0] == 3 * len(aggs) and calls[1:] == [3] * len(aggs)
+        elif tamper == "duplicate":
+            assert rejected == [len(aggs) - 1] and calls == [3 * len(aggs)]
+            assert "already seen" in str(results[-1])
+        else:
+            assert rejected == [0]
+            # the batch fails; the first aggregate is checked alone, then
+            # every other one, its valid twin included
+            assert calls == [3 * len(aggs)] + [3] * len(aggs)
+        for i, agg in enumerate(aggs):
+            data = agg.message.aggregate.data
+            known = h.chain.observed_aggregators.is_known(
+                data.target.epoch, agg.message.aggregator_index
+            )
+            assert known == (i not in rejected or tamper != "bad_signature")
+    finally:
+        bls.set_backend("fake_crypto")
+
+
 def test_sync_message_rejects_non_member_and_bad_signature():
     from lighthouse_tpu.beacon_chain.sync_pool import SyncMessageError
 
