@@ -19,6 +19,7 @@ from ..state_processing.accessors import (
     compute_epoch_at_slot,
     get_attesting_indices,
 )
+from ..utils.tracing import span
 
 # Slot-anchored observation delays (the reference's
 # beacon_attestation_gossip_slot_start_delay_time family): how far into
@@ -146,29 +147,34 @@ class AttestationVerifier:
         prepared = []
         results: list = [None] * len(attestations)
         seen_in_batch: set[tuple[int, int]] = set()
-        for i, att in enumerate(attestations):
-            try:
-                verified, sig_set = self.build_unaggregated(att)
-                # intra-batch dedup: the observed cache only updates after
-                # verification, so duplicates inside one batch need catching
-                key = (att.data.target.epoch, verified.validator_index)
-                if key in seen_in_batch:
-                    raise AttestationError(
-                        "validator already attested this epoch"
-                    )
-                seen_in_batch.add(key)
-                prepared.append((i, verified, sig_set))
-            except AttestationError as e:
-                results[i] = e
+        with span("attestation_prepare", attestations=len(attestations)):
+            for i, att in enumerate(attestations):
+                try:
+                    verified, sig_set = self.build_unaggregated(att)
+                    # intra-batch dedup: the observed cache only updates
+                    # after verification, so duplicates inside one batch
+                    # need catching
+                    key = (att.data.target.epoch, verified.validator_index)
+                    if key in seen_in_batch:
+                        raise AttestationError(
+                            "validator already attested this epoch"
+                        )
+                    seen_in_batch.add(key)
+                    prepared.append((i, verified, sig_set))
+                except AttestationError as e:
+                    results[i] = e
         sets = [s for (_, _, s) in prepared]
-        if sets and bls.verify_signature_sets(sets):
+        if not sets:
+            return results
+        if bls.verify_signature_sets(sets):
             for i, verified, _ in prepared:
                 self.chain.observed_attesters.observe(
                     verified.attestation.data.target.epoch,
                     verified.validator_index,
                 )
                 results[i] = verified
-        else:
+            return results
+        with span("attestation_fallback", sets=len(sets)):
             for i, verified, sig_set in prepared:
                 if sig_set.verify():
                     self.chain.observed_attesters.observe(

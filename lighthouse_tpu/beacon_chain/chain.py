@@ -1102,36 +1102,42 @@ class BeaconChain:
 
     def process_attestation_batch(self, attestations) -> list:
         # root span of the gossip-attestation hot path (OBSERVABILITY.md
-        # taxonomy): verification + fork-choice application as one trace
+        # taxonomy): verification + fork-choice application as one trace;
+        # verification opens attestation_prepare, the device verifier's
+        # spans and (on a failed batch) attestation_fallback under it
         with span("attestation_batch", n=len(attestations)):
             results = self.attestation_verifier.batch_verify_unaggregated(
                 attestations
             )
-            with self.import_lock.acquire_write():
-                accepted = [
-                    (att, res)
-                    for att, res in zip(attestations, results)
-                    if not isinstance(res, Exception)
-                ]
-                if accepted:
-                    if self.slasher_service is not None:
-                        # one call for the drained batch: the columnar
-                        # slasher consumes its queue as one array program
-                        self.slasher_service.observe_indexed_attestations(
-                            [res.indexed_attestation for _a, res in accepted]
-                        )
-                    # one vectorized vote write per (head root, target
-                    # epoch) group instead of a per-validator dict walk;
-                    # fork-choice rejection of individual attestations is
-                    # non-fatal, exactly like the old per-item try/except
-                    try:
-                        self.fork_choice.on_attestation_batch(
-                            [res.indexed_attestation for _a, res in accepted]
-                        )
-                    except Exception:
-                        pass  # unviable targets are skipped, not fatal
-                    for att, _res in accepted:
-                        self.op_pool.insert_attestation(att)
+            accepted = [
+                (att, res)
+                for att, res in zip(attestations, results)
+                if not isinstance(res, Exception)
+            ]
+            with span("attestation_apply", accepted=len(accepted)):
+                with span("import_lock_wait"):
+                    guard = self.import_lock.acquire_write()
+                with guard:
+                    if accepted:
+                        if self.slasher_service is not None:
+                            # one call for the drained batch: the columnar
+                            # slasher consumes its queue as one array program
+                            self.slasher_service.observe_indexed_attestations(
+                                [res.indexed_attestation for _a, res in accepted]
+                            )
+                        # one vectorized vote write per (head root, target
+                        # epoch) group instead of a per-validator dict walk;
+                        # fork-choice rejection of individual attestations
+                        # is non-fatal, exactly like the old per-item
+                        # try/except
+                        try:
+                            self.fork_choice.on_attestation_batch(
+                                [res.indexed_attestation for _a, res in accepted]
+                            )
+                        except Exception:
+                            pass  # unviable targets are skipped, not fatal
+                        for att, _res in accepted:
+                            self.op_pool.insert_attestation(att)
         return results
 
     def process_aggregate(self, signed_aggregate):

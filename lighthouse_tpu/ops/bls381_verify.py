@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 
 from ..crypto.bls12_381.fields import P
+from ..metrics import REGISTRY
+from ..utils.tracing import span
 from .bls381 import (
     NLIMB,
     DevFq,
@@ -200,126 +202,162 @@ def _g1_affine_grid_to_device(grids):
 # ---------------------------------------------------------------------------
 
 
+# registered at import, so the series read zero before the first batch
+_BATCHES = REGISTRY.counter(
+    "bls_device_batches_total", "device batch verifications (one per chunk)"
+)
+_BATCHES.inc(0)
+_SETS = REGISTRY.counter(
+    "bls_device_sets_total", "signature sets sent to the device verifier"
+)
+_SETS.inc(0)
+_SET_LANES = REGISTRY.counter(
+    "bls_device_set_lanes_total",
+    "set lanes the device verifier ran: the set axis padded to its chunk",
+)
+_SET_LANES.inc(0)
+
+
 def verify_signature_sets_device_full(sets, rng=None) -> bool:
     """Full-device RLC batch verification. Each set: (signature, pubkeys[],
-    message). Returns True iff every signature is valid (w.h.p.)."""
-    import secrets as _secrets
+    message). Returns True iff every signature is valid (w.h.p.).
 
-    from ..crypto import bls
-    from ..metrics import inc_counter
-
+    One `bls_device_verify` span (sets, lanes, keys) a call, with a child
+    span for each stage in order; the two places the host waits on the
+    device are `bls_device_wait` spans inside their stage."""
     sets = list(sets)
     if not sets:
         return False
-    inc_counter("bls_device_batches_total")
-    inc_counter("bls_device_sets_total", len(sets))
-    rand = rng if rng is not None else _secrets.SystemRandom()
-
-    sig_affs = []
-    pk_rows = []
-    scalars = []
-    messages = []
-    for s in sets:
-        try:
-            if s.signature.is_infinity():
-                return False
-            sig_aff = _affine_int(s.signature.point())
-            pk_affs = [_affine_int(pk.point()) for pk in s.pubkeys]
-        except (bls.BlsError, ValueError):
-            return False
-        if sig_aff is None or not pk_affs:
-            return False
-        r = 0
-        while r == 0:
-            r = rand.getrandbits(bls.RAND_BITS)
-        sig_affs.append(sig_aff)
-        pk_rows.append(pk_affs)
-        scalars.append(r)
-        messages.append(s.message)
+    from ..crypto import bls
 
     n = len(sets)
     # one shape for every chunk the node sends: the set axis pads to the
     # device chunk, messages pad to the set count, committees and message
     # groups to whole AGG_LANES slices
     nb = _bucket(n, floor=bls.DEFAULT_DEVICE_CHUNK)
+    kmax = _round_up(max(len(s.pubkeys) for s in sets), AGG_LANES)
+    _BATCHES.inc()
+    _SETS.inc(n)
+    _SET_LANES.inc(nb)
+    with span("bls_device_verify", sets=n, lanes=nb, keys=kmax):
+        return _verify_chunk(sets, rng, nb, kmax)
+
+
+def _verify_chunk(sets, rng, nb: int, kmax: int) -> bool:
+    import secrets as _secrets
+
+    from ..crypto import bls
+
+    n = len(sets)
+    rand = rng if rng is not None else _secrets.SystemRandom()
+    with span("bls_device_decode"):
+        sig_affs = []
+        pk_rows = []
+        scalars = []
+        messages = []
+        for s in sets:
+            try:
+                if s.signature.is_infinity():
+                    return False
+                sig_aff = _affine_int(s.signature.point())
+                pk_affs = [_affine_int(pk.point()) for pk in s.pubkeys]
+            except (bls.BlsError, ValueError):
+                return False
+            if sig_aff is None or not pk_affs:
+                return False
+            r = 0
+            while r == 0:
+                r = rand.getrandbits(bls.RAND_BITS)
+            sig_affs.append(sig_aff)
+            pk_rows.append(pk_affs)
+            scalars.append(r)
+            messages.append(s.message)
 
     # --- G2 subgroup checks on all signatures (device) ---
-    sig_pad = sig_affs + [None] * (nb - n)
-    qx, qy, q_inf = g2_affine_to_device(sig_pad)
-    in_sub = np.asarray(g2_subgroup_check_device(qx, qy, q_inf))
-    if not bool(in_sub.all()):
-        return False
+    with span("bls_device_subgroup"):
+        sig_pad = sig_affs + [None] * (nb - n)
+        qx, qy, q_inf = g2_affine_to_device(sig_pad)
+        in_sub = g2_subgroup_check_device(qx, qy, q_inf)
+        with span("bls_device_wait"):
+            in_sub = np.asarray(in_sub)
+        if not bool(in_sub.all()):
+            return False
 
     # --- per-set pubkey aggregation (device, padded committee axis) ---
-    kmax = _round_up(max(len(r) for r in pk_rows), AGG_LANES)
-    grid = [row + [None] * (kmax - len(row)) for row in pk_rows]
-    grid += [[None] * kmax] * (nb - n)
-    gx, gy, gz = _g1_affine_grid_to_device(grid)
-    agg_x, agg_y, agg_z = g1_segment_sum(gx, gy, gz)
+    with span("bls_device_aggregate"):
+        grid = [row + [None] * (kmax - len(row)) for row in pk_rows]
+        grid += [[None] * kmax] * (nb - n)
+        gx, gy, gz = _g1_affine_grid_to_device(grid)
+        agg_x, agg_y, agg_z = g1_segment_sum(gx, gy, gz)
 
-    # --- RLC scalar multiplications (device ladders) ---
-    bits = jnp.asarray(scalars_to_bits(scalars + [0] * (nb - n), bls.RAND_BITS))
-    s_pk = batch_g1_scalar_mul(agg_x, agg_y, agg_z, bits)
-    one2 = jnp.broadcast_to(
-        jnp.stack(
-            [jnp.asarray(fq_to_device([1])[0]), jnp.zeros(NLIMB, jnp.int32)]
-        ),
-        (nb, 2, NLIMB),
-    ).astype(jnp.int32)
-    z_pad = jnp.where(q_inf[:, None, None], jnp.zeros_like(one2), one2)
-    s_sig = batch_g2_scalar_mul(qx, qy, z_pad, bits)
-
-    # --- signature aggregate Σ rᵢ·sigᵢ (device tree-reduce) ---
-    agg_sig = g2_sum_reduce(*s_sig)
+    # --- RLC scalar multiplications (device ladders) and the signature
+    # aggregate Σ rᵢ·sigᵢ (device tree-reduce) ---
+    with span("bls_device_ladders"):
+        bits = jnp.asarray(scalars_to_bits(scalars + [0] * (nb - n), bls.RAND_BITS))
+        s_pk = batch_g1_scalar_mul(agg_x, agg_y, agg_z, bits)
+        one2 = jnp.broadcast_to(
+            jnp.stack(
+                [jnp.asarray(fq_to_device([1])[0]), jnp.zeros(NLIMB, jnp.int32)]
+            ),
+            (nb, 2, NLIMB),
+        ).astype(jnp.int32)
+        z_pad = jnp.where(q_inf[:, None, None], jnp.zeros_like(one2), one2)
+        s_sig = batch_g2_scalar_mul(qx, qy, z_pad, bits)
+        agg_sig = g2_sum_reduce(*s_sig)
 
     # --- per-message aggregation of scaled pubkeys (device gather+reduce) ---
-    groups: dict[bytes, list[int]] = {}
-    for i, m in enumerate(messages):
-        groups.setdefault(m, []).append(i)
-    msgs = list(groups)
-    m_count = len(msgs)
-    mb = nb
-    gmax = _round_up(max(len(v) for v in groups.values()), AGG_LANES)
-    if nb > n:
-        # lane nb-1 is a padded set (scalar 0 ladder → infinity): reuse it
-        # as the gather pad slot.
-        pad_slot = nb - 1
-    else:
-        # exact-power batch: append an explicit infinity lane.
-        s_pk = tuple(
-            jnp.concatenate([c, jnp.zeros_like(c[-1:])], axis=0) for c in s_pk
-        )
-        pad_slot = nb
-    idx = np.full((mb, gmax), pad_slot, dtype=np.int32)
-    for gi, m in enumerate(msgs):
-        for jj, si in enumerate(groups[m]):
-            idx[gi, jj] = si
-    gx2 = tuple(jnp.take(c, jnp.asarray(idx), axis=0) for c in s_pk)
-    msg_pk = g1_segment_sum(*gx2)
+    with span("bls_device_message_groups"):
+        groups: dict[bytes, list[int]] = {}
+        for i, m in enumerate(messages):
+            groups.setdefault(m, []).append(i)
+        msgs = list(groups)
+        m_count = len(msgs)
+        mb = nb
+        gmax = _round_up(max(len(v) for v in groups.values()), AGG_LANES)
+        if nb > n:
+            # lane nb-1 is a padded set (scalar 0 ladder → infinity): reuse it
+            # as the gather pad slot.
+            pad_slot = nb - 1
+        else:
+            # exact-power batch: append an explicit infinity lane.
+            s_pk = tuple(
+                jnp.concatenate([c, jnp.zeros_like(c[-1:])], axis=0) for c in s_pk
+            )
+            pad_slot = nb
+        idx = np.full((mb, gmax), pad_slot, dtype=np.int32)
+        for gi, m in enumerate(msgs):
+            for jj, si in enumerate(groups[m]):
+                idx[gi, jj] = si
+        gx2 = tuple(jnp.take(c, jnp.asarray(idx), axis=0) for c in s_pk)
+        msg_pk = g1_segment_sum(*gx2)
 
-    # --- H(m): device SSWU hash-to-curve ---
-    u = messages_to_field_device(msgs + [b"\x00" * 32] * (mb - m_count))
-    hm = hash_to_g2_device(jnp.asarray(u))
+    # --- H(m): host expand_message_xmd, device SSWU hash-to-curve ---
+    with span("bls_device_hash_to_g2"):
+        u = messages_to_field_device(msgs + [b"\x00" * 32] * (mb - m_count))
+        hm = hash_to_g2_device(jnp.asarray(u))
 
     # --- assemble the multi-pairing: (-G1, agg_sig) + (msg_pk_i, H(m_i)) ---
-    from ..crypto.bls12_381 import FQ, G1_GEN
-    from ..crypto.bls12_381.curve import pt_neg, to_affine
+    with span("bls_device_pairing"):
+        from ..crypto.bls12_381 import FQ, G1_GEN
+        from ..crypto.bls12_381.curve import pt_neg, to_affine
 
-    neg_g1 = to_affine(FQ, pt_neg(FQ, G1_GEN))
-    ngx, ngy, ng_inf = g1_affine_to_device([neg_g1])
+        neg_g1 = to_affine(FQ, pt_neg(FQ, G1_GEN))
+        ngx, ngy, ng_inf = g1_affine_to_device([neg_g1])
 
-    # Jacobian → affine for every pairing input with ONE shared Fq
-    # inversion: the G1 z's and the norms of the G2 z's ride one pow call
-    q = tuple(jnp.concatenate([a, h], axis=0) for a, h in zip(agg_sig, hm))
-    inv = fq_inv_staged(jnp.concatenate([msg_pk[2], _jit_f2_norm(q[2])]))
-    pk_ax, pk_ay, pk_inf = _jit_g1_affine_from_inv(*msg_pk, inv[:mb])
-    # mask out padded message lanes
-    pk_inf = pk_inf | jnp.asarray(np.arange(mb) >= m_count)
-    qx2, qy2, q_inf2 = _jit_g2_affine_from_inv(
-        *q, _jit_f2_scale_inv(q[2], inv[mb:])
-    )
+        # Jacobian → affine for every pairing input with ONE shared Fq
+        # inversion: the G1 z's and the norms of the G2 z's ride one pow call
+        q = tuple(jnp.concatenate([a, h], axis=0) for a, h in zip(agg_sig, hm))
+        inv = fq_inv_staged(jnp.concatenate([msg_pk[2], _jit_f2_norm(q[2])]))
+        pk_ax, pk_ay, pk_inf = _jit_g1_affine_from_inv(*msg_pk, inv[:mb])
+        # mask out padded message lanes
+        pk_inf = pk_inf | jnp.asarray(np.arange(mb) >= m_count)
+        qx2, qy2, q_inf2 = _jit_g2_affine_from_inv(
+            *q, _jit_f2_scale_inv(q[2], inv[mb:])
+        )
 
-    xp = jnp.concatenate([ngx, pk_ax], axis=0)
-    yp = jnp.concatenate([ngy, pk_ay], axis=0)
-    p_inf = jnp.concatenate([ng_inf, pk_inf], axis=0)
-    return bool(multi_pairing_check_device(xp, yp, p_inf, qx2, qy2, q_inf2))
+        xp = jnp.concatenate([ngx, pk_ax], axis=0)
+        yp = jnp.concatenate([ngy, pk_ay], axis=0)
+        p_inf = jnp.concatenate([ng_inf, pk_inf], axis=0)
+        ok = multi_pairing_check_device(xp, yp, p_inf, qx2, qy2, q_inf2)
+        with span("bls_device_wait"):
+            return bool(ok)

@@ -21,6 +21,12 @@ the same capability sized to this runtime: context-manager spans that
     are not readable cross-thread; `adopt_thread_span` lets the
     beacon_processor register the SUBMITTING span for a worker-side
     handler run,
+  * open a profiler TraceMe of the same name (the span's fields as the
+    event's stats) while a JAX profiler trace is active, so every span
+    lands on the profiler's timeline beside the device's programs and
+    names the device's idle gaps; with no trace active this is one
+    `is_enabled()` check, and JAX is used only if the process has
+    already imported it,
   * and emit one structured log line per span at close
     (`span=<name> parent=<name> ms=<dur>`), rate-limited per span name
     so hot paths don't flood the log.
@@ -43,6 +49,7 @@ import contextvars
 import functools
 import itertools
 import os
+import sys
 import threading
 import time
 
@@ -105,6 +112,18 @@ def _collect_enabled() -> bool:
     return os.environ.get("LIGHTHOUSE_TPU_TRACE_COLLECT", "1") != "0"
 
 
+def _open_annotation(name: str, fields: dict):
+    """An entered TraceMe named `name` while a JAX profiler trace is
+    active, else None. Never imports JAX: a process that has not loaded
+    it has no trace to join."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None or not profiler.TraceAnnotation.is_enabled():
+        return None
+    annotation = profiler.TraceAnnotation(name, **fields)
+    annotation.__enter__()
+    return annotation
+
+
 class Span:
     __slots__ = (
         "name",
@@ -118,6 +137,7 @@ class Span:
         "_token",
         "_thread_prev",
         "_collect",
+        "_annotation",
         "duration_s",
     )
 
@@ -134,6 +154,7 @@ class Span:
         self._token = None
         self._thread_prev = None
         self._collect = False
+        self._annotation = None
 
     def __enter__(self) -> "Span":
         self.parent = _current.get()
@@ -155,11 +176,15 @@ class Span:
         self._thread_prev = _thread_spans.get(ident)
         _thread_spans[ident] = self
         self._token = _current.set(self)
+        self._annotation = _open_annotation(self.name, self.fields)
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.duration_s = time.perf_counter() - self.t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         _current.reset(self._token)
         ident = threading.get_ident()
         if self._thread_prev is None:

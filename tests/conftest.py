@@ -55,6 +55,7 @@ def pytest_sessionstart(session):
         behaviour,  # mesh gauges + peer-score distribution histogram
     )
     from lighthouse_tpu.utils import compile_cache  # noqa: F401 — registers
+    from lighthouse_tpu.ops import bls381_verify  # noqa: F401 — registers
     from lighthouse_tpu.state_processing import (  # noqa: F401 — registers
         attestation_batch,  # the batch path counter + attestation_apply span
         registry_columns,  # the columns counters + epoch_stage spans
@@ -186,7 +187,15 @@ def pytest_sessionstart(session):
         "profiler_overrun_total",
         "compile_cache_hits_total",
         "compile_cache_misses_total",
-        "compile_cache_compile_seconds_total",
+        # the compile counters from JAX's own build events, and the
+        # device verifier's batch, set and padded-lane counters
+        "jax_programs_built_total",
+        'jax_program_build_seconds_total{phase="trace"}',
+        'jax_program_build_seconds_total{phase="lower"}',
+        'jax_program_build_seconds_total{phase="compile"}',
+        "bls_device_batches_total",
+        "bls_device_sets_total",
+        "bls_device_set_lanes_total",
         'gossipsub_mesh_peers{topic="beacon_block"}',
         'gossipsub_mesh_peers{topic="beacon_aggregate_and_proof"}',
         "gossipsub_peer_score_distribution",

@@ -98,12 +98,25 @@ def test_device_verify_signature_sets():
 
     from lighthouse_tpu.crypto import bls
     from lighthouse_tpu.ops.bls381_verify import verify_signature_sets_device_full
+    from lighthouse_tpu.utils.tracing import span
 
     bls.set_backend("host")
     kps = bls.interop_keypairs(8)
     msg = hashlib.sha256(b"device batch").digest()
     sets = [bls.SignatureSet.single(kp.sk.sign(msg), kp.pk, msg) for kp in kps]
-    assert verify_signature_sets_device_full(sets, random.Random(5))
+    with span("device_bls_test_root") as root:
+        assert verify_signature_sets_device_full(sets, random.Random(5))
+    # one verify span with every stage once, in order, and the two waits
+    (call,) = root.children
+    assert call.name == "bls_device_verify"
+    assert call.fields == {"sets": 8, "lanes": 32, "keys": 16}
+    assert [c.name for c in call.children] == [
+        "bls_device_decode", "bls_device_subgroup", "bls_device_aggregate",
+        "bls_device_ladders", "bls_device_message_groups",
+        "bls_device_hash_to_g2", "bls_device_pairing",
+    ]
+    waits = [w for c in call.children for w in c.children]
+    assert [w.name for w in waits] == ["bls_device_wait"] * 2
     bad = list(sets)
     bad[3] = bls.SignatureSet.single(
         sets[4].signature, sets[3].pubkeys[0], sets[3].message

@@ -543,29 +543,59 @@ def test_bench_profile_flag_sets_env(monkeypatch):
     monkeypatch.delenv("BENCH_PROFILE", raising=False)
 
 
-def test_compile_cache_tracking(tmp_path, monkeypatch):
+def test_compile_cache_tracking(tmp_path):
+    """The compile counters count from JAX's own build events: a program
+    compiled into an empty persistent cache is one built and one miss;
+    built again after the in-memory caches are cleared, it is one built
+    and one hit. Trace, lower and compile seconds all grow."""
+    import jax
+    import numpy as np
+    from jax._src import compilation_cache
+
     from lighthouse_tpu.utils import compile_cache as cc
 
-    hits0, miss0 = cc._HITS.value(), cc._MISSES.value()
-    secs0 = cc._COMPILE_SECONDS.value()
-    cache = tmp_path / "jc"
-    cache.mkdir()
-    # the tracker reads the directory JAX is actually using
-    monkeypatch.setattr(cc, "active_cache_dir", lambda: str(cache))
-    # no new cache entry → hit
-    with cc.track_device_compile("unit_kernel"):
-        pass
-    assert cc._HITS.value() == hits0 + 1
-    # cache dir grows inside the block → miss + compile seconds
-    with cc.track_device_compile("unit_kernel"):
-        (cache / "entry").write_text("x")
-        time.sleep(0.01)
-    assert cc._MISSES.value() == miss0 + 1
-    assert cc._COMPILE_SECONDS.value() > secs0
+    def snap():
+        return (
+            cc._BUILT.value(), cc._HITS.value(), cc._MISSES.value(),
+            {p: cc._BUILD_SECONDS.value(phase=p) for p in ("trace", "lower", "compile")},
+        )
+
+    def build_once():
+        # a host array in, so the call builds this one program and no other
+        jax.jit(lambda x: x * 3 + 1)(np.arange(4)).block_until_ready()
+
+    saved = (
+        jax.config.jax_compilation_cache_dir,
+        jax.config.jax_persistent_cache_min_compile_time_secs,
+    )
+    try:
+        cc.enable_compile_cache()  # idempotent: the listeners register once
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "jc"))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        compilation_cache.reset_cache()
+        jax.clear_caches()
+        built0, hits0, miss0, secs0 = snap()
+        build_once()
+        built1, hits1, miss1, secs1 = snap()
+        assert (built1 - built0, hits1 - hits0, miss1 - miss0) == (1, 0, 1)
+        assert all(secs1[p] > secs0[p] for p in secs0)
+        jax.clear_caches()
+        build_once()
+        built2, hits2, miss2, _ = snap()
+        assert (built2 - built1, hits2 - hits1, miss2 - miss1) == (1, 1, 0)
+        # which program was built when
+        assert cc.program_builds()[-1][1:2] == ("compile",)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", saved[1])
+        compilation_cache.reset_cache()
+        jax.clear_caches()
     stats = cc.compile_cache_stats()
     assert {"hits", "misses", "compile_seconds"} <= set(stats)
-    # the warmup rode a device_compile span (standard metrics path)
-    assert REGISTRY.histogram("trace_span_seconds_device_compile").count >= 2
+    # the warmup tracker keeps its device_compile span
+    with cc.track_device_compile("unit_kernel"):
+        pass
+    assert REGISTRY.histogram("trace_span_seconds_device_compile").count >= 1
 
 
 @pytest.mark.parametrize("env_dir", [None, "/placed/from/outside"])

@@ -1,0 +1,202 @@
+"""Stage spans on the gossip attestation path, and the bridge that puts
+every span on a JAX profiler trace.
+
+A span opened while a profiler trace runs is a TraceMe of the same name
+in the recorded `.xplane.pb`, nested in its parent; with no trace running
+no TraceMe is made. The device verifier records one `bls_device_verify`
+span a chunk with a child span for each stage and a `bls_device_wait`
+span for each of its two blocking reads (checked here with its device
+kernels replaced by stand-ins; `test_device_bls.py` checks the real
+kernels); `process_attestation_batch` records its prepare, fallback and
+apply stages."""
+
+import glob
+import random
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import lighthouse_tpu.ops.bls381_verify as ops_full
+from lighthouse_tpu.crypto import bls
+from lighthouse_tpu.metrics import REGISTRY
+from lighthouse_tpu.utils import tracing
+from lighthouse_tpu.utils.tracing import span
+
+DEVICE_STAGES = [
+    "bls_device_decode",
+    "bls_device_subgroup",
+    "bls_device_aggregate",
+    "bls_device_ladders",
+    "bls_device_message_groups",
+    "bls_device_hash_to_g2",
+    "bls_device_pairing",
+]
+
+
+def _walk(s):
+    yield s
+    for c in s.children:
+        yield from _walk(c)
+
+
+# -- the profiler bridge -----------------------------------------------------
+
+
+def test_span_lands_in_profiler_trace_nested(tmp_path):
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with span("obs_bridge_outer"):
+            with span("obs_bridge_inner", sets=3):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("obs_bridge_"):
+                    events[e.name] = (line.name, e.start_ns, e.end_ns, dict(e.stats))
+    outer, inner = events["obs_bridge_outer"], events["obs_bridge_inner"]
+    assert outer[0] == inner[0]  # one thread's line
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    assert inner[3] == {"sets": 3}
+
+
+def test_no_traceme_without_an_active_trace(monkeypatch):
+    made = []
+    real = jax.profiler.TraceAnnotation
+
+    class Counting(real):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    assert not real.is_enabled()
+    with span("obs_bridge_off"):
+        pass
+    assert made == []
+    # the same span while a trace is active opens exactly one TraceMe
+    monkeypatch.setattr(Counting, "is_enabled", staticmethod(lambda: True))
+    with span("obs_bridge_on"):
+        pass
+    assert made == ["obs_bridge_on"]
+    assert tracing.current_span() is None
+
+
+# -- the device verifier's stages --------------------------------------------
+
+
+@pytest.fixture(autouse=True)
+def host_backend():
+    before = bls.get_backend().name
+    bls.set_backend("host")
+    yield
+    bls.set_backend(before)
+
+
+def _stand_in_kernels(monkeypatch):
+    """Shape-preserving stand-ins for the verifier's device programs: the
+    host staging, the stage spans and the counters run for real."""
+    no_inf = lambda x: jnp.zeros(x.shape[0], bool)  # noqa: E731
+    for name, fn in {
+        "g2_subgroup_check_device": lambda x, y, inf: jnp.ones(x.shape[0], bool),
+        "g1_segment_sum": lambda xs, ys, zs: (xs[:, 0], ys[:, 0], zs[:, 0]),
+        "batch_g1_scalar_mul": lambda x, y, z, bits: (x, y, z),
+        "batch_g2_scalar_mul": lambda x, y, z, bits: (x, y, z),
+        "g2_sum_reduce": lambda x, y, z: (x[:1], y[:1], z[:1]),
+        "hash_to_g2_device": lambda u: (u[:, 0], u[:, 1], u[:, 0]),
+        "fq_inv_staged": lambda a: a,
+        "_jit_f2_norm": lambda z: z[:, 0],
+        "_jit_f2_scale_inv": lambda z, inv: z,
+        "_jit_g1_affine_from_inv": lambda x, y, z, inv: (x, y, no_inf(x)),
+        "_jit_g2_affine_from_inv": lambda x, y, z, inv: (x, y, no_inf(x)),
+        "multi_pairing_check_device": lambda *a: jnp.asarray(True),
+    }.items():
+        monkeypatch.setattr(ops_full, name, fn)
+
+
+def _counts():
+    return {
+        k: REGISTRY.counter(k).value()
+        for k in ("bls_device_batches_total", "bls_device_sets_total", "bls_device_set_lanes_total")
+    }
+
+
+def test_device_verifier_records_each_stage_once(monkeypatch):
+    _stand_in_kernels(monkeypatch)
+    kps = bls.interop_keypairs(3)
+    msgs = [b"\x01" * 32, b"\x01" * 32, b"\x02" * 32]
+    sets = [bls.SignatureSet.single(kp.sk.sign(m), kp.pk, m) for kp, m in zip(kps, msgs)]
+    before = _counts()
+    with span("obs_device_root") as root:
+        assert ops_full.verify_signature_sets_device_full(sets, random.Random(1))
+    after = _counts()
+    assert {k: after[k] - before[k] for k in after} == {
+        "bls_device_batches_total": 1,
+        "bls_device_sets_total": 3,
+        "bls_device_set_lanes_total": 32,
+    }
+    (call,) = root.children
+    assert call.name == "bls_device_verify"
+    assert call.fields == {"sets": 3, "lanes": 32, "keys": 16}
+    assert [c.name for c in call.children] == DEVICE_STAGES
+    stages = {c.name: c for c in call.children}
+    waits = [s for s in _walk(call) if s.name == "bls_device_wait"]
+    assert len(waits) == 2
+    assert [w.name for w in stages["bls_device_subgroup"].children] == ["bls_device_wait"]
+    assert [w.name for w in stages["bls_device_pairing"].children] == ["bls_device_wait"]
+    # the stages cover the call: only the span bookkeeping falls between
+    assert sum(c.duration_s for c in call.children) <= call.duration_s
+
+
+def test_device_verifier_chunks_get_a_span_each(monkeypatch):
+    _stand_in_kernels(monkeypatch)
+    monkeypatch.setenv("LIGHTHOUSE_TPU_BLS_CHUNK", "2")
+    kp = bls.interop_keypairs(1)[0]
+    msg = b"\x03" * 32
+    sets = [bls.SignatureSet.single(kp.sk.sign(msg), kp.pk, msg)] * 3
+    with span("obs_device_root") as root:
+        assert bls._TpuBackend().verify_signature_sets(sets)
+    calls = [c for c in root.children if c.name == "bls_device_verify"]
+    assert [c.fields["sets"] for c in calls] == [2, 1]
+    for c in calls:
+        assert [s.name for s in c.children] == DEVICE_STAGES
+
+
+# -- the gossip batch's stages -----------------------------------------------
+
+
+@pytest.fixture
+def attestations():
+    from lighthouse_tpu.beacon_chain import BeaconChainHarness
+    from lighthouse_tpu.types import MinimalEthSpec, minimal_spec
+
+    h = BeaconChainHarness(minimal_spec(), MinimalEthSpec, validator_count=64)
+    h.extend_chain(2, attest=False)
+    chain = h.chain
+    return chain, h.make_unaggregated_attestations(chain.head_state.slot, chain.head_root)
+
+
+@pytest.mark.parametrize("invalid", [False, True])
+def test_attestation_batch_stages(attestations, invalid):
+    chain, atts = attestations
+    if invalid:
+        atts[3].signature = atts[4].signature  # a valid point, the wrong signer
+    with span("obs_batch_root") as root:
+        results = chain.process_attestation_batch(atts)
+    assert sum(isinstance(r, Exception) for r in results) == int(invalid)
+    (batch,) = root.children
+    assert batch.name == "attestation_batch"
+    stages = [c.name for c in batch.children if c.name.startswith("attestation_")]
+    want = ["attestation_prepare"] + ["attestation_fallback"] * invalid + ["attestation_apply"]
+    assert stages == want
+    (apply,) = [c for c in batch.children if c.name == "attestation_apply"]
+    assert [c.name for c in apply.children] == ["import_lock_wait"]
+    assert apply.fields == {"accepted": len(atts) - invalid}
