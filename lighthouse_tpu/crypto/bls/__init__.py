@@ -927,7 +927,8 @@ class _FakeBackend:
 class _TpuBackend(_HostBackend):
     """Host ops with FULL device batch verification (ops/bls381_verify):
     subgroup checks, committee aggregation, RLC ladders, SSWU hash-to-G2
-    and the multi-pairing all on device. Batches are processed in
+    and the Miller loops on device; the host multiplies the Miller lanes
+    and runs the one final exponentiation. Batches are processed in
     bounded-shape chunks (LIGHTHOUSE_TPU_BLS_CHUNK, default
     DEFAULT_DEVICE_CHUNK = 32 — the same value bench.py's BENCH_BLS_CHUNK
     defaults to) so every chunk reuses one set of compiled kernels. A

@@ -34,9 +34,9 @@ oracle). Two structural changes, both standard in the pairing literature:
 
   ((x−1) ≡ 0 mod 3 for BLS12-381, so every factor is integral and the
   result is the *exact* pairing value, not the cubed variant some
-  implementations use; the device kernel in ops/bls381_pairing keeps the
-  cubed form, which only ever feeds ==1 checks). Asserted against the
-  generic exponent below and differentially tested on random points.
+  implementations use; the device check in ops/bls381_pairing finishes
+  with this function). Asserted against the generic exponent below and
+  differentially tested on random points.
 """
 
 from __future__ import annotations

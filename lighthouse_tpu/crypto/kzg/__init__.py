@@ -384,8 +384,9 @@ class _DeviceKzg:
         return fr_from_device(q)
 
     def pairing_check(self, pairs) -> bool:
-        """∏ e(Pᵢ, Qᵢ) == 1 with the Miller loops + final exp on device.
-        pairs: host (G1 Jacobian, G2 Jacobian) tuples."""
+        """∏ e(Pᵢ, Qᵢ) == 1 with the Miller loops on device, their product
+        and the final exp on the host. pairs: host (G1 Jacobian, G2
+        Jacobian) tuples."""
         from ...ops.bls381_pairing import (
             g1_affine_to_device,
             g2_affine_to_device,

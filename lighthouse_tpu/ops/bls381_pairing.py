@@ -3,9 +3,9 @@
 This is the TPU analog of blst's `verify_multiple_aggregate_signatures`
 multi-pairing core (reference: crypto/bls/src/impls/blst.rs:112-117) — the
 single most important kernel for north-star metric 1 (BASELINE.md): the
-Miller loops of a signature batch run vmapped over the batch dimension, the
-loop results are tree-multiplied in Fq12, and ONE final exponentiation
-decides the whole batch.
+Miller loops of a signature batch run vmapped over the batch dimension on
+the device; their product and ONE final exponentiation, which decide the
+whole batch, run on the host (`pairing_host_tail`).
 
 Design notes (derived, not transliterated — the reference's backend is
 vendored C/assembly):
@@ -27,13 +27,12 @@ vendored C/assembly):
   by Fq2 factors (the cleared denominators and one ξ) is sound: subfield
   elements die in the final exponentiation's (p⁶−1) easy part.
 
-* Final exponentiation uses the BLS12 hard-part factorization
-      (x−1)²·(x+p)·(x²+p²−1) + 3 == 3·(p⁴−p²+1)/r      (verified in-repo)
-  so the device computes f^(3·(p¹²−1)/r). For pairing CHECKS this is
-  equivalent (gcd(3, r)=1 on μ_r); for GT VALUES everything this module
-  returns is the cube of the host oracle's value — tests assert exactly
-  that relation. After the easy part, inversion is conjugation and x<0
-  exponents use conj(f^|x|).
+* The check ends on the host: after the Miller loop a check is one
+  product of the lanes and one final exponentiation of a single Fq12
+  element, a chain of dependent operations with no batch axis. On the
+  device each Fq12 product in that chain costs its full latency (~0.5 ms,
+  TPU v5e); the host oracle's exact `final_exponentiation`
+  (crypto/bls12_381/pairing.py) runs the whole tail in milliseconds.
 
 * G2 subgroup membership uses the ψ-endomorphism criterion
   ψ(Q) == [x]Q (valid since p ≡ x (mod r), verified in-repo; ψ = twist ∘
@@ -52,18 +51,15 @@ from jax import lax
 
 from ..crypto.bls12_381 import fields as HF
 from ..crypto.bls12_381.fields import P, R, X
-from . import bls381_tower as TW
+from ..crypto.bls12_381.pairing import final_exponentiation
+from ..metrics import REGISTRY
+from ..utils.tracing import span
 from .bls381 import (
     NLIMB,
     _stack,
     DevFq2,
-    R_MONT,
     _ONE_MONT,
     fq_to_device,
-    int_to_limbs,
-    mod_add,
-    mod_sub,
-    mont_mul,
     pt_add,
     pt_double,
 )
@@ -78,23 +74,12 @@ from .bls381_tower import (
     f2_select,
     f2_sub,
     f2_triple,
-    f6_inv_parts,
-    f6_mul,
-    f6_mul_by_v,
-    f6_neg,
-    f6_sqr,
-    f6_sub,
     f12_conj,
-    f12_frob,
-    f12_is_one,
     f12_mul,
     f12_ones,
-    f12_select,
-    f12_slots,
     f12_sqr,
-    _f12,
+    f12_from_device,
     fq2_const,
-    fq_inv_staged,
 )
 
 # ---------------------------------------------------------------------------
@@ -104,15 +89,11 @@ from .bls381_tower import (
 _ATE = abs(X)  # 0xd201000000010000
 # MSB-first bits after the leading 1 (63 entries) — the Miller loop schedule.
 _ATE_TAIL_BITS = np.array([int(b) for b in bin(_ATE)[3:]], dtype=np.int32)
-# LSB-first bits of |x| for exponentiation scans.
-_X_BITS_LSB = np.array([(_ATE >> i) & 1 for i in range(_ATE.bit_length())],
-                       dtype=np.int32)
 
 # ψ coefficients (host-derived): ξ^(−(p−1)/3), ξ^(−(p−1)/2)
 _PSI_CX = fq2_const(HF.f2_pow(HF.f2_inv(HF.XI), (P - 1) // 3))
 _PSI_CY = fq2_const(HF.f2_pow(HF.f2_inv(HF.XI), (P - 1) // 2))
 
-assert (X - 1) ** 2 * (X + P) * (X**2 + P**2 - 1) + 3 == 3 * ((P**4 - P**2 + 1) // R)
 assert P % R == X % R  # ψ acts as [x] on G2 — the subgroup criterion
 
 
@@ -238,7 +219,7 @@ _MILLER_OPS = np.array(_MILLER_OPS, dtype=np.int32)
 def miller_loop_batch(xp, yp, q_x, q_y):
     """Batched f_{|x|,Q}(P), conjugated for x<0. Inputs: G1 affine limbs
     [n, 48]×2, G2 (twisted) affine limbs [n, 2, 48]×2. Returns [n] Fq12.
-    Infinity handling is the CALLER's job (mask lanes to one)."""
+    Infinity handling is the CALLER's job (skip those lanes)."""
     batch = xp.shape[:-1]
     one2 = _one_fq2(batch)
     T0 = (q_x, q_y, one2)
@@ -262,116 +243,7 @@ def miller_loop_batch(xp, yp, q_x, q_y):
     return f12_conj(f)  # x < 0
 
 
-# --- staged jit pieces ------------------------------------------------------
-# One mega-jit (miller + final exp + reductions) made XLA-CPU compile for
-# hours on slow hosts: each baked-in pow chain became its own while loop
-# with a huge body. Instead: the Miller scan is one jit; the final
-# exponentiation is orchestrated in Python over a SINGLE runtime-bits
-# f12-pow scan (compiled once, reused for all five x-powers) plus small
-# straight-line jits.
-
 _miller_jit = jax.jit(miller_loop_batch)
-
-# |x|-power op schedule (LSB-first square-and-multiply, one op per scan
-# step — same body-splitting rationale as the Miller schedule)
-_POW_X_OPS = []
-for _i in range(64):
-    if (_ATE >> _i) & 1:
-        _POW_X_OPS.append(1)  # acc ×= base
-    _POW_X_OPS.append(0)  # base ²= (harmless past the top bit)
-_POW_X_OPS = np.array(_POW_X_OPS, dtype=np.int32)
-
-
-@jax.jit
-def _jit_f12_pow_x(a):
-    """a^|x| via the fixed schedule."""
-    one = f12_ones(a.shape[:-4])
-
-    def mul_branch(carry):
-        acc, base = carry
-        return (f12_mul(acc, base), base)
-
-    def sqr_branch(carry):
-        acc, base = carry
-        return (acc, f12_sqr(base))
-
-    def body(carry, op):
-        return lax.cond(op > 0, mul_branch, sqr_branch, carry), None
-
-    (acc, _), _ = lax.scan(body, (one, a), jnp.asarray(_POW_X_OPS))
-    return acc
-
-
-def _pow_x_conj(a):
-    """a^x = conj(a^|x|) (x < 0)."""
-    return _jit_f12_conj(_jit_f12_pow_x(a))
-
-
-_jit_f12_mul = jax.jit(f12_mul)
-_jit_f12_conj = jax.jit(f12_conj)
-_jit_f12_frob = jax.jit(f12_frob)
-
-
-@jax.jit
-def _jit_f12_inv_parts(a):
-    """a⁻¹ down to its one Fq inversion: with t = a0² − v·a1² = c / d,
-    returns (c, d, |d|²)."""
-    a0, a1 = f12_slots(a)
-    c, d = f6_inv_parts(f6_sub(f6_sqr(a0), f6_mul_by_v(f6_sqr(a1))))
-    d0, d1 = d[..., 0, :], d[..., 1, :]
-    return c, d, mod_add(mont_mul(d0, d0), mont_mul(d1, d1))
-
-
-@jax.jit
-def _jit_f12_inv_finish(a, c, d, ninv):
-    """a⁻¹ = (a0·t⁻¹, −a1·t⁻¹) from the parts and 1/|d|²."""
-    d0, d1 = d[..., 0, :], d[..., 1, :]
-    dinv = jnp.stack(
-        [mont_mul(d0, ninv), mod_sub(jnp.zeros_like(d0), mont_mul(d1, ninv))], -2
-    )
-    tinv = f2_mul(c, dinv[..., None, :, :])
-    m = f6_mul(jnp.stack(f12_slots(a), -4), tinv[..., None, :, :, :])
-    return _f12(m[..., 0, :, :, :], f6_neg(m[..., 1, :, :, :]))
-
-
-def _f12_inv_staged(a):
-    """Fq12 inversion whose Fq inversion rides the shared pow loop: two
-    small programs instead of one holding a whole exponentiation."""
-    c, d, n = _jit_f12_inv_parts(a)
-    return _jit_f12_inv_finish(a, c, d, fq_inv_staged(n))
-
-
-def final_exp_cubed(F):
-    """F^(3·(p¹²−1)/r) — easy part then the (x−1)²(x+p)(x²+p²−1)+3 chain.
-    Cube of the host oracle's final_exponentiation; identical for ==1
-    checks. Python orchestration over a handful of small jits, each
-    compiled once and reused (one program holding the whole chain compiles
-    superlinearly slower than its pieces)."""
-    mul, conj, frob = _jit_f12_mul, _jit_f12_conj, _jit_f12_frob
-    t = mul(conj(F), _f12_inv_staged(F))  # ^(p⁶−1)
-    t = mul(frob(frob(t)), t)  # ^(p²+1): now cyclotomic
-    y1 = conj(mul(_jit_f12_pow_x(t), t))
-    y2 = conj(mul(_jit_f12_pow_x(y1), y1))
-    y3 = mul(_pow_x_conj(y2), frob(y2))  # ^(x+p)
-    a = _pow_x_conj(y3)  # y3^x
-    b = _pow_x_conj(a)  # y3^(x²)
-    y4 = mul(mul(b, frob(frob(y3))), conj(y3))
-    return mul(y4, mul(mul(t, t), t))  # y4 · t³
-
-
-@jax.jit
-def _mask_and_reduce(f, p_inf, q_inf):
-    """Infinity lanes → identity, then the product of all lanes → [1]
-    Fq12, as one fori_loop of single products (one f12_mul in the
-    compiled program for any lane count)."""
-    skip = p_inf | q_inf
-    f = f12_select(skip, f12_ones(f.shape[:-4]), f)
-
-    def body(j, acc):
-        return f12_mul(acc, lax.dynamic_slice_in_dim(f, j, 1))
-
-    return lax.fori_loop(1, f.shape[0], body, f[:1])
-
 
 #: Miller-loop lanes per call: one BLS chunk's messages plus the signature
 #: pair (crypto.bls.DEFAULT_DEVICE_CHUNK + 1). Smaller batches (the KZG
@@ -379,10 +251,20 @@ def _mask_and_reduce(f, p_inf, q_inf):
 #: compiled Miller loop.
 PAIRING_LANES = 33
 
+# registered at import, so the series reads zero before the first check
+_HOST_TAILS = REGISTRY.counter(
+    "bls_pairing_host_tails_total",
+    "multi-pairing checks finished on the host: lane product and final exponentiation",
+)
+_HOST_TAILS.inc(0)
 
-def multi_pairing_check_device(xp, yp, p_inf, q_x, q_y, q_inf):
-    """∏ e(P_i, Q_i) == 1 over the batch, entirely on device. Infinity
-    lanes contribute the identity (host oracle behavior)."""
+
+def miller_lanes_device(xp, yp, p_inf, q_x, q_y, q_inf):
+    """The device half of a multi-pairing check: the batch padded with
+    infinity lanes to a multiple of PAIRING_LANES, one Miller loop per
+    lane. Returns (lanes [n, 2, 3, 2, 48], skip [n]) as device arrays;
+    a skipped lane (either point at infinity) holds junk and contributes
+    the identity."""
     n = xp.shape[0]
     pad = -(-n // PAIRING_LANES) * PAIRING_LANES - n
     if pad:
@@ -391,16 +273,27 @@ def multi_pairing_check_device(xp, yp, p_inf, q_x, q_y, q_inf):
         )
         xp, yp, q_x, q_y = (grow(a, 0) for a in (xp, yp, q_x, q_y))
         p_inf, q_inf = grow(p_inf, True), grow(q_inf, True)
-    f = _miller_jit(xp, yp, q_x, q_y)
-    F = _mask_and_reduce(f, p_inf, q_inf)
-    return f12_is_one(final_exp_cubed(F))[0]
+    return _miller_jit(xp, yp, q_x, q_y), p_inf | q_inf
 
 
-def pairing_cubed_device(xp, yp, q_x, q_y):
-    """e(P, Q)³ per lane (full final exp per element — for tests; batch
-    verification never needs per-element GT values)."""
-    f = _miller_jit(xp, yp, q_x, q_y)
-    return final_exp_cubed(f)
+def pairing_host_tail(lanes, skip) -> bool:
+    """The host half: the product of the Miller lanes that are not skipped,
+    then the host oracle's exact final exponentiation, compared with one."""
+    with span("bls_pairing_host_tail", lanes=len(skip)):
+        _HOST_TAILS.inc()
+        f = HF.F12_ONE
+        for lane, skipped in zip(f12_from_device(lanes), np.asarray(skip)):
+            if not skipped:
+                f = HF.f12_mul(f, lane)
+        return HF.f12_is_one(final_exponentiation(f))
+
+
+def multi_pairing_check_device(xp, yp, p_inf, q_x, q_y, q_inf) -> bool:
+    """∏ e(P_i, Q_i) == 1 over the batch: Miller loops on the device, their
+    product and the final exponentiation on the host. Infinity lanes
+    contribute the identity (host oracle behavior)."""
+    lanes, skip = jax.device_get(miller_lanes_device(xp, yp, p_inf, q_x, q_y, q_inf))
+    return pairing_host_tail(lanes, skip)
 
 
 # ---------------------------------------------------------------------------

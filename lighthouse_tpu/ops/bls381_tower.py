@@ -325,16 +325,6 @@ def f12_frob(a):
     return _f12(b[..., 0, :, :, :], b1)
 
 
-def f12_select(c, a, b):
-    """c: [...] bool, broadcast over the 4 trailing axes."""
-    return jnp.where(c[..., None, None, None, None], a, b)
-
-
-def f12_is_one(a):
-    """Per-lane check a == 1 (Montgomery one in slot [0,0,0])."""
-    return jnp.all(a == f12_ones(a.shape[:-4]), axis=(-1, -2, -3, -4))
-
-
 # ---------------------------------------------------------------------------
 # Host <-> device conversion for tower elements
 # ---------------------------------------------------------------------------
@@ -356,18 +346,15 @@ def f12_to_device(vals: list) -> np.ndarray:
 
 
 def f12_from_device(arr) -> list:
-    from .bls381 import limbs_to_int
-
-    host = np.asarray(arr).reshape(-1, 2, 3, 2, NLIMB)
+    """[..., 2, 3, 2, 48] Montgomery limbs → list of host Fq12 tuples: one
+    bytes → int per Fq and one product by R⁻¹ mod p. Device limbs are
+    bytes: every field kernel normalizes its limbs into [0, 256)."""
+    raw = np.asarray(arr).astype(np.uint8).tobytes()
     rinv = pow(R_MONT, -1, P)
-    out = []
-    for row in host:
-        parts = []
-        for w in range(2):
-            parts.append(tuple(
-                (limbs_to_int(row[w, v, 0]) * rinv % P,
-                 limbs_to_int(row[w, v, 1]) * rinv % P)
-                for v in range(3)
-            ))
-        out.append((parts[0], parts[1]))
-    return out
+    fq = [
+        int.from_bytes(raw[i : i + NLIMB], "little") * rinv % P
+        for i in range(0, len(raw), NLIMB)
+    ]
+    f2 = list(zip(fq[0::2], fq[1::2]))
+    f6 = list(zip(f2[0::3], f2[1::3], f2[2::3]))
+    return list(zip(f6[0::2], f6[1::2]))

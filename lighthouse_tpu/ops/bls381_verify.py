@@ -6,7 +6,7 @@ check
 
     e(-G1, Σ rᵢ·sigᵢ) · Π_m e(Σ_{i: msgᵢ=m} rᵢ·aggpkᵢ, H(m)) == 1
 
-with EVERY group/field operation on device:
+with every group operation and the Miller loops on device:
 
   1. per-set pubkey aggregation   — padded tree-reduction over the
                                      committee axis (G1, Fq lanes)
@@ -16,10 +16,11 @@ with EVERY group/field operation on device:
   5. H(m) hash-to-curve           — device SSWU (bls381_htc; host does only
                                      the SHA-256 expand_message_xmd)
   6. Jacobian→affine              — batched Fermat inversions
-  7. Miller loops + final exp     — one multi-pairing (bls381_pairing)
+  7. Miller loops                 — one lane per pair (bls381_pairing)
 
 The host's remaining jobs: point decompression (bytes → ints, cached on the
-PublicKey/Signature wrappers), RLC scalar sampling, and padding every
+PublicKey/Signature wrappers), RLC scalar sampling, the product of the
+Miller lanes and the one final exponentiation, and padding every
 batch to one shape (the set axis to the device chunk, committees to whole
 AGG_LANES slices), so one set of compiled kernels serves every batch —
 the reference batches gossip work in fixed chunks of 64 for the same
@@ -58,7 +59,8 @@ from .bls381_pairing import (
     g1_affine_to_device,
     g2_affine_to_device,
     g2_subgroup_check_device,
-    multi_pairing_check_device,
+    miller_lanes_device,
+    pairing_host_tail,
 )
 
 # ---------------------------------------------------------------------------
@@ -224,7 +226,8 @@ def verify_signature_sets_device_full(sets, rng=None) -> bool:
 
     One `bls_device_verify` span (sets, lanes, keys) a call, with a child
     span for each stage in order; the two places the host waits on the
-    device are `bls_device_wait` spans inside their stage."""
+    device are `bls_device_wait` spans inside their stage, and the pairing
+    stage ends in the host's `bls_pairing_host_tail`."""
     sets = list(sets)
     if not sets:
         return False
@@ -358,6 +361,7 @@ def _verify_chunk(sets, rng, nb: int, kmax: int) -> bool:
         xp = jnp.concatenate([ngx, pk_ax], axis=0)
         yp = jnp.concatenate([ngy, pk_ay], axis=0)
         p_inf = jnp.concatenate([ng_inf, pk_inf], axis=0)
-        ok = multi_pairing_check_device(xp, yp, p_inf, qx2, qy2, q_inf2)
+        lanes, skip = miller_lanes_device(xp, yp, p_inf, qx2, qy2, q_inf2)
         with span("bls_device_wait"):
-            return bool(ok)
+            lanes, skip = jax.device_get((lanes, skip))
+        return pairing_host_tail(lanes, skip)

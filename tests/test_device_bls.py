@@ -106,7 +106,8 @@ def test_device_verify_signature_sets():
     sets = [bls.SignatureSet.single(kp.sk.sign(msg), kp.pk, msg) for kp in kps]
     with span("device_bls_test_root") as root:
         assert verify_signature_sets_device_full(sets, random.Random(5))
-    # one verify span with every stage once, in order, and the two waits
+    # one verify span with every stage once, in order, the two waits and
+    # the pairing's host tail
     (call,) = root.children
     assert call.name == "bls_device_verify"
     assert call.fields == {"sets": 8, "lanes": 32, "keys": 16}
@@ -115,8 +116,8 @@ def test_device_verify_signature_sets():
         "bls_device_ladders", "bls_device_message_groups",
         "bls_device_hash_to_g2", "bls_device_pairing",
     ]
-    waits = [w for c in call.children for w in c.children]
-    assert [w.name for w in waits] == ["bls_device_wait"] * 2
+    inner = [w for c in call.children for w in c.children]
+    assert [w.name for w in inner] == ["bls_device_wait"] * 2 + ["bls_pairing_host_tail"]
     bad = list(sets)
     bad[3] = bls.SignatureSet.single(
         sets[4].signature, sets[3].pubkeys[0], sets[3].message

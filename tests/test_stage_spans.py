@@ -7,8 +7,9 @@ no TraceMe is made. The device verifier records one `bls_device_verify`
 span a chunk with a child span for each stage and a `bls_device_wait`
 span for each of its two blocking reads (checked here with its device
 kernels replaced by stand-ins; `test_device_bls.py` checks the real
-kernels); `process_attestation_batch` records its prepare, fallback and
-apply stages."""
+kernels), and its pairing stage ends in one `bls_pairing_host_tail` span,
+outside the waits; `process_attestation_batch` records its prepare,
+fallback and apply stages."""
 
 import glob
 import random
@@ -18,6 +19,8 @@ import jax.numpy as jnp
 import pytest
 
 import lighthouse_tpu.ops.bls381_verify as ops_full
+from lighthouse_tpu.ops.bls381_pairing import PAIRING_LANES
+from lighthouse_tpu.ops.bls381_tower import f12_ones
 from lighthouse_tpu.crypto import bls
 from lighthouse_tpu.metrics import REGISTRY
 from lighthouse_tpu.utils import tracing
@@ -103,7 +106,8 @@ def host_backend():
 
 def _stand_in_kernels(monkeypatch):
     """Shape-preserving stand-ins for the verifier's device programs: the
-    host staging, the stage spans and the counters run for real."""
+    host staging, the stage spans, the counters and the pairing's host
+    tail (over Miller lanes that all hold one) run for real."""
     no_inf = lambda x: jnp.zeros(x.shape[0], bool)  # noqa: E731
     for name, fn in {
         "g2_subgroup_check_device": lambda x, y, inf: jnp.ones(x.shape[0], bool),
@@ -117,7 +121,9 @@ def _stand_in_kernels(monkeypatch):
         "_jit_f2_scale_inv": lambda z, inv: z,
         "_jit_g1_affine_from_inv": lambda x, y, z, inv: (x, y, no_inf(x)),
         "_jit_g2_affine_from_inv": lambda x, y, z, inv: (x, y, no_inf(x)),
-        "multi_pairing_check_device": lambda *a: jnp.asarray(True),
+        "miller_lanes_device": lambda *a: (
+            f12_ones((PAIRING_LANES,)), jnp.zeros(PAIRING_LANES, bool)
+        ),
     }.items():
         monkeypatch.setattr(ops_full, name, fn)
 
@@ -125,7 +131,10 @@ def _stand_in_kernels(monkeypatch):
 def _counts():
     return {
         k: REGISTRY.counter(k).value()
-        for k in ("bls_device_batches_total", "bls_device_sets_total", "bls_device_set_lanes_total")
+        for k in (
+            "bls_device_batches_total", "bls_device_sets_total", "bls_device_set_lanes_total",
+            "bls_pairing_host_tails_total",
+        )
     }
 
 
@@ -142,6 +151,7 @@ def test_device_verifier_records_each_stage_once(monkeypatch):
         "bls_device_batches_total": 1,
         "bls_device_sets_total": 3,
         "bls_device_set_lanes_total": 32,
+        "bls_pairing_host_tails_total": 1,
     }
     (call,) = root.children
     assert call.name == "bls_device_verify"
@@ -151,7 +161,11 @@ def test_device_verifier_records_each_stage_once(monkeypatch):
     waits = [s for s in _walk(call) if s.name == "bls_device_wait"]
     assert len(waits) == 2
     assert [w.name for w in stages["bls_device_subgroup"].children] == ["bls_device_wait"]
-    assert [w.name for w in stages["bls_device_pairing"].children] == ["bls_device_wait"]
+    pairing = stages["bls_device_pairing"]
+    assert [c.name for c in pairing.children] == ["bls_device_wait", "bls_pairing_host_tail"]
+    (tail,) = [s for s in _walk(call) if s.name == "bls_pairing_host_tail"]
+    assert tail.fields == {"lanes": PAIRING_LANES}
+    assert all(tail not in list(_walk(w)) for w in waits)
     # the stages cover the call: only the span bookkeeping falls between
     assert sum(c.duration_s for c in call.children) <= call.duration_s
 
@@ -162,12 +176,19 @@ def test_device_verifier_chunks_get_a_span_each(monkeypatch):
     kp = bls.interop_keypairs(1)[0]
     msg = b"\x03" * 32
     sets = [bls.SignatureSet.single(kp.sk.sign(msg), kp.pk, msg)] * 3
+    before = _counts()
     with span("obs_device_root") as root:
         assert bls._TpuBackend().verify_signature_sets(sets)
+    after = _counts()
     calls = [c for c in root.children if c.name == "bls_device_verify"]
     assert [c.fields["sets"] for c in calls] == [2, 1]
     for c in calls:
         assert [s.name for s in c.children] == DEVICE_STAGES
+        (pairing,) = [s for s in c.children if s.name == "bls_device_pairing"]
+        assert [s.name for s in pairing.children] == ["bls_device_wait", "bls_pairing_host_tail"]
+    # one host tail a chunk: the tail counter moves with the batch counter
+    moved = {k: after[k] - before[k] for k in after}
+    assert moved["bls_pairing_host_tails_total"] == moved["bls_device_batches_total"] == 2
 
 
 # -- the gossip batch's stages -----------------------------------------------
