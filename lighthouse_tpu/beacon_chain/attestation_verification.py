@@ -9,14 +9,17 @@ TPU offload point for the gossip hot path (SURVEY.md §3.3).
 
 from __future__ import annotations
 
+from collections import OrderedDict
 from dataclasses import dataclass
 
 from ..crypto import bls
 from ..metrics import REGISTRY
+from ..state_processing import per_slot_processing
 from ..state_processing import signature_sets as sigsets
 from ..state_processing.accessors import (
     committee_cache_at,
     compute_epoch_at_slot,
+    compute_start_slot_at_epoch,
     get_attesting_indices,
 )
 from ..utils.tracing import span
@@ -39,6 +42,9 @@ _AGG_OBS_DELAY = REGISTRY.histogram(
     "aggregate's slot start → gossip verification reached it",
     buckets=_OBS_DELAY_BUCKETS,
 )
+
+# indexing states of forks off the head, one per (epoch, decision root)
+_FORK_STATES_MAX = 8
 
 
 class AttestationError(ValueError):
@@ -64,6 +70,8 @@ class AttestationVerifier:
 
     def __init__(self, chain):
         self.chain = chain
+        # (epoch, decision root) -> indexing state, for forks off the head
+        self._fork_states = OrderedDict()
 
     # -- shared structural checks -------------------------------------------
 
@@ -88,8 +96,40 @@ class AttestationVerifier:
 
     def _indexing_state(self, data):
         """A state able to compute committees for the attestation's epoch
-        (the shuffling cache role)."""
-        return self.chain.state_for_attestation_epoch(data.target.epoch)
+        (the shuffling cache role). An epoch's attester shuffling is fixed
+        by the block at the last slot of epoch - 2 (its decision root,
+        beacon_chain/src/shuffling_cache.rs): when the target's decision
+        root is the head's, the head's state serves, as it does for nearly
+        every vote. Otherwise the target block's state advanced to the
+        target epoch does, built once per (epoch, decision root)."""
+        chain = self.chain
+        epoch = data.target.epoch
+        state = chain.state_for_attestation_epoch(epoch)
+        decision_slot = max(compute_start_slot_at_epoch(max(epoch - 1, 0), chain.E) - 1, 0)
+        proto = chain.fork_choice.proto.proto_array
+        target = bytes(data.target.root)
+        decision = proto.ancestor_at_slot(target, decision_slot)
+        if decision is None or decision == proto.ancestor_at_slot(
+            bytes(chain.head_root), decision_slot
+        ):
+            return state
+        key = (epoch, decision)
+        cached = self._fork_states.get(key)
+        if cached is not None:
+            self._fork_states.move_to_end(key)
+            return cached
+        target_state = chain.state_for_block_root(target)
+        if target_state is None:
+            return state
+        start = compute_start_slot_at_epoch(epoch, chain.E)
+        if target_state.slot < start:
+            target_state = target_state.copy()
+            while target_state.slot < start:
+                per_slot_processing(target_state, chain.spec, chain.E)
+        self._fork_states[key] = target_state
+        if len(self._fork_states) > _FORK_STATES_MAX:
+            self._fork_states.popitem(last=False)
+        return target_state
 
     # -- unaggregated --------------------------------------------------------
 

@@ -14,6 +14,7 @@ import time
 from dataclasses import dataclass
 
 from ..fork_choice import ForkChoice
+from ..metrics import REGISTRY
 from ..state_processing import (
     BlockProcessingError,
     BlockSignatureStrategy,
@@ -39,6 +40,26 @@ from .attestation_verification import (
     ObservedCache,
 )
 from .op_pool import OperationPool
+
+
+# the import's stage spans and the gossip sidecar check's spans
+# (OBSERVABILITY.md): registered at import so their histograms read 0
+# before first use
+for _stage in (
+    "import_da_gate",
+    "import_state_root",
+    "import_store_write",
+    "import_head_update",
+    "blob_sidecar_verify",
+    "blob_header_signature",
+    "blob_kzg_verify",
+):
+    REGISTRY.histogram(
+        # lint: allow(metric-hygiene) -- bounded by the stage tuple above
+        f"trace_span_seconds_{_stage}",
+        f"span duration: {_stage}",
+    )
+del _stage
 
 
 class BlobsUnavailableError(ValueError):
@@ -174,7 +195,7 @@ class BeaconChain:
         # the anchor block is synthetic for genesis boots (never stored),
         # so replay's base search needs this root→state mapping pinned
         self.genesis_state_root = bytes(genesis_state_root)
-        store.put_state(genesis_state_root, genesis_state)
+        store.put_full_state(genesis_state_root, genesis_state)
         # restart anchor: boot stamps the (genesis or checkpoint) anchor;
         # every migration cycle re-points it at the newest finalized
         store.set_anchor_info(
@@ -412,13 +433,14 @@ class BeaconChain:
 
     def _load_state_for_block(self, block_root: bytes):
         """Fetch a block's post-state: hot/cold store by advertised state
-        root, falling back to replaying blocks from the nearest ancestor
+        root (a summarized hot state is replayed from its epoch-boundary
+        state), falling back to replaying blocks from the nearest ancestor
         whose state survives (the reference's BlockReplayer,
         state_processing/src/block_replayer.rs)."""
         signed = self._signed_block(block_root)
         if signed is None:
             return None
-        state = self.store.get_state(signed.message.state_root)
+        state = self.state_by_state_root(signed.message.state_root)
         if state is None:
             if signed.message.slot < self.store.split_slot:
                 # pre-split: restore-point snapshot + replay, memoized in
@@ -438,15 +460,43 @@ class BeaconChain:
             return blk
         return self.store.get_block(block_root)
 
-    def _replay_state(self, block_root: bytes):
+    def state_by_state_root(self, state_root: bytes):
+        """A stored state by its root: one stored in full, or a summarized
+        hot state rebuilt from its epoch-boundary state through the
+        blocks since (hot_cold_store.rs load_hot_state)."""
+        state = self.store.get_state(state_root)
+        if state is not None:
+            return state
+        summary = self.store.get_hot_state_summary(state_root)
+        if summary is None:
+            return None
+        state = self._replay_state(
+            bytes(summary.latest_block_root),
+            boundary_state_root=bytes(summary.epoch_boundary_state_root),
+        )
+        if state is not None:
+            while state.slot < summary.slot:
+                per_slot_processing(state, self.spec, self.E)
+        return state
+
+    def _replay_state(self, block_root: bytes, boundary_state_root=None):
         """Walk ancestors to the nearest retrievable state, then re-apply
         the intervening blocks (signatures already verified at first import;
-        the state-root check re-anchors every replayed block)."""
+        the state-root check re-anchors every replayed block). With
+        `boundary_state_root`, the walk stops at that epoch-boundary state
+        (a summary's base) once it reaches the boundary's slot; states
+        stored in full are the only other bases, so a replay never
+        starts another."""
         from ..state_processing.per_block import (
             BlockSignatureStrategy,
             per_block_processing,
         )
 
+        boundary = (
+            self.store.get_state(boundary_state_root)
+            if boundary_state_root is not None
+            else None
+        )
         chain = []
         r = block_root
         base = None
@@ -455,17 +505,24 @@ class BeaconChain:
                 base = self._states[r].copy()
                 break
             signed = self._signed_block(r)
+            if signed is None and r != self.genesis_block_root:
+                return None
+            slot = signed.message.slot if signed is not None else self.anchor_slot
+            if boundary is not None and slot <= boundary.slot:
+                # r is the boundary state's latest block: the state at the
+                # boundary slot is r's post-state advanced to it
+                base = boundary
+                break
             if signed is None:
                 # the anchor/genesis block is synthetic — no stored block
                 # maps its root to a state root, but the boot pinned the
                 # state itself (migration keeps a cold copy: slot 0 is
                 # always a restore point)
-                if r == self.genesis_block_root:
-                    st = self.store.get_state(self.genesis_state_root)
-                    if st is not None:
-                        base = st.copy()
-                        break
-                return None
+                st = self.store.get_state(self.genesis_state_root)
+                if st is None:
+                    return None
+                base = st.copy()
+                break
             st = self.store.get_state(signed.message.state_root)
             if st is not None:
                 base = st.copy()
@@ -563,9 +620,12 @@ class BeaconChain:
             signed_block=signed_block, block_root=block_root, pre_state=parent_state
         )
 
-    def _pre_state_for(self, block):
+    def _pre_state_for(self, block, boundary_states=None):
         """Parent post-state advanced to the block's slot (the
-        cheap_state_advance / catchup_state path)."""
+        cheap_state_advance / catchup_state path). An epoch-boundary state
+        the advance passes through (its slot had no block) is appended to
+        `boundary_states`, when given, for the import to store: later
+        summaries replay from it."""
         parent_state = self._states.get(block.parent_root)
         if parent_state is None:
             raise BlockError(f"no state for parent {block.parent_root.hex()[:16]}")
@@ -576,6 +636,12 @@ class BeaconChain:
         state = advanced if advanced is not None else parent_state.copy()
         while state.slot < block.slot:
             per_slot_processing(state, self.spec, self.E)
+            if (
+                boundary_states is not None
+                and state.slot < block.slot
+                and state.slot % self.E.SLOTS_PER_EPOCH == 0
+            ):
+                boundary_states.append(state.copy())
         return state
 
     def process_block(
@@ -654,16 +720,17 @@ class BeaconChain:
                 MissingComponentsError,
             )
 
-            try:
-                avail = self.data_availability_checker.put_block(
-                    block_root, signed_block, slot=current_slot
-                )
-            except MissingComponentsError as e:
-                # IGNORE class: nothing proven invalid, the block is
-                # staged — this forwarder must not be penalized
-                raise BlobsUnavailableError(f"data availability: {e}") from e
-            except AvailabilityCheckError as e:
-                raise BlockError(f"data availability: {e}") from e
+            with span("import_da_gate", commitments=len(commitments)):
+                try:
+                    avail = self.data_availability_checker.put_block(
+                        block_root, signed_block, slot=current_slot
+                    )
+                except MissingComponentsError as e:
+                    # IGNORE class: nothing proven invalid, the block is
+                    # staged — this forwarder must not be penalized
+                    raise BlobsUnavailableError(f"data availability: {e}") from e
+                except AvailabilityCheckError as e:
+                    raise BlockError(f"data availability: {e}") from e
             if not avail.available:
                 raise BlobsUnavailableError(
                     "blobs unavailable: feed sidecars via "
@@ -676,6 +743,7 @@ class BeaconChain:
             self.block_times_cache.stamp(name, _root, _slot, time.monotonic())
 
         ctxt = ConsensusContext(block.slot)
+        boundary_states = []
         if (
             precomputed_post_state is not None
             and block_root in segment_verified_roots
@@ -688,7 +756,9 @@ class BeaconChain:
             _milestone("payload_verified")
         else:
             state = (
-                pre_state if pre_state is not None else self._pre_state_for(block)
+                pre_state
+                if pre_state is not None
+                else self._pre_state_for(block, boundary_states)
             )
             strategy = (
                 BlockSignatureStrategy.NO_VERIFICATION
@@ -721,31 +791,25 @@ class BeaconChain:
             self.fork_choice.on_block(
                 current_slot, block, block_root, state, is_timely=is_timely
             )
-        for att in block.body.attestations:
-            try:
-                indexed = ctxt.get_indexed_attestation(state, att, self.E)
-            except Exception:
-                continue  # unindexable in this context
-            if self.slasher_service is not None:
-                try:
-                    self.slasher_service.observe_indexed_attestation(indexed)
-                except Exception:  # noqa: BLE001 — slasher faults must not
-                    pass  # cost fork choice its attestation weight
-            try:
-                self.fork_choice.on_attestation(indexed, is_from_block=True)
-            except Exception:
-                continue  # fork-choice-irrelevant attestations are skipped
 
-        self.store.put_block(block_root, signed_block)
-        self.store.put_state(block.state_root, state)
-        if imported_blobs:
-            # verified sidecars persist with the block so the node can
-            # serve BlobSidecarsByRange/Root for the DA window
-            self.store.put_blob_sidecars(block_root, imported_blobs)
-        if imported_columns:
-            # column route: persist the verified (or reconstructed-to-full)
-            # column set for DataColumnsByRange/Root serving
-            self.store.put_data_column_sidecars(block_root, imported_columns)
+        with span("import_store_write"):
+            self.store.put_block(block_root, signed_block)
+            # epoch-boundary states the advance passed through: summaries
+            # of this epoch's states replay from them
+            for st in boundary_states:
+                self.store.put_state(
+                    state.state_roots[st.slot % len(state.state_roots)], st
+                )
+            # in full at an epoch boundary, else a summary
+            self.store.put_state(block.state_root, state)
+            if imported_blobs:
+                # verified sidecars persist with the block so the node can
+                # serve BlobSidecarsByRange/Root for the DA window
+                self.store.put_blob_sidecars(block_root, imported_blobs)
+            if imported_columns:
+                # column route: persist the verified (or reconstructed-to-full)
+                # column set for DataColumnsByRange/Root serving
+                self.store.put_data_column_sidecars(block_root, imported_columns)
         self._states[block_root] = state
         self._blocks_by_root[block_root] = signed_block
         self.block_times_cache.set_imported(
@@ -764,7 +828,22 @@ class BeaconChain:
         if completed_epoch >= 0:
             self.validator_monitor.process_epoch_rollover(completed_epoch)
 
-        self.recompute_head()
+        with span("import_head_update"):
+            for att in block.body.attestations:
+                try:
+                    indexed = ctxt.get_indexed_attestation(state, att, self.E)
+                except Exception:
+                    continue  # unindexable in this context
+                if self.slasher_service is not None:
+                    try:
+                        self.slasher_service.observe_indexed_attestation(indexed)
+                    except Exception:  # noqa: BLE001 — slasher faults must not
+                        pass  # cost fork choice its attestation weight
+                try:
+                    self.fork_choice.on_attestation(indexed, is_from_block=True)
+                except Exception:
+                    continue  # fork-choice-irrelevant attestations are skipped
+            self.recompute_head()
         self.op_pool.prune(self.head_state)
         if commitments:
             self.data_availability_checker.pop(block_root)
@@ -975,17 +1054,23 @@ class BeaconChain:
             MissingComponentsError,
         )
 
-        self._verify_sidecar_headers(sidecars, verify_header_signature, "blob")
-        try:
-            return self.data_availability_checker.put_blobs(
-                block_root, sidecars, slot=self.slot_clock.now()
-            )
-        except MissingComponentsError as e:
-            # IGNORE class (spec): nothing proven invalid — the forwarder
-            # must not be penalized for locally missing prerequisites
-            raise BlobsUnavailableError(f"blob sidecars pending: {e}") from e
-        except AvailabilityCheckError as e:
-            raise BlockError(f"blob sidecars rejected: {e}") from e
+        # one trace per check: the header signature, then the KZG proofs
+        # (blob_kzg_verify, inside the availability checker)
+        with span("blob_sidecar_verify", n=len(sidecars)):
+            with span("blob_header_signature"):
+                self._verify_sidecar_headers(
+                    sidecars, verify_header_signature, "blob"
+                )
+            try:
+                return self.data_availability_checker.put_blobs(
+                    block_root, sidecars, slot=self.slot_clock.now()
+                )
+            except MissingComponentsError as e:
+                # IGNORE class (spec): nothing proven invalid — the forwarder
+                # must not be penalized for locally missing prerequisites
+                raise BlobsUnavailableError(f"blob sidecars pending: {e}") from e
+            except AvailabilityCheckError as e:
+                raise BlockError(f"blob sidecars rejected: {e}") from e
 
     def _verify_sidecar_headers(
         self, sidecars: list, verify_header_signature: bool, kind: str
@@ -1077,6 +1162,8 @@ class BeaconChain:
         """Roots whose sidecars fail KZG, found by batch-then-bisect: the
         whole segment is one RLC when clean (the common case); a failing
         batch splits on block boundaries until each failure is pinned."""
+        from .data_availability import verify_blob_proofs
+
         kzg = self.data_availability_checker.kzg
         if not groups or kzg is None:
             return set()
@@ -1088,7 +1175,7 @@ class BeaconChain:
                     blobs.append(bytes(sc.blob))
                     commitments.append(bytes(sc.kzg_commitment))
                     proofs.append(bytes(sc.kzg_proof))
-            return kzg.verify_blob_kzg_proof_batch(blobs, commitments, proofs)
+            return verify_blob_proofs(kzg, blobs, commitments, proofs)
 
         def bisect(gs) -> set:
             if not gs or batch_ok(gs):

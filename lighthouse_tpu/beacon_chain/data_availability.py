@@ -33,7 +33,25 @@ working. A commitment mismatch discovered for PREVIOUSLY staged sidecars
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, field
+
+from ..metrics import REGISTRY
+from ..utils.tracing import span
+
+_KZG_BLOBS_TOTAL = REGISTRY.counter(
+    "kzg_blob_verifications_total",
+    "blob KZG proofs the node checked (gossip, RPC and segment batches)",
+)
+_KZG_BLOBS_TOTAL.inc(0)
+
+
+def verify_blob_proofs(kzg, blobs: list, commitments: list, proofs: list) -> bool:
+    """One batch check of blob KZG proofs, counted per proof."""
+    with span("blob_kzg_verify", blobs=len(blobs)):
+        ok = kzg.verify_blob_kzg_proof_batch(blobs, commitments, proofs)
+    _KZG_BLOBS_TOTAL.inc(len(blobs))
+    return ok
 
 
 class AvailabilityCheckError(ValueError):
@@ -84,6 +102,11 @@ class DataAvailabilityChecker:
         #: from the node id via das.custody_columns)
         self.custody_columns = tuple(custody) if custody is not None else None
         self._pending: dict[bytes, PendingComponents] = {}
+        #: the block's and its sidecars' handlers stage components from
+        #: different processor workers: creating an entry and completing
+        #: the set must not interleave (two fresh entries for one root
+        #: would lose the components of the first)
+        self._lock = threading.RLock()
         #: finalization watermark (prune_before): components for slots
         #: behind it are refused, so an in-flight sampling fetch racing the
         #: finality prune cannot resurrect a pruned entry
@@ -148,7 +171,7 @@ class DataAvailabilityChecker:
             proofs.append(bytes(sc.kzg_proof))
         if skip_kzg:
             return
-        if not self.kzg.verify_blob_kzg_proof_batch(blobs, commitments, proofs):
+        if not verify_blob_proofs(self.kzg, blobs, commitments, proofs):
             raise InvalidComponentsError("blob KZG batch verification failed")
 
     def verify_column_sidecars(self, sidecars: list, block_root: bytes) -> None:
@@ -194,13 +217,14 @@ class DataAvailabilityChecker:
         self.verify_blob_sidecars(sidecars, block_root, skip_kzg=pre_verified)
         if self._behind_finality(sidecars):
             return Availability(available=False)
-        pend = self._bounded_entry(block_root)
-        pend.inserted_at_slot = max(pend.inserted_at_slot, slot)
-        new_indices = set()
-        for sc in sidecars:
-            pend.blobs[int(sc.index)] = sc
-            new_indices.add(int(sc.index))
-        return self.check_availability(block_root, new_indices=new_indices)
+        with self._lock:
+            pend = self._bounded_entry(block_root)
+            pend.inserted_at_slot = max(pend.inserted_at_slot, slot)
+            new_indices = set()
+            for sc in sidecars:
+                pend.blobs[int(sc.index)] = sc
+                new_indices.add(int(sc.index))
+            return self.check_availability(block_root, new_indices=new_indices)
 
     def put_columns(
         self, block_root: bytes, sidecars: list, slot: int = 0
@@ -208,20 +232,22 @@ class DataAvailabilityChecker:
         self.verify_column_sidecars(sidecars, block_root)
         if self._behind_finality(sidecars):
             return Availability(available=False)
-        pend = self._bounded_entry(block_root)
-        pend.inserted_at_slot = max(pend.inserted_at_slot, slot)
-        for sc in sidecars:
-            pend.columns[int(sc.index)] = sc
-        return self.check_availability(block_root)
+        with self._lock:
+            pend = self._bounded_entry(block_root)
+            pend.inserted_at_slot = max(pend.inserted_at_slot, slot)
+            for sc in sidecars:
+                pend.columns[int(sc.index)] = sc
+            return self.check_availability(block_root)
 
     def put_block(self, block_root: bytes, signed_block, slot: int = 0) -> Availability:
         blk_slot = getattr(signed_block.message, "slot", None)
         if blk_slot is not None and int(blk_slot) < self._finalized_slot:
             return Availability(available=False)
-        pend = self._bounded_entry(block_root)
-        pend.inserted_at_slot = max(pend.inserted_at_slot, slot)
-        pend.block = signed_block
-        return self.check_availability(block_root)
+        with self._lock:
+            pend = self._bounded_entry(block_root)
+            pend.inserted_at_slot = max(pend.inserted_at_slot, slot)
+            pend.block = signed_block
+            return self.check_availability(block_root)
 
     def set_sampling_result(self, block_root: bytes, ok: bool, slot: int = 0) -> Availability:
         """Record the SamplingEngine's verdict for a block (network layer).

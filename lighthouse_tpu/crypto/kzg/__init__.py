@@ -356,11 +356,10 @@ class _DeviceKzg:
         mb = 8
         while mb < m:
             mb *= 2
-        padded = list(evals_lists) + [evals_lists[0]] * (mb - m)
+        rows = np.stack([fr_to_device(e) for e in evals_lists])
+        # the pad lanes repeat the first blob's rows, converted once
+        ev = jnp.asarray(np.concatenate([rows, np.repeat(rows[:1], mb - m, axis=0)]))
         zs_p = list(zs) + [zs[0]] * (mb - m)
-        ev = jnp.asarray(
-            np.stack([fr_to_device(e) for e in padded])
-        )
         z_dev = jnp.asarray(fr_to_device(zs_p))
         ys = barycentric_eval_batch(ev, self.roots, z_dev, self.log_n)
         return fr_from_device(ys)[:m]

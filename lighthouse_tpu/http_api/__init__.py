@@ -174,7 +174,7 @@ class BeaconApi:
                 root = bytes.fromhex(state_id[2:])
             except ValueError as e:
                 raise ApiError(400, f"invalid state id {state_id}") from e
-            st = chain.store.get_state(root)
+            st = chain.state_by_state_root(root)
             if st is None:
                 raise ApiError(404, f"state {state_id} not found")
             return root, st

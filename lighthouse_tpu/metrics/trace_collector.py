@@ -42,6 +42,7 @@ ROOT_SPAN_NAMES = (
     "fork_choice_get_head",
     "slasher_process",
     "da_verify",
+    "blob_sidecar_verify",
     "block_production",
     "vc_duty_cycle",
 )

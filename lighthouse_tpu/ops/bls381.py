@@ -47,6 +47,15 @@ def int_to_limbs(x: int, n: int = NLIMB) -> np.ndarray:
     return np.array([(x >> (BASE * i)) & MASK for i in range(n)], dtype=np.int32)
 
 
+def ints_to_limbs(values, n: int = NLIMB) -> np.ndarray:
+    """[len(values), n] limbs of non-negative ints below 2^(BASE·n): with
+    8-bit limbs they are each int's little-endian bytes, converted in one
+    pass over their concatenation."""
+    assert BASE == 8, "one limb a byte"
+    buf = b"".join(v.to_bytes(n, "little") for v in values)
+    return np.frombuffer(buf, dtype=np.uint8).reshape(-1, n).astype(np.int32)
+
+
 def limbs_to_int(limbs) -> int:
     arr = np.asarray(limbs, dtype=np.int64)
     return sum(int(v) << (BASE * i) for i, v in enumerate(arr))
@@ -456,9 +465,7 @@ def fold_sum(F, acc, pt):
 
 def fq_to_device(values: list[int]) -> np.ndarray:
     """List of field ints → [batch, 48] Montgomery limb array."""
-    return np.stack(
-        [int_to_limbs(v * R_MONT % P) for v in values]
-    ).astype(np.int32)
+    return ints_to_limbs([v * R_MONT % P for v in values])
 
 
 def fq_from_device(arr) -> list[int]:

@@ -172,31 +172,20 @@ _G1_INF_LIMBS = np.zeros(NLIMB, dtype=np.int32)
 def _g1_affine_grid_to_device(grids):
     """[n][k] host affine-or-None G1 → Jacobian device arrays [n, k, 48]×3
     (infinity encoded z=0)."""
-    from .bls381 import R_MONT, int_to_limbs
+    from .bls381 import R_MONT, int_to_limbs, ints_to_limbs
 
     n = len(grids)
     k = len(grids[0])
-    xs = np.zeros((n, k, NLIMB), dtype=np.int32)
-    ys = np.zeros((n, k, NLIMB), dtype=np.int32)
-    zs = np.zeros((n, k, NLIMB), dtype=np.int32)
-    one = int_to_limbs(R_MONT % P)
-    cache: dict = {}
-    for i, row in enumerate(grids):
-        for j, aff in enumerate(row):
-            if aff is None:
-                continue
-            key = aff[0]
-            ent = cache.get(key)
-            if ent is None:
-                ent = (
-                    int_to_limbs(aff[0] * R_MONT % P),
-                    int_to_limbs(aff[1] * R_MONT % P),
-                )
-                cache[key] = ent
-            xs[i, j] = ent[0]
-            ys[i, j] = ent[1]
-            zs[i, j] = one
-    return jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(zs)
+    flat = [aff for row in grids for aff in row]
+    live = [i for i, aff in enumerate(flat) if aff is not None]
+    xs = np.zeros((n * k, NLIMB), dtype=np.int32)
+    ys = np.zeros((n * k, NLIMB), dtype=np.int32)
+    zs = np.zeros((n * k, NLIMB), dtype=np.int32)
+    if live:
+        xs[live] = ints_to_limbs([flat[i][0] * R_MONT % P for i in live])
+        ys[live] = ints_to_limbs([flat[i][1] * R_MONT % P for i in live])
+        zs[live] = int_to_limbs(R_MONT % P)
+    return tuple(jnp.asarray(a.reshape(n, k, NLIMB)) for a in (xs, ys, zs))
 
 
 # ---------------------------------------------------------------------------

@@ -37,6 +37,7 @@ from .bls381 import (
     _mod_add,
     _mod_sub,
     _mont_mul,
+    ints_to_limbs,
 )
 
 NLIMB_FR = 32  # 32 × 8-bit limbs = 256 bits
@@ -118,9 +119,7 @@ def _tree_sum(v):
 
 def fr_to_device(values) -> np.ndarray:
     """Iterable of ints mod r → [n, 32] Montgomery limb array."""
-    return np.stack(
-        [_int_to_limbs(v % FR_MOD * R_MONT_FR % FR_MOD) for v in values]
-    ).astype(np.int32)
+    return ints_to_limbs([v % FR_MOD * R_MONT_FR % FR_MOD for v in values], NLIMB_FR)
 
 
 def fr_from_device(arr) -> list[int]:

@@ -843,6 +843,10 @@ class Container(SSZType, metaclass=_ContainerMeta):
         cols = self.__dict__.get("_registry_columns")
         if cols is not None:
             out.__dict__["_registry_columns"] = cols.copy()
+        # the state's shufflings (state_processing/accessors.StateCaches)
+        caches = self.__dict__.get("_lh_caches")
+        if caches is not None:
+            out.__dict__["_lh_caches"] = caches.copy()
         return out
 
     # -- SSZType protocol ---------------------------------------------------

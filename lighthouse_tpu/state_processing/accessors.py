@@ -296,6 +296,17 @@ class StateCaches:
         self.committees: dict[int, CommitteeCache] = {}
         self.duty_tables: dict[int, EpochDutyTable] = {}
 
+    def copy(self) -> "StateCaches":
+        """A copy of the state carries its shufflings, as the reference's
+        state clone carries its committee caches: the entries are frozen,
+        and an epoch's shuffling cannot change while the state stays in
+        the epochs it was built for (epoch processing and fork upgrades
+        invalidate). Each side then caches on its own."""
+        out = StateCaches()
+        out.committees = dict(self.committees)
+        out.duty_tables = dict(self.duty_tables)
+        return out
+
 
 def _caches(state) -> StateCaches:
     c = getattr(state, "_lh_caches", None)

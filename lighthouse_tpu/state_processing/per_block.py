@@ -358,7 +358,8 @@ def _per_block_processing_inner(
         )
 
     if verify_block_root:
-        expected = state.hash_tree_root()
+        with span("import_state_root"):
+            expected = state.hash_tree_root()
         if block.state_root != expected:
             raise BlockProcessingError(
                 f"state root mismatch: block {block.state_root.hex()} != "

@@ -2,14 +2,22 @@
 
 Mirrors beacon_node/store/src/hot_cold_store.rs:50-55: hot (recent,
 unfinalized) data separate from cold (finalized history), split at the
-finalization boundary; states in the hot DB carry summaries, cold states are
-reconstructable from restore points. The hot side + split bookkeeping live
-here; the finality-driven migration cycle, periodic cold restore-point
+finalization boundary. Hot states are stored as the reference's
+`store_hot_state` stores them: in full at an epoch boundary, and as a
+`HotStateSummary` (slot, latest block root, the epoch-boundary state's
+root) at any other slot, rebuilt on read by replaying the blocks since that
+boundary state (the chain's replay; the store holds data only). The anchor
+state a node boots from is always written in full. Cold states
+are reconstructable from restore points. The hot side + split bookkeeping
+live here; the finality-driven migration cycle, periodic cold restore-point
 snapshots, and snapshot+replay reconstruction of intermediate cold states
 (store/src/reconstruct.rs) live in `store/migrator.py`."""
 
 from __future__ import annotations
 
+from dataclasses import dataclass
+
+from ..metrics import REGISTRY
 from ..types.chain_spec import ForkName
 from .kv import DBColumn, ItemStore, MemoryStore
 
@@ -44,8 +52,49 @@ _FORK_TAGS = [
 _TAG_OF_FORK = {f: i for i, f in enumerate(_FORK_TAGS)}
 
 
+_SUMMARIES_TOTAL = REGISTRY.counter(
+    "store_hot_state_summaries_total",
+    "hot states stored as a summary (slot, latest block root, boundary state root)",
+)
+_SUMMARIES_TOTAL.inc(0)
+_FULL_TOTAL = REGISTRY.counter(
+    "store_hot_states_full_total",
+    "hot states stored in full (epoch boundaries and boot anchors)",
+)
+_FULL_TOTAL.inc(0)
+
+# a stored BeaconState is its fork tag, then genesis_time (8 bytes),
+# genesis_validators_root (32) and slot (8): the slot is readable from the
+# value's prefix without decoding the state
+_STATE_SLOT_AT = 1 + 8 + 32
+
+
 class StoreError(ValueError):
     pass
+
+
+@dataclass(frozen=True)
+class HotStateSummary:
+    """A hot state that is not stored in full (hot_cold_store.rs
+    HotStateSummary): its slot, the root of the block it was built on, and
+    the root of the epoch-boundary state its replay starts from."""
+
+    slot: int
+    latest_block_root: bytes
+    epoch_boundary_state_root: bytes
+
+    def to_bytes(self) -> bytes:
+        return (
+            int(self.slot).to_bytes(8, "little")
+            + bytes(self.latest_block_root)
+            + bytes(self.epoch_boundary_state_root)
+        )
+
+    @classmethod
+    def from_bytes(cls, raw: bytes) -> "HotStateSummary":
+        if len(raw) != 72:
+            raise StoreError(f"hot state summary of {len(raw)} bytes")
+        return cls(int.from_bytes(raw[:8], "little"), raw[8:40], raw[40:72])
 
 
 class SchemaVersionError(StoreError):
@@ -287,12 +336,41 @@ class HotColdDB:
     # -- states ------------------------------------------------------------
 
     def put_state(self, state_root: bytes, state):
-        """Unfinalized state → hot DB (the split invariant: hot holds
-        recent states, cold holds restore-point snapshots only)."""
+        """Store a hot state as the reference's `store_hot_state` does: in
+        full when its slot is an epoch boundary, else as a summary that
+        names the epoch-boundary state its replay starts from."""
+        slots_per_epoch = self.types.preset.SLOTS_PER_EPOCH
+        slot = int(state.slot)
+        if slot % slots_per_epoch == 0:
+            self.put_full_state(state_root, state)
+            return
+        header = state.latest_block_header
+        if bytes(header.state_root) == bytes(32):
+            # the block's own post-state: its header waits for this root
+            header = type(header)(
+                slot=header.slot,
+                proposer_index=header.proposer_index,
+                parent_root=header.parent_root,
+                state_root=state_root,
+                body_root=header.body_root,
+            )
+        boundary = slot - slot % slots_per_epoch
+        summary = HotStateSummary(
+            slot,
+            bytes(header.hash_tree_root()),
+            bytes(state.state_roots[boundary % len(state.state_roots)]),
+        )
+        self.hot.put(DBColumn.HOT_STATE_SUMMARY, state_root, summary.to_bytes())
+        _SUMMARIES_TOTAL.inc()
+
+    def put_full_state(self, state_root: bytes, state):
+        """Store a hot state in full, whatever its slot: epoch boundaries,
+        and the anchor a node boots from (every replay starts from one)."""
         fork = self.types.fork_of_state(state)
         self.hot.put(
             DBColumn.BEACON_STATE, state_root, self._encode(state, fork)
         )
+        _FULL_TOTAL.inc()
 
     def put_cold_state(self, state_root: bytes, state):
         """Finalized restore-point snapshot → cold DB explicitly. The old
@@ -304,6 +382,8 @@ class HotColdDB:
         )
 
     def get_state(self, state_root: bytes):
+        """A state stored in full, hot or cold; None for a summarized or
+        unknown root (the chain replays a summarized one)."""
         data = self.hot.get(DBColumn.BEACON_STATE, state_root)
         if data is None:
             data = self.cold.get(DBColumn.BEACON_STATE, state_root)
@@ -311,18 +391,46 @@ class HotColdDB:
             return None
         return self._decode(data, "BeaconState")
 
+    def get_hot_state_summary(self, state_root: bytes) -> HotStateSummary | None:
+        raw = self.hot.get(DBColumn.HOT_STATE_SUMMARY, state_root)
+        return None if raw is None else HotStateSummary.from_bytes(raw)
+
     def delete_state(self, state_root: bytes, side: str = "both"):
         """Side-aware deletion. Default removes BOTH copies — a state
         migrated to cold and then deleted must not resurrect through
         `get_state`'s cold fallback. The migrator passes side="hot" when
         it intentionally keeps (or just wrote) a cold snapshot of the
-        same root."""
+        same root. The hot side holds a state in full or as a summary;
+        both go."""
         if side not in ("both", "hot", "cold"):
             raise StoreError(f"unknown state deletion side {side!r}")
         if side in ("both", "hot"):
             self.hot.delete(DBColumn.BEACON_STATE, state_root)
+            self.hot.delete(DBColumn.HOT_STATE_SUMMARY, state_root)
         if side in ("both", "cold"):
             self.cold.delete(DBColumn.BEACON_STATE, state_root)
+
+    def delete_hot_states_before(self, slot: int, keep=()) -> int:
+        """Delete every hot state, full or summarized, whose slot is below
+        `slot`, but the roots in `keep`; returns how many went. The
+        migrator's sweep below the split: it also reaches states the
+        snapshot cache no longer holds."""
+        keep = {bytes(r) for r in keep}
+        doomed = []
+        for root in self.hot.keys(DBColumn.HOT_STATE_SUMMARY):
+            raw = self.hot.get(DBColumn.HOT_STATE_SUMMARY, root)
+            if raw is not None and HotStateSummary.from_bytes(raw).slot < slot:
+                doomed.append((DBColumn.HOT_STATE_SUMMARY, root))
+        for root in self.hot.keys(DBColumn.BEACON_STATE):
+            prefix = self.hot.get_prefix(
+                DBColumn.BEACON_STATE, root, _STATE_SLOT_AT + 8
+            )
+            if prefix is not None and len(prefix) == _STATE_SLOT_AT + 8:
+                if int.from_bytes(prefix[_STATE_SLOT_AT:], "little") < slot:
+                    doomed.append((DBColumn.BEACON_STATE, root))
+        ops = [("delete", col, root) for col, root in doomed if root not in keep]
+        self.hot.do_atomically(ops)
+        return len(ops)
 
     # -- metadata ----------------------------------------------------------
 

@@ -3,9 +3,9 @@
 The beacon_chain/src/migrate.rs analog: every time the finalized
 checkpoint advances, finalized canonical blocks move hot→cold (the
 split advances with them), abandoned forks are dropped, hot states
-strictly before the split are pruned, and the DA availability window is
-trimmed — all in one migration cycle. On top of the reference's block
-migration this module owns the cold-state story
+strictly before the split are pruned (in full or as summaries), and the
+DA availability window is trimmed — all in one migration cycle. On top
+of the reference's block migration this module owns the cold-state story
 (store/src/reconstruct.rs): every `slots_per_restore_point` slots the
 about-to-be-pruned canonical state is written to the COLD db as a
 restore point, and `reconstruct_state` rebuilds any intermediate
@@ -269,6 +269,13 @@ class BackgroundMigrator:
             chain.observed_block_producers.prune(finalized_slot)  # by slot
 
             self._persist_resume_point(finalized)
+            # hot states below the split the snapshot cache no longer
+            # held: summaries, and boundary states stored in full
+            kept = chain._blocks_by_root.get(finalized.root)
+            store.delete_hot_states_before(
+                finalized_slot,
+                keep=[kept.message.state_root] if kept is not None else (),
+            )
             store.bump_generation()
             inc_counter("store_migrations_total")
             set_gauge("store_split_slot", store.split_slot)
@@ -294,7 +301,7 @@ class BackgroundMigrator:
         if chain.store.cold.get(DBColumn.BEACON_STATE, state_root) is None:
             st = chain._states.get(finalized.root)
             if st is None:
-                st = chain.store.get_state(state_root)
+                st = chain.state_by_state_root(state_root)
             if st is not None:
                 chain.store.put_cold_state(state_root, st)
         chain.store.set_anchor_info(

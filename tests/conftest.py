@@ -83,6 +83,10 @@ def pytest_sessionstart(session):
     from lighthouse_tpu.beacon_chain import (  # noqa: F401 — registers
         checkpoint_sync,  # boot counter + anchor-slot gauge
     )
+    from lighthouse_tpu.beacon_chain import (  # noqa: F401 — registers
+        chain,  # import + sidecar stage spans (hot_cold: store_hot_*)
+        data_availability,  # kzg_blob_verifications_total
+    )
 
     text = REGISTRY.expose()
     for needle in (
@@ -378,6 +382,20 @@ def pytest_sessionstart(session):
         "checkpoint_sync_anchor_slot",
         "trace_span_seconds_store_prune",
         "trace_span_seconds_store_reconstruct",
+        # block import at mainnet size: the hot store's summary/full
+        # split, the blob-proof count the import-full-blocks cell checks,
+        # and the import and sidecar stage spans its metrics read
+        "store_hot_state_summaries_total",
+        "store_hot_states_full_total",
+        "kzg_blob_verifications_total",
+        "trace_span_seconds_import_da_gate",
+        "trace_span_seconds_import_state_root",
+        "trace_span_seconds_import_store_write",
+        "trace_span_seconds_import_head_update",
+        "trace_span_seconds_blob_sidecar_verify",
+        "trace_span_seconds_blob_header_signature",
+        "trace_span_seconds_blob_kzg_verify",
+        'trace_collector_traces_total{root="blob_sidecar_verify"}',
     ):
         assert needle in text, (
             f"metric series {needle} missing from metrics exposition"

@@ -196,7 +196,7 @@ def test_store_roundtrip(harness):
     head_block = chain.head_block()
     stored = chain.store.get_block(chain.head_root)
     assert stored.hash_tree_root() == head_block.hash_tree_root()
-    state = chain.store.get_state(head_block.message.state_root)
+    state = chain.state_by_state_root(head_block.message.state_root)
     assert state.slot == chain.head_state.slot
     assert state.hash_tree_root() == chain.head_state.hash_tree_root()
 
