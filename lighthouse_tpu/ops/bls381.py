@@ -222,9 +222,12 @@ _UNIT = int_to_limbs(1)
 _UNIT_PLUS_P = _UNIT + _P_LIMBS  # limbwise; normalized with the sum
 
 
+@jax.jit
 def mont_mul(a, b):
     """Montgomery product a·b·R⁻¹ mod p. Inputs/outputs: [..., 48] int32,
-    limbs < 2^8, value < p."""
+    limbs < 2^8, value < p. Jitted, like the point ops below: a program
+    that holds hundreds of them traces each once a shape, and XLA inlines
+    the calls, so the device code is the same."""
     return _mont_mul(a, b, _P_LIMBS, _NPRIME_LIMBS, jnp.asarray(_PBAR_LIMBS))
 
 
@@ -304,6 +307,7 @@ class DevFq2:
         return mod_sub(a, b)
 
     @staticmethod
+    @jax.jit
     def mul(a, b):
         a, b = jnp.broadcast_arrays(a, b)
         a0, a1 = a[..., 0, :], a[..., 1, :]
@@ -319,6 +323,7 @@ class DevFq2:
         return jnp.stack([d[..., 0, :], c1], -2)
 
     @staticmethod
+    @jax.jit
     def sqr(a):
         """(a0+a1)(a0−a1) + 2·a0·a1·u."""
         a0, a1 = a[..., 0, :], a[..., 1, :]
@@ -354,6 +359,7 @@ def _stack(*xs):
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def pt_double(F, pt):
     """Jacobian doubling (dbl-2009-l). Independent products share one
     stacked F.mul call: three multiplication stages in all."""
@@ -372,6 +378,7 @@ def pt_double(F, pt):
     return (x3, y3, z3)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def pt_add(F, p1, p2):
     """Branchless Jacobian add handling infinity and doubling cases via
     selects (device code cannot branch per lane). Independent products
@@ -417,9 +424,10 @@ def pt_add(F, p1, p2):
     return (x3, y3, z3)
 
 
-def pt_scalar_mul(F, pt, scalar_bits):
+def pt_scalar_mul(F, pt, scalar_bits, start=None):
     """Batch double-and-add: scalar_bits [batch, nbits] int32 (LSB first),
-    pt = tuple of [batch, ...] coords. Fixed trip count, branchless."""
+    pt = tuple of [batch, ...] coords. Fixed trip count, branchless.
+    Returns start + [k]pt, start infinity unless given."""
     nbits = scalar_bits.shape[-1]
 
     def body(i, carry):
@@ -442,8 +450,8 @@ def pt_scalar_mul(F, pt, scalar_bits):
         one = jnp.stack([one_mont, jnp.zeros_like(one_mont)], axis=-2)
     else:
         one = one_mont
-    inf = (one, one, zero)
-    acc, _ = lax.fori_loop(0, nbits, body, (inf, pt))
+    acc = (one, one, zero) if start is None else start
+    acc, _ = lax.fori_loop(0, nbits, body, (acc, pt))
     return acc
 
 

@@ -12,18 +12,24 @@ message batch.
 Design notes:
 * Square roots use the complex method (p ≡ 3 mod 4), mirrored branchlessly
   from the host oracle `crypto/bls12_381/fields.py:f2_sqrt`: all four
-  Fq-sqrt candidate exponentiations are STACKED into one fixed 379-bit
-  square-and-multiply scan (lax.scan over static exponent bits), then
-  per-lane selects pick the valid candidate. Non-square inputs yield
-  garbage lanes that the SSWU select masks out — exactly one of
-  gx1/gx2 is square, so the chosen lane is always exact.
+  Fq-sqrt candidate exponentiations are STACKED into one pass of the
+  fixed-window pow loop (`bls381_tower.fq_pow_digits`), then per-lane
+  selects pick the valid candidate. Non-square inputs yield garbage
+  lanes that the SSWU select masks out — exactly one of gx1/gx2 is
+  square, so the chosen lane is always exact.
 * sgn0 needs canonical (non-Montgomery) parity: one extra mont_mul per
   coordinate converts out of Montgomery form.
 * The 3-isogeny constants are taken from the host module (derived there
   via Vélu's formulas, pinned to RFC 9380 §E.3 by tests) and pushed as
   Montgomery limb constants.
-* Cofactor clearing reuses `bls381_pairing.g2_clear_cofactor_device`
+* Cofactor clearing is `bls381_pairing.g2_clear_cofactor_device`
   (Budroni–Pintore x-ladders + ψ).
+* The whole field→group stage is ONE device program
+  (`hash_to_g2_device`): every pow chain and ladder stays a loop, traced
+  once and never unrolled — an unrolled graph is what made XLA-CPU's LLVM
+  stage blow up. SSWU and the isogeny take four pow passes: 1/tv, the
+  square roots' two, and one inversion shared by 1/(2t) and the
+  isogeny's 1/(x − x0), taken for both x candidates before the select.
 """
 
 from __future__ import annotations
@@ -32,12 +38,11 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ..crypto.bls12_381 import hash_to_curve as HH
 from ..crypto.bls12_381.fields import P
-from .bls381 import NLIMB, int_to_limbs, mont_mul, mod_add, mod_sub
-from .bls381_pairing import _jit_pt_add_g2, _one_fq2, g2_clear_cofactor_device
+from .bls381 import NLIMB, DevFq2, int_to_limbs, mont_mul, mod_add, mod_sub, pt_add
+from .bls381_pairing import _one_fq2, g2_clear_cofactor_device
 from .bls381_tower import (
     f2_add,
     f2_inv,
@@ -49,8 +54,10 @@ from .bls381_tower import (
     f2_sub,
     fq2_const,
     fq_const,
-    fq_inv_staged,
-    fq_pow_fixed,
+    f2_norm,
+    f2_scale_inv,
+    fq_inv,
+    fq_pow_digits,
     pow_digits,
 )
 
@@ -78,15 +85,13 @@ def _fq_is_zero(a):
     return jnp.all(a == 0, axis=-1)
 
 
-@jax.jit
-def _jit_sqrt_norm(a):
+def _sqrt_norm(a):
     x, y = a[..., 0, :], a[..., 1, :]
     return mod_add(mont_mul(x, x), mont_mul(y, y))
 
 
-@jax.jit
-def _jit_sqrt_candidates(a, n):
-    """Stack the four Fq-sqrt candidate bases for one shared pow scan."""
+def _sqrt_candidates(a, n):
+    """Stack the four Fq-sqrt candidate bases for one pow pass."""
     x = a[..., 0, :]
     inv2 = jnp.asarray(_INV2_DEV)
     half_a = mont_mul(mod_add(x, n), inv2)
@@ -95,8 +100,7 @@ def _jit_sqrt_candidates(a, n):
     return jnp.stack([half_a, half_b, x, neg_x], axis=0)
 
 
-@jax.jit
-def _jit_sqrt_pick_t(a, n, roots):
+def _sqrt_pick_t(a, n, roots):
     """Select the valid complex-method candidate; returns (t, 2t)."""
     x = a[..., 0, :]
     inv2 = jnp.asarray(_INV2_DEV)
@@ -107,8 +111,7 @@ def _jit_sqrt_pick_t(a, n, roots):
     return t, mod_add(t, t)
 
 
-@jax.jit
-def _jit_sqrt_finish(a, roots, t, inv_2t):
+def _sqrt_finish(a, roots, t, inv_2t):
     """Assemble (root, is_square) from the candidates + 1/(2t)."""
     x, y = a[..., 0, :], a[..., 1, :]
     zero = jnp.zeros_like(x)
@@ -137,39 +140,13 @@ def _jit_sqrt_finish(a, roots, t, inv_2t):
     return root, is_sq
 
 
-def f2_sqrt_device(a):
-    """Batched Fq2 square root (complex method, p ≡ 3 mod 4).
-
-    Returns (root, is_square); non-square lanes yield garbage roots with
-    is_square False. Mirrors crypto/bls12_381/fields.py:f2_sqrt. Staged as
-    small jits around the shared pow scan — one mega-jit here made XLA-CPU
-    compile for hours."""
-    norm = _jit_sqrt_norm(a)
-    n = fq_pow_fixed(norm, _SQRT_DIGITS)
-    roots = fq_pow_fixed(_jit_sqrt_candidates(a, n), _SQRT_DIGITS)
-    t, two_t = _jit_sqrt_pick_t(a, n, roots)
-    inv_2t = fq_inv_staged(two_t)
-    return _jit_sqrt_finish(a, roots, t, inv_2t)
-
-
-@jax.jit
-def _jit_f2_norm(a):
-    a0, a1 = a[..., 0, :], a[..., 1, :]
-    return mod_add(mont_mul(a0, a0), mont_mul(a1, a1))
-
-
-@jax.jit
-def _jit_f2_scale_inv(a, ninv):
-    a0, a1 = a[..., 0, :], a[..., 1, :]
-    return jnp.stack(
-        [mont_mul(a0, ninv), mod_sub(jnp.zeros_like(a0), mont_mul(a1, ninv))],
-        axis=-2,
-    )
-
-
-def f2_inv_staged(a):
-    """Fq2 inversion with the Fq pow hoisted to the shared scan."""
-    return _jit_f2_scale_inv(a, fq_inv_staged(_jit_f2_norm(a)))
+def _sqrt_roots(a):
+    """The candidate roots and (t, 2t): two pow passes, the norm's root
+    then the four stacked candidates; the root needs 1/(2t) besides."""
+    digits = jnp.asarray(_SQRT_DIGITS)
+    n = fq_pow_digits(_sqrt_norm(a), digits)
+    roots = fq_pow_digits(_sqrt_candidates(a, n), digits)
+    return (roots, *_sqrt_pick_t(a, n, roots))
 
 
 def _from_mont_fq(a):
@@ -194,16 +171,16 @@ def _gx(x):
     return f2_add(f2_add(f2_mul(f2_sqr(x), x), f2_mul(a, x)), b)
 
 
-@jax.jit
-def _jit_sswu_tv(u):
+def map_to_e2(u):
+    """Batched simplified SWU onto E', then the 3-isogeny to E2
+    ([..., 2, 48] → affine (x, y)) in four pow passes: 1/tv, the two of
+    the stacked square root of both gx candidates, then one inversion of
+    2t and of both x − x0 candidates, picked by the select after it."""
     z = jnp.asarray(_Z_DEV)
     z_u2 = f2_mul(z, f2_sqr(u))
     tv = f2_add(f2_sqr(z_u2), z_u2)
-    return z_u2, tv
+    tv_inv = f2_inv(tv)
 
-
-@jax.jit
-def _jit_sswu_gx(u, z_u2, tv, tv_inv):
     tv_zero = f2_is_zero(tv)
     one = jnp.broadcast_to(jnp.asarray(_ONE_F2_DEV), u.shape).astype(jnp.int32)
     x1_main = f2_mul(jnp.asarray(_MBA_DEV), f2_add(one, tv_inv))
@@ -212,38 +189,25 @@ def _jit_sswu_gx(u, z_u2, tv, tv_inv):
         jnp.broadcast_to(jnp.asarray(_BZA_DEV), u.shape).astype(jnp.int32),
         x1_main,
     )
-    gx1 = _gx(x1)
     x2 = f2_mul(z_u2, x1)
-    gx2 = _gx(x2)
-    return x1, gx1, x2, gx2
+    gx = jnp.stack([_gx(x1), _gx(x2)], axis=0)
+    roots, t, two_t = _sqrt_roots(gx)
+    d = f2_sub(jnp.stack([x1, x2], axis=0), jnp.asarray(_X0_DEV))
+    inv = fq_inv(jnp.stack([two_t, f2_norm(d)], axis=0))
+    root, is_sq = _sqrt_finish(gx, roots, t, inv[0])
 
-
-@jax.jit
-def _jit_sswu_select(u, x1, x2, roots, is_sq):
-    y1, y2 = roots[0], roots[1]
     sq1 = is_sq[0]
     x = f2_select(sq1, x1, x2)
-    y = f2_select(sq1, y1, y2)
+    y = f2_select(sq1, root[0], root[1])
     flip = f2_sgn0_device(u) != f2_sgn0_device(y)
     y = f2_select(flip, f2_neg(y), y)
-    return x, y
+    d_inv = f2_scale_inv(f2_select(sq1, d[0], d[1]), jnp.where(sq1[..., None], *inv[1]))
+    return _isogeny(x, y, d_inv)
 
 
-def map_to_curve_sswu_device(u):
-    """Batched simplified SWU onto E' ([..., 2, 48] → affine (x, y)).
-    Staged orchestrator: tv → shared-scan inversion → gx candidates →
-    staged sqrt → selects."""
-    z_u2, tv = _jit_sswu_tv(u)
-    tv_inv = f2_inv_staged(tv)
-    x1, gx1, x2, gx2 = _jit_sswu_gx(u, z_u2, tv, tv_inv)
-    roots, is_sq = f2_sqrt_device(jnp.stack([gx1, gx2], axis=0))
-    return _jit_sswu_select(u, x1, x2, roots, is_sq)
-
-
-@jax.jit
-def _jit_iso(x, y, d_inv):
-    """3-isogeny E' → E2 with 1/(x - x0) precomputed (Vélu-derived, RFC
-    9380 §E.3-pinned — mirrors the host `_isogeny_to_e2`)."""
+def _isogeny(x, y, d_inv):
+    """3-isogeny E' → E2 (Vélu-derived, RFC 9380 §E.3-pinned — mirrors the
+    host `_isogeny_to_e2`), given d_inv = 1/(x − x0)."""
     d_inv2 = f2_sqr(d_inv)
     d_inv3 = f2_mul(d_inv2, d_inv)
     t = jnp.asarray(_T_DEV)
@@ -257,31 +221,21 @@ def _jit_iso(x, y, d_inv):
     return phi_x, phi_y
 
 
-def isogeny_to_e2_device(x, y):
-    d = f2_sub(x, jnp.asarray(_X0_DEV))
-    return _jit_iso(x, y, f2_inv_staged(d))
-
-
-# g2_clear_cofactor_device orchestrates its own staged jits
-
-
+@jax.jit
 def hash_to_g2_device(u):
-    """Batched hash_to_curve field→group stage.
+    """Batched hash_to_curve field→group stage, one device program.
 
     u: [n, 2, 2, 48] — per message the two hash_to_field outputs u0, u1
     (Montgomery limbs). Returns Jacobian twisted G2 points ([n, 2, 48]×3)
-    in the r-torsion subgroup. Python-level orchestration over staged jits
-    (see fq_pow_fixed docstring for why)."""
-    u = jnp.asarray(u)
+    in the r-torsion subgroup: SSWU, the isogeny, the sum of the two maps
+    and the cofactor clearing, with every pow chain and ladder a loop."""
     n = u.shape[0]
     # stack all u0 then all u1 (NOT a raw reshape, which would interleave
     # messages): lanes [0:n] are u0 maps, [n:2n] are u1 maps.
     flat = jnp.concatenate([u[:, 0], u[:, 1]], axis=0)
-    x, y = map_to_curve_sswu_device(flat)
-    px, py = isogeny_to_e2_device(x, y)
+    px, py = map_to_e2(flat)
     one2 = _one_fq2((n,))
-    # the cofactor-clearing G2 add ([n] lanes) serves this sum too
-    s = _jit_pt_add_g2(px[:n], py[:n], one2, px[n:], py[n:], one2)
+    s = pt_add(DevFq2, (px[:n], py[:n], one2), (px[n:], py[n:], one2))
     return g2_clear_cofactor_device(s)
 
 

@@ -61,7 +61,8 @@ from .bls381 import (
     _ONE_MONT,
     fq_to_device,
     pt_add,
-    pt_double,
+    pt_scalar_mul,
+    scalars_to_bits,
 )
 from .bls381_tower import (
     f2_add,
@@ -71,7 +72,6 @@ from .bls381_tower import (
     f2_mul,
     f2_mul_xi,
     f2_neg,
-    f2_select,
     f2_sub,
     f2_triple,
     f12_conj,
@@ -259,12 +259,11 @@ _HOST_TAILS = REGISTRY.counter(
 _HOST_TAILS.inc(0)
 
 
-def miller_lanes_device(xp, yp, p_inf, q_x, q_y, q_inf):
-    """The device half of a multi-pairing check: the batch padded with
-    infinity lanes to a multiple of PAIRING_LANES, one Miller loop per
-    lane. Returns (lanes [n, 2, 3, 2, 48], skip [n]) as device arrays;
-    a skipped lane (either point at infinity) holds junk and contributes
-    the identity."""
+def pad_pairing_lanes(xp, yp, p_inf, q_x, q_y, q_inf):
+    """The batch padded with infinity lanes to a multiple of
+    PAIRING_LANES: (xp, yp, q_x, q_y, skip), skip marking the lanes with
+    either point at infinity. Traced inside the verifier's pairing-input
+    program; run eagerly by `miller_lanes_device`."""
     n = xp.shape[0]
     pad = -(-n // PAIRING_LANES) * PAIRING_LANES - n
     if pad:
@@ -273,7 +272,17 @@ def miller_lanes_device(xp, yp, p_inf, q_x, q_y, q_inf):
         )
         xp, yp, q_x, q_y = (grow(a, 0) for a in (xp, yp, q_x, q_y))
         p_inf, q_inf = grow(p_inf, True), grow(q_inf, True)
-    return _miller_jit(xp, yp, q_x, q_y), p_inf | q_inf
+    return xp, yp, q_x, q_y, p_inf | q_inf
+
+
+def miller_lanes_device(xp, yp, p_inf, q_x, q_y, q_inf):
+    """The device half of a multi-pairing check: the batch padded with
+    infinity lanes to a multiple of PAIRING_LANES, one Miller loop per
+    lane. Returns (lanes [n, 2, 3, 2, 48], skip [n]) as device arrays;
+    a skipped lane (either point at infinity) holds junk and contributes
+    the identity."""
+    xp, yp, q_x, q_y, skip = pad_pairing_lanes(xp, yp, p_inf, q_x, q_y, q_inf)
+    return _miller_jit(xp, yp, q_x, q_y), skip
 
 
 def pairing_host_tail(lanes, skip) -> bool:
@@ -320,48 +329,18 @@ def _psi_jac(T):
     )
 
 
-def _ladder_mul_const(T, bits_msb_first: np.ndarray):
-    """[k]T for a fixed scalar via left-to-right double-and-add (branchless
-    scan; bits ride as a runtime argument so ONE compiled scan serves every
-    fixed scalar of the same width — see `_jit_ladder`)."""
-    return _jit_ladder(*T, jnp.asarray(bits_msb_first[1:]))
+#: |x| as the RLC ladders take their scalars: 64 bits, least significant
+#: first (`bls381.batch_g2_scalar_mul`)
+X_BITS = scalars_to_bits([_ATE], 64)[0]
 
 
-@jax.jit
-def _jit_ladder(Tx, Ty, Tz, tail_bits):
-    """Left-to-right ladder: acc starts at T (the leading 1 bit), then one
-    double(+conditional add of T) per remaining bit."""
-    T = (Tx, Ty, Tz)
-    batch = Tx.shape[:-2]
-
-    def body(acc, bit):
-        acc = pt_double(DevFq2, acc)
-        added = pt_add(DevFq2, acc, T)
-        take = jnp.broadcast_to(bit > 0, batch)
-        acc = tuple(f2_select(take, a, b) for a, b in zip(added, acc))
-        return acc, None
-
-    acc, _ = lax.scan(body, T, tail_bits)
-    return acc
-
-
-# small shared point jits (straight-line pieces stay out of mega-graphs —
-# a single fused cofactor/hash graph made XLA-CPU's LLVM stage blow up
-# superlinearly on slow hosts)
-
-_jit_pt_add_g2 = jax.jit(lambda ax, ay, az, bx, by, bz: pt_add(
-    DevFq2, (ax, ay, az), (bx, by, bz)
-))
-_jit_pt_double_g2 = jax.jit(lambda x, y, z: pt_double(DevFq2, (x, y, z)))
-
-
-_ATE_BITS_MSB = np.array([int(b) for b in bin(_ATE)[2:]], dtype=np.int32)
-
-
-@jax.jit
-def _jit_psi_is_neg(q_x, q_y, q_inf, X, Y, Z):
-    """ψ(Q) == −(X:Y:Z), compared in Jacobian coordinates (ψ(Q) is
-    affine): x·Z² == X and y·Z³ == −Y with Z ≠ 0; infinity lanes pass."""
+def psi_is_neg(q_x, q_y, q_inf, X, Y, Z):
+    """The G2 subgroup check's compare, given (X:Y:Z) = [|x|]Q: Q is in G2
+    iff ψ(Q) == [x]Q, and x < 0, so the test is ψ(Q) == −(X:Y:Z) in
+    Jacobian coordinates (ψ(Q) is affine): x·Z² == X and y·Z³ == −Y with
+    Z ≠ 0; infinity lanes pass. A 64-bit ladder instead of the host's
+    255-bit order multiplication: the device verifier runs it as extra
+    lanes of its RLC G2 ladder (bls381_verify._ladders)."""
     px, py = psi(q_x, q_y)
     ZZ, pyZ = f2_mul(_stack(Z, py), _stack(Z, Z))
     lhs_x, lhs_y = f2_mul(_stack(px, pyZ), _stack(ZZ, ZZ))
@@ -373,37 +352,35 @@ def _jit_psi_is_neg(q_x, q_y, q_inf, X, Y, Z):
     return same | q_inf
 
 
-def g2_subgroup_check_device(q_x, q_y, q_inf):
-    """Batched ψ(Q) == [x]Q membership test (64-iteration ladder instead of
-    the host's 255-bit order multiplication; x < 0, so the test is
-    ψ(Q) == −[|x|]Q). Infinity counts as member. The ladder is the
-    compiled kernel cofactor clearing shares."""
-    one2 = _one_fq2(q_x.shape[:-2])
-    xq = _ladder_mul_const((q_x, q_y, one2), _ATE_BITS_MSB)  # [|x|]Q
-    return _jit_psi_is_neg(q_x, q_y, q_inf, *xq)
-
-
 # ---------------------------------------------------------------------------
 # Fast cofactor clearing (Budroni–Pintore form, identity verified in-repo):
 #   [h_eff]Q = [x²−x−1]Q + [x−1]ψ(Q) + ψ²(2Q)
 # ---------------------------------------------------------------------------
 
 
-_jit_neg_y = jax.jit(lambda x, y, z: (x, f2_neg(y), z))
-_jit_psi_jac = jax.jit(lambda x, y, z: _psi_jac((x, y, z)))
+def _neg_y(T):
+    x, y, z = T
+    return (x, f2_neg(y), z)
 
 
+@jax.jit
 def g2_clear_cofactor_device(T):
     """Jacobian twisted point(s) → subgroup point(s); 2 x-ladders + 3 ψ
-    instead of a 636-bit scalar multiplication. Python orchestration over
-    the shared ladder/point jits."""
-    a = _ladder_mul_const(T, _ATE_BITS_MSB)           # [|x|]Q
-    a = _jit_neg_y(*a)                                # [x]Q
-    negT = _jit_neg_y(*T)
-    c1 = _jit_pt_add_g2(*a, *negT)                    # [x−1]Q
-    c2 = _ladder_mul_const(c1, _ATE_BITS_MSB)
-    c2 = _jit_neg_y(*c2)                              # [x²−x]Q
-    c3 = _jit_pt_add_g2(*c2, *negT)                   # [x²−x−1]Q
-    out = _jit_pt_add_g2(*c3, *_jit_psi_jac(*c1))
-    two_q = _jit_pt_double_g2(*T)
-    return _jit_pt_add_g2(*out, *_jit_psi_jac(*_jit_psi_jac(*two_q)))
+    instead of a 636-bit scalar multiplication. Both ladders are the two
+    steps of one scan, Y ← [x]Y − T = −(T + [|x|]Y), each the RLC scalars'
+    ladder over X_BITS started from T: [x−1]T, then [x²−x−1]T; a second
+    scan adds ψ([x−1]T) and ψ²(T) twice (ψ²(2T)). A scan's body is
+    compiled once, so the program holds one ladder and one G2 add past
+    the ladder's own."""
+    bits = jnp.broadcast_to(jnp.asarray(X_BITS), (*T[0].shape[:-2], X_BITS.shape[0]))
+
+    def ladder_step(Y, _):
+        Y = _neg_y(pt_scalar_mul(DevFq2, Y, bits, start=T))
+        return Y, Y
+
+    c3, ys = lax.scan(ladder_step, T, None, length=2)
+    psi2 = _psi_jac(_psi_jac(T))
+    terms = (_psi_jac(tuple(c[0] for c in ys)), psi2, psi2)
+    terms = tuple(jnp.stack(c) for c in zip(*terms))
+    out, _ = lax.scan(lambda acc, q: (pt_add(DevFq2, acc, q), None), c3, terms)
+    return out

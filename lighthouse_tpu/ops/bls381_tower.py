@@ -111,18 +111,23 @@ def f2_triple(a):
     return mod_add(mod_add(a, a), a)
 
 
+@jax.jit
 def fq_pow_digits(a, digits):
     """a^e over [..., 48] limbs, e as `pow_digits(e)` (a runtime array):
     fixed 4-bit-window exponentiation — a table of a^0..a^15, then per
     digit four squarings and one table product, 96 loop steps for a
-    384-bit exponent (no data-dependent control flow)."""
+    384-bit exponent (no data-dependent control flow). Jitted, and the
+    table is a loop too, so a program that holds several pow passes
+    traces this once a shape and holds six products a pass."""
     one = jnp.broadcast_to(jnp.asarray(_ONE_MONT), a.shape).astype(jnp.int32)
-    table = [one, a]
-    while len(table) < 1 << _POW_WINDOW:
-        m = len(table)
-        top = mont_mul(table[m // 2], table[m // 2])  # a^m
-        table += [top, *mont_mul(top, jnp.stack(table[1:m]))]
-    table = jnp.stack(table)
+    table = jnp.zeros((1 << _POW_WINDOW, *a.shape), jnp.int32)
+    table = table.at[0].set(one).at[1].set(a)
+
+    def grow(i, table):  # table[i] = a^i
+        prev = lax.dynamic_index_in_dim(table, i - 1, keepdims=False)
+        return lax.dynamic_update_index_in_dim(table, mont_mul(prev, a), i, 0)
+
+    table = lax.fori_loop(2, 1 << _POW_WINDOW, grow, table)
 
     def body(i, acc):
         for _ in range(_POW_WINDOW):
@@ -137,42 +142,24 @@ def fq_inv(a):
     return fq_pow_digits(a, jnp.asarray(_PM2_DIGITS))
 
 
-#: lanes per shared pow call: the largest caller (four sqrt candidates of
-#: two SSWU maps over 2×32 messages) fills it; smaller ones pad up, so
-#: every exponent and every caller runs ONE compiled loop
-POW_LANES = 512
-
-
-def fq_pow_fixed(a, digits_np: np.ndarray):
-    """a^e over [..., 48] Montgomery limbs, exponent as `pow_digits(e)`.
-    The digits ride as a RUNTIME argument and the lanes pad to a multiple
-    of POW_LANES, so one jitted loop serves every exponent and shape —
-    sqrt chains, Fermat inversions, the lot."""
-    flat = a.reshape(-1, a.shape[-1])
-    n = flat.shape[0]
-    pad = -(-n // POW_LANES) * POW_LANES - n
-    flat = jnp.concatenate([flat, jnp.zeros((pad, flat.shape[1]), flat.dtype)])
-    out = _fq_pow_var(flat, jnp.asarray(digits_np))
-    return out[:n].reshape(a.shape)
-
-
-_fq_pow_var = jax.jit(fq_pow_digits)
-
-
-def fq_inv_staged(a):
-    """1/a over Fq limbs via the shared pow loop."""
-    return fq_pow_fixed(a, _PM2_DIGITS)
-
-
-def f2_inv(a):
-    """1/(a0+a1u) = (a0 − a1u)/(a0²+a1²): one Fq inversion."""
+def f2_norm(a):
+    """a0² + a1², the Fq norm whose inverse inverts a0 + a1u."""
     a0, a1 = a[..., 0, :], a[..., 1, :]
-    norm = mod_add(mont_mul(a0, a0), mont_mul(a1, a1))
-    ninv = fq_inv(norm)
+    return mod_add(mont_mul(a0, a0), mont_mul(a1, a1))
+
+
+def f2_scale_inv(a, ninv):
+    """1/a = (a0 − a1u)·ninv, given ninv = 1/norm(a)."""
+    a0, a1 = a[..., 0, :], a[..., 1, :]
     return jnp.stack(
         [mont_mul(a0, ninv), mod_sub(jnp.zeros_like(a0), mont_mul(a1, ninv))],
         axis=-2,
     )
+
+
+def f2_inv(a):
+    """1/(a0+a1u) = (a0 − a1u)/(a0²+a1²): one Fq inversion."""
+    return f2_scale_inv(a, fq_inv(f2_norm(a)))
 
 
 def f2_select(c, a, b):

@@ -192,7 +192,7 @@ def pytest_sessionstart(session):
         "compile_cache_hits_total",
         "compile_cache_misses_total",
         # the compile counters from JAX's own build events, and the
-        # device verifier's batch, set and padded-lane counters
+        # device verifier's batch, set, padded-lane and program counters
         "jax_programs_built_total",
         'jax_program_build_seconds_total{phase="trace"}',
         'jax_program_build_seconds_total{phase="lower"}',
@@ -200,6 +200,7 @@ def pytest_sessionstart(session):
         "bls_device_batches_total",
         "bls_device_sets_total",
         "bls_device_set_lanes_total",
+        "bls_device_programs_total",
         'gossipsub_mesh_peers{topic="beacon_block"}',
         'gossipsub_mesh_peers{topic="beacon_aggregate_and_proof"}',
         "gossipsub_peer_score_distribution",

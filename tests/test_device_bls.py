@@ -19,7 +19,7 @@ from lighthouse_tpu.crypto.bls12_381 import (
     pt_eq,
     pt_mul,
 )
-from lighthouse_tpu.crypto.bls12_381.fields import P
+from lighthouse_tpu.crypto.bls12_381.fields import P, R
 from lighthouse_tpu.ops import bls381 as D
 
 # every test in this file is tier-2: device kernels: XLA-CPU compiles take minutes cold.
@@ -93,33 +93,43 @@ def test_g2_batch_scalar_mul():
 
 
 @pytest.mark.slow
-def test_device_verify_signature_sets():
+@pytest.mark.parametrize("case", ["single_keys", "key_row_496", "invalid", "full_chunk"])
+def test_device_verify_signature_sets(case):
     import hashlib
 
     from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.crypto.bls import SecretKey
     from lighthouse_tpu.ops.bls381_verify import verify_signature_sets_device_full
     from lighthouse_tpu.utils.tracing import span
 
     bls.set_backend("host")
-    kps = bls.interop_keypairs(8)
     msg = hashlib.sha256(b"device batch").digest()
+    kps = bls.interop_keypairs(32 if case == "full_chunk" else 8)
     sets = [bls.SignatureSet.single(kp.sk.sign(msg), kp.pk, msg) for kp in kps]
+    keys = 16
+    if case == "key_row_496":
+        row = bls.interop_keypairs(496)
+        # Σ skᵢ·H(m) is the aggregate of the 496 signatures
+        sig = SecretKey(sum(kp.sk.scalar for kp in row) % R).sign(msg)
+        sets.append(bls.SignatureSet(sig, [kp.pk for kp in row], msg))
+        keys = 512
+    if case == "invalid":
+        sets[3] = bls.SignatureSet.single(
+            sets[4].signature, sets[3].pubkeys[0], sets[3].message
+        )
+    want = bls.verify_signature_sets(sets)
+    assert want == (case != "invalid")
     with span("device_bls_test_root") as root:
-        assert verify_signature_sets_device_full(sets, random.Random(5))
+        assert verify_signature_sets_device_full(sets, random.Random(5)) == want
     # one verify span with every stage once, in order, the two waits and
-    # the pairing's host tail
+    # the pairing's host tail; six device programs
     (call,) = root.children
     assert call.name == "bls_device_verify"
-    assert call.fields == {"sets": 8, "lanes": 32, "keys": 16}
+    assert call.fields == {"sets": len(sets), "lanes": 32, "keys": keys, "programs": 6}
     assert [c.name for c in call.children] == [
-        "bls_device_decode", "bls_device_subgroup", "bls_device_aggregate",
-        "bls_device_ladders", "bls_device_message_groups",
-        "bls_device_hash_to_g2", "bls_device_pairing",
+        "bls_device_decode", "bls_device_hash_to_g2", "bls_device_aggregate",
+        "bls_device_ladders", "bls_device_message_groups", "bls_device_subgroup",
+        "bls_device_pairing",
     ]
     inner = [w for c in call.children for w in c.children]
     assert [w.name for w in inner] == ["bls_device_wait"] * 2 + ["bls_pairing_host_tail"]
-    bad = list(sets)
-    bad[3] = bls.SignatureSet.single(
-        sets[4].signature, sets[3].pubkeys[0], sets[3].message
-    )
-    assert not verify_signature_sets_device_full(bad, random.Random(6))

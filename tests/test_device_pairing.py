@@ -10,6 +10,7 @@ cache amortizes them across runs."""
 import hashlib
 import random
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -30,8 +31,10 @@ from lighthouse_tpu.crypto.bls12_381.curve import g2_clear_cofactor, pt_neg
 from lighthouse_tpu.crypto.bls12_381.fields import P, f2, f2_add, f2_mul, f2_sqrt
 from lighthouse_tpu.ops import bls381_htc as H
 from lighthouse_tpu.ops import bls381_pairing as DP
+from lighthouse_tpu.crypto import bls
 from lighthouse_tpu.ops import bls381_tower as TW
-from lighthouse_tpu.ops.bls381 import g2_points_from_device
+from lighthouse_tpu.ops import bls381_verify as V
+from lighthouse_tpu.ops.bls381 import NLIMB, g2_points_from_device
 from lighthouse_tpu.ops.bls381_tower import fq2_const
 
 # every test in this file is tier-2: pairing kernels: the slowest compiles in the tree.
@@ -86,7 +89,15 @@ def test_f2_sqrt_device():
         x += 1
     sq_in.append((x, 3))  # non-square
     dev = jnp.asarray(np.stack([fq2_const(v) for v in sq_in]))
-    roots, is_sq = H.f2_sqrt_device(dev)
+
+    # the square root as map_to_e2 takes it: the candidate roots, then
+    # the root from 1/(2t)
+    @jax.jit
+    def sqrt(a):
+        roots, t, two_t = H._sqrt_roots(a)
+        return H._sqrt_finish(a, roots, t, TW.fq_inv(two_t))
+
+    roots, is_sq = sqrt(dev)
     assert np.asarray(is_sq).tolist() == [True] * 7 + [False]
     got_sq = np.asarray(TW.f2_sqr(roots))
     assert (got_sq[:7] == np.asarray(dev)[:7]).all()
@@ -97,9 +108,14 @@ def test_g2_subgroup_check_device():
     good = [pt_mul(FQ2, G2_GEN, k) for k in (1, 5, 123456789)]
     bad = _non_subgroup_g2()
     pts = [to_affine(FQ2, p) for p in good] + [bad]
-    qx, qy, q_inf = DP.g2_affine_to_device(pts)
-    res = np.asarray(DP.g2_subgroup_check_device(qx, qy, q_inf))
-    assert res.tolist() == [True, True, True, False]
+    sigs = DP.g2_affine_to_device(pts)
+    # the verifier's ladders program with zero RLC scalars: the subgroup
+    # verdicts come from its extra [|x|] lanes
+    n = len(pts)
+    agg = tuple(jnp.zeros((n, NLIMB), jnp.int32) for _ in range(3))
+    bits = np.zeros((n, bls.RAND_BITS), np.int32)
+    _, _, in_sub = V._ladders(agg, sigs, bits)
+    assert np.asarray(in_sub).tolist() == [True, True, True, False]
 
 
 @pytest.mark.slow
@@ -142,29 +158,65 @@ def test_multi_pairing_check_device():
     )
 
 
+def _batch_case(case):
+    """Signature sets for the device verifier's oracle cases: a mixed chunk
+    (singles and a 3-key aggregate), a 496-key row (the import's committee
+    bucket, 512), an invalid set, a signature on the twist outside G2, and
+    a full 32-set chunk, whose message gather pads with the appended
+    infinity lane."""
+    from lighthouse_tpu.crypto.bls import AggregateSignature, SecretKey
+    from lighthouse_tpu.crypto.bls12_381.fields import R
+
+    def singles(count):
+        out = []
+        for i, kp in enumerate(bls.interop_keypairs(count)):
+            m = hashlib.sha256(bytes([i % 5])).digest()
+            out.append(bls.SignatureSet.single(kp.sk.sign(m), kp.pk, m))
+        return out
+
+    msg = hashlib.sha256(b"full device").digest()
+    if case == "key_row_496":
+        kps = bls.interop_keypairs(496)
+        # Σ skᵢ·H(m) is the aggregate of the 496 signatures
+        sig = SecretKey(sum(kp.sk.scalar for kp in kps) % R).sign(msg)
+        return singles(2) + [bls.SignatureSet(sig, [kp.pk for kp in kps], msg)]
+    if case == "full_chunk":
+        return singles(32)
+    kps = bls.interop_keypairs(8)
+    sets = singles(8)
+    aggsig = AggregateSignature.from_signatures(
+        [kp.sk.sign(msg) for kp in kps[:3]]
+    ).to_signature()
+    sets.append(bls.SignatureSet(aggsig, [kp.pk for kp in kps[:3]], msg))
+    if case == "invalid":
+        sets[2] = bls.SignatureSet.single(
+            sets[3].signature, sets[2].pubkeys[0], sets[2].message
+        )
+    if case == "not_in_g2":
+        x, y = _non_subgroup_g2()
+        sig = bls.Signature.from_point((x, y, f2(1)))
+        sets[2] = bls.SignatureSet.single(sig, sets[2].pubkeys[0], sets[2].message)
+    return sets
+
+
 @pytest.mark.slow
-def test_full_device_batch_verify():
-    from lighthouse_tpu.crypto import bls
-    from lighthouse_tpu.crypto.bls import AggregateSignature
+@pytest.mark.parametrize(
+    "case", ["mixed", "key_row_496", "invalid", "not_in_g2", "full_chunk"]
+)
+def test_full_device_batch_verify(case):
     from lighthouse_tpu.ops.bls381_verify import verify_signature_sets_device_full
+    from lighthouse_tpu.utils.tracing import span
 
     bls.set_backend("host")
     try:
-        kps = bls.interop_keypairs(8)
-        msg = hashlib.sha256(b"full device").digest()
-        sets = []
-        for i, kp in enumerate(kps):
-            m = hashlib.sha256(bytes([i])).digest()
-            sets.append(bls.SignatureSet.single(kp.sk.sign(m), kp.pk, m))
-        aggsig = AggregateSignature.from_signatures(
-            [kp.sk.sign(msg) for kp in kps[:3]]
-        ).to_signature()
-        sets.append(bls.SignatureSet(aggsig, [kp.pk for kp in kps[:3]], msg))
-        assert verify_signature_sets_device_full(sets, random.Random(5))
-        bad = list(sets)
-        bad[2] = bls.SignatureSet.single(
-            sets[3].signature, sets[2].pubkeys[0], sets[2].message
-        )
-        assert not verify_signature_sets_device_full(bad, random.Random(6))
+        sets = _batch_case(case)
+        want = bls.verify_signature_sets(sets)
+        assert want == (case not in ("invalid", "not_in_g2"))
+        with span("device_batch_root") as root:
+            assert verify_signature_sets_device_full(sets, random.Random(5)) == want
+        # the subgroup verdict, not the pairing, rejects a signature outside
+        # G2: the chunk ends before the pairing's two programs
+        (call,) = root.children
+        assert call.fields["programs"] == (4 if case == "not_in_g2" else 6)
     finally:
         bls.set_backend("fake_crypto")

@@ -101,7 +101,7 @@ def test_ints_to_limbs_equals_one_int_at_a_time():
 
 def test_pubkey_grid_holds_each_point_and_infinity_pads():
     from lighthouse_tpu.ops.bls381 import P, R_MONT, int_to_limbs
-    from lighthouse_tpu.ops.bls381_verify import _g1_affine_grid_to_device
+    from lighthouse_tpu.ops.bls381_verify import _g1_affine_grid
 
     rng = random.Random(11)
     grid = [
@@ -109,7 +109,7 @@ def test_pubkey_grid_holds_each_point_and_infinity_pads():
         for _ in range(4)
     ]
     grid.append([None] * 16)  # a padding set
-    xs, ys, zs = (np.asarray(a) for a in _g1_affine_grid_to_device(grid))
+    xs, ys, zs = _g1_affine_grid(grid)
     assert xs.shape == ys.shape == zs.shape == (5, 16, 48)
     one = int_to_limbs(R_MONT % P)
     for i, row in enumerate(grid):

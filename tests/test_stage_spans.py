@@ -6,10 +6,12 @@ in the recorded `.xplane.pb`, nested in its parent; with no trace running
 no TraceMe is made. The device verifier records one `bls_device_verify`
 span a chunk with a child span for each stage and a `bls_device_wait`
 span for each of its two blocking reads (checked here with its device
-kernels replaced by stand-ins; `test_device_bls.py` checks the real
-kernels), and its pairing stage ends in one `bls_pairing_host_tail` span,
-outside the waits; `process_attestation_batch` records its prepare,
-fallback and apply stages."""
+programs replaced by stand-ins; `test_device_bls.py` checks the real
+ones), its pairing stage ends in one `bls_pairing_host_tail` span,
+outside the waits, and a chunk launches a fixed handful of device
+programs with no eager device op between them;
+`process_attestation_batch` records its prepare, fallback and apply
+stages."""
 
 import glob
 import random
@@ -28,11 +30,11 @@ from lighthouse_tpu.utils.tracing import span
 
 DEVICE_STAGES = [
     "bls_device_decode",
-    "bls_device_subgroup",
+    "bls_device_hash_to_g2",
     "bls_device_aggregate",
     "bls_device_ladders",
     "bls_device_message_groups",
-    "bls_device_hash_to_g2",
+    "bls_device_subgroup",
     "bls_device_pairing",
 ]
 
@@ -104,28 +106,80 @@ def host_backend():
     bls.set_backend(before)
 
 
-def _stand_in_kernels(monkeypatch):
-    """Shape-preserving stand-ins for the verifier's device programs: the
-    host staging, the stage spans, the counters and the pairing's host
-    tail (over Miller lanes that all hold one) run for real."""
-    no_inf = lambda x: jnp.zeros(x.shape[0], bool)  # noqa: E731
+#: the device programs of one valid chunk, in launch order
+CHUNK_PROGRAMS = [
+    "hash_to_g2_device",
+    "_aggregate",
+    "_ladders",
+    "_message_groups",
+    "_pairing_inputs",
+    "_miller_jit",
+]
+
+
+def _program(name, fn):
+    fn.__name__ = name
+    return jax.jit(fn)
+
+
+def _stand_in_kernels(monkeypatch, in_subgroup=True):
+    """Shape-preserving stand-ins for the verifier's device programs, each
+    a jitted program of the same name: the host staging, the stage spans,
+    the counters and the pairing's host tail (over Miller lanes that all
+    hold one) run for real. The ladders' stand-in reports every signature
+    in the subgroup, or none."""
+    lanes = lambda: jnp.zeros((PAIRING_LANES, 48), jnp.int32)  # noqa: E731
+    lanes2 = lambda: jnp.zeros((PAIRING_LANES, 2, 48), jnp.int32)  # noqa: E731
     for name, fn in {
-        "g2_subgroup_check_device": lambda x, y, inf: jnp.ones(x.shape[0], bool),
-        "g1_segment_sum": lambda xs, ys, zs: (xs[:, 0], ys[:, 0], zs[:, 0]),
-        "batch_g1_scalar_mul": lambda x, y, z, bits: (x, y, z),
-        "batch_g2_scalar_mul": lambda x, y, z, bits: (x, y, z),
-        "g2_sum_reduce": lambda x, y, z: (x[:1], y[:1], z[:1]),
-        "hash_to_g2_device": lambda u: (u[:, 0], u[:, 1], u[:, 0]),
-        "fq_inv_staged": lambda a: a,
-        "_jit_f2_norm": lambda z: z[:, 0],
-        "_jit_f2_scale_inv": lambda z, inv: z,
-        "_jit_g1_affine_from_inv": lambda x, y, z, inv: (x, y, no_inf(x)),
-        "_jit_g2_affine_from_inv": lambda x, y, z, inv: (x, y, no_inf(x)),
-        "miller_lanes_device": lambda *a: (
-            f12_ones((PAIRING_LANES,)), jnp.zeros(PAIRING_LANES, bool)
+        "_aggregate": lambda grid, slices: tuple(c[:, 0] for c in grid),
+        "_ladders": lambda agg, sigs, bits: (
+            agg,
+            (sigs[0][:1], sigs[1][:1], sigs[0][:1]),
+            jnp.full(sigs[2].shape, in_subgroup),
         ),
+        "_message_groups": lambda s_pk, idx, slices: s_pk,
+        "hash_to_g2_device": lambda u: (u[:, 0], u[:, 1], u[:, 0]),
+        "_pairing_inputs": lambda *a: (
+            lanes(), lanes(), lanes2(), lanes2(), jnp.zeros(PAIRING_LANES, bool)
+        ),
+        "_miller_jit": lambda xp, yp, qx, qy: f12_ones(xp.shape[:1]),
     }.items():
-        monkeypatch.setattr(ops_full, name, fn)
+        monkeypatch.setattr(ops_full, name, _program(name, fn))
+
+
+def _device_executions(monkeypatch):
+    """Every device program JAX runs from here on, by name, eager ops
+    included: the C++ dispatch path is cleared and kept off, so each
+    execution passes through `ExecuteReplicated`."""
+    from jax._src import pjit
+    from jax._src.interpreters import pxla
+
+    ran = []
+    execute = pxla.ExecuteReplicated.__call__
+
+    def counting(self, *args):
+        ran.append(self.name)
+        return execute(self, *args)
+
+    monkeypatch.setattr(pxla.ExecuteReplicated, "__call__", counting)
+    monkeypatch.setattr(pjit, "_get_fastpath_data", lambda *a, **k: None)
+    jax.clear_caches()
+    return ran
+
+
+def _eager_primitives(monkeypatch):
+    """Primitives applied one by one outside any program."""
+    from jax._src import dispatch
+
+    applied = []
+    apply = dispatch.apply_primitive
+
+    def counting(prim, *args, **params):
+        applied.append(prim.name)
+        return apply(prim, *args, **params)
+
+    monkeypatch.setattr(dispatch, "apply_primitive", counting)
+    return applied
 
 
 def _counts():
@@ -133,7 +187,7 @@ def _counts():
         k: REGISTRY.counter(k).value()
         for k in (
             "bls_device_batches_total", "bls_device_sets_total", "bls_device_set_lanes_total",
-            "bls_pairing_host_tails_total",
+            "bls_pairing_host_tails_total", "bls_device_programs_total",
         )
     }
 
@@ -152,10 +206,11 @@ def test_device_verifier_records_each_stage_once(monkeypatch):
         "bls_device_sets_total": 3,
         "bls_device_set_lanes_total": 32,
         "bls_pairing_host_tails_total": 1,
+        "bls_device_programs_total": len(CHUNK_PROGRAMS),
     }
     (call,) = root.children
     assert call.name == "bls_device_verify"
-    assert call.fields == {"sets": 3, "lanes": 32, "keys": 16}
+    assert call.fields == {"sets": 3, "lanes": 32, "keys": 16, "programs": len(CHUNK_PROGRAMS)}
     assert [c.name for c in call.children] == DEVICE_STAGES
     stages = {c.name: c for c in call.children}
     waits = [s for s in _walk(call) if s.name == "bls_device_wait"]
@@ -189,6 +244,49 @@ def test_device_verifier_chunks_get_a_span_each(monkeypatch):
     # one host tail a chunk: the tail counter moves with the batch counter
     moved = {k: after[k] - before[k] for k in after}
     assert moved["bls_pairing_host_tails_total"] == moved["bls_device_batches_total"] == 2
+
+
+@pytest.mark.parametrize("keys", [1, 496], ids=["att", "import"])
+def test_device_verifier_chunk_launches_its_programs_only(monkeypatch, keys):
+    """One chunk is a fixed handful of device programs and nothing else:
+    no eager op runs on the device between them, whatever the committee
+    size (an att-shaped chunk of single keys, an import-shaped one of
+    496-key rows, bucketed to 512)."""
+    _stand_in_kernels(monkeypatch)
+    kps = bls.interop_keypairs(3)
+    msgs = [b"\x04" * 32, b"\x04" * 32, b"\x05" * 32]
+    sets = [
+        bls.SignatureSet(kp.sk.sign(m), [kp.pk] * keys, m) for kp, m in zip(kps, msgs)
+    ]
+    before = _counts()["bls_device_programs_total"]
+    ran = _device_executions(monkeypatch)
+    applied = _eager_primitives(monkeypatch)
+    with span("obs_device_root") as root:
+        assert ops_full.verify_signature_sets_device_full(sets, random.Random(3))
+    assert applied == []
+    assert ran == [f"jit({name})" for name in CHUNK_PROGRAMS]
+    (call,) = root.children
+    assert call.fields == {
+        "sets": 3, "lanes": 32, "keys": 16 if keys == 1 else 512, "programs": len(ran)
+    }
+    assert _counts()["bls_device_programs_total"] - before == len(ran)
+
+
+def test_device_verifier_counts_the_programs_of_a_rejected_chunk(monkeypatch):
+    """A chunk that fails the subgroup check stops at the verdict's read,
+    before the pairing's two programs, and the span and the counter say
+    so."""
+    _stand_in_kernels(monkeypatch, in_subgroup=False)
+    kp = bls.interop_keypairs(1)[0]
+    msg = b"\x06" * 32
+    before = _counts()["bls_device_programs_total"]
+    with span("obs_device_root") as root:
+        assert not ops_full.verify_signature_sets_device_full(
+            [bls.SignatureSet.single(kp.sk.sign(msg), kp.pk, msg)], random.Random(4)
+        )
+    (call,) = root.children
+    assert call.fields["programs"] == len(CHUNK_PROGRAMS) - 2
+    assert _counts()["bls_device_programs_total"] - before == len(CHUNK_PROGRAMS) - 2
 
 
 # -- the gossip batch's stages -----------------------------------------------

@@ -74,12 +74,38 @@ def test_pt_double(one_chip, field):
     _compile(lambda x, y, z: pt_double(F, (x, y, z)), c, c, c)
 
 
-def test_committee_aggregation_chunk(one_chip):
-    from lighthouse_tpu.ops.bls381_verify import AGG_LANES, _jit_agg_step
+@pytest.mark.parametrize("keys", [16, 512])
+def test_committee_aggregation_chunk(one_chip, keys):
+    """The device verifier's aggregation program at both committee
+    buckets the node sends: single keys (16) and ~486-key aggregates and
+    the sync aggregate (512)."""
+    from lighthouse_tpu.ops.bls381_verify import _aggregate
 
-    acc = _spec(one_chip, (LANES, 48))
-    pts = _spec(one_chip, (LANES, AGG_LANES, 48))
-    _jit_agg_step.lower(acc, acc, acc, pts, pts, pts).compile()
+    pts = _spec(one_chip, (LANES, keys, 48))
+    _aggregate.lower((pts, pts, pts), _spec(one_chip, ())).compile()
+
+
+def test_message_groups_chunk(one_chip):
+    from lighthouse_tpu.ops.bls381_verify import _message_groups
+
+    pts = _spec(one_chip, (LANES, 48))
+    idx = _spec(one_chip, (LANES, LANES))
+    _message_groups.lower((pts, pts, pts), idx, _spec(one_chip, ())).compile()
+
+
+def test_pairing_inputs_chunk(one_chip):
+    """The verifier's shared inversion, affine conversions and Miller-lane
+    padding. The chunk's ladders, hash to G2 and Miller loop are left out:
+    each takes from 49 s to 2.5 minutes to compile on a CPU host."""
+    from lighthouse_tpu.ops.bls381_verify import _pairing_inputs
+
+    g1 = _spec(one_chip, (LANES, 48))
+    sig = _spec(one_chip, (1, 2, 48))
+    hm = _spec(one_chip, (LANES, 2, 48))
+    compiled = _pairing_inputs.lower(
+        (g1, g1, g1), (sig, sig, sig), (hm, hm, hm), _spec(one_chip, ())
+    ).compile()
+    assert compiled.out_info[0].shape == (LANES + 1, 48)
 
 
 def test_fr_mul_blob_width(one_chip):
