@@ -272,12 +272,51 @@ def phase_node() -> None:
 # ---------------------------------------------------------------------------
 
 
+def _make_sets(bls, n_sets: int, committee: int, cache: str):
+    """n_sets aggregate-signature sets over one `committee`-key committee.
+
+    The aggregate of per-key signatures on one message equals a single
+    signature under the summed secret key (Σ skᵢ·H(m) = (Σ skᵢ)·H(m)), so
+    generation costs one host sign per set instead of `committee`. The
+    signatures are read from the pickle at `cache` when it holds `n_sets`
+    of them, and written there otherwise.
+    """
+    import pickle
+
+    from lighthouse_tpu.crypto.bls import R
+
+    kps = bls.interop_keypairs(committee)
+    pks = [kp.pk for kp in kps]  # shared objects: 64 decompressions, not 64k
+    msgs = [
+        hashlib.sha256(b"att" + i.to_bytes(4, "little")).digest()
+        for i in range(n_sets)
+    ]
+    if os.path.exists(cache):
+        with open(cache, "rb") as f:
+            sig_bytes = pickle.load(f)
+        if len(sig_bytes) == n_sets:
+            return [
+                bls.SignatureSet(bls.Signature(sb), pks, m)
+                for sb, m in zip(sig_bytes, msgs)
+            ]
+    sk_agg = bls.SecretKey(sum(kp.sk.scalar for kp in kps) % R)
+    sets = [bls.SignatureSet(sk_agg.sign(m), pks, m) for m in msgs]
+    os.makedirs(os.path.dirname(cache), exist_ok=True)
+    with open(cache, "wb") as f:
+        pickle.dump([s.signature.to_bytes() for s in sets], f)
+    return sets
+
+
 def phase_bls() -> None:
-    import bench
     from lighthouse_tpu.crypto import bls
 
-    # the bls_batch_verify_1k sets: bench.py's generator and committed cache
-    sets = bench._make_sets(bls, BLS_SETS, BLS_COMMITTEE)
+    # the bls_batch_verify_1k sets, from their committed cache
+    sets = _make_sets(
+        bls,
+        BLS_SETS,
+        BLS_COMMITTEE,
+        os.path.join(_REPO, ".bench_cache", f"sets_v1_{BLS_SETS}x{BLS_COMMITTEE}.pkl"),
+    )
     bad = list(sets)
     bad[BLS_SETS // 2] = bls.SignatureSet(
         sets[BLS_SETS // 2 + 1].signature,
@@ -326,8 +365,8 @@ def phase_kzg() -> None:
         proofs[0] == host.compute_blob_kzg_proof(blobs[0], cs[0]),
         "device proof != host",
     )
-    # the same blobs' host-engine commitments and proofs, as bench.py
-    # generated and committed them
+    # the same blobs' host-engine commitments and proofs, as generated and
+    # committed with the repo
     import pickle
 
     with open(os.path.join(_REPO, ".bench_cache", f"kzg_v1_{KZG_BLOBS}x{setup.n}.pkl"), "rb") as f:
@@ -357,6 +396,55 @@ def _hashlib_root(leaves: bytes) -> bytes:
             for i in range(0, len(nodes), 2)
         ]
     return nodes[0]
+
+
+def _build_epoch_state(n: int, resident: bool):
+    """A boundary-ready Altair state of `n` cloned validators with
+    randomized participation/scores and steady-state balances (inside
+    the hysteresis band: real epochs move balances by rewards, not by
+    mass effective-balance churn). `resident` converts to the node's
+    tree-states representation and builds and refreshes its columns."""
+    from dataclasses import replace
+
+    from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.state_processing import interop_genesis_state
+    from lighthouse_tpu.types.chain_spec import minimal_spec
+    from lighthouse_tpu.types.eth_spec import MinimalEthSpec as E
+
+    bls.set_backend("fake_crypto")
+    spec = replace(minimal_spec(), altair_fork_epoch=0)
+    base = interop_genesis_state(
+        bls.interop_keypairs(8), 1_600_000_000, b"\x42" * 32, spec, E
+    )
+    # clone validator 0 out to n (deposit-path construction of n keys is
+    # minutes of BLS; registry shape is what the sweep cares about)
+    rng = random.Random(3)
+    v0 = base.validators[0]
+    vs, bal, prev, cur, scores = [], [], bytearray(n), bytearray(n), []
+    for i in range(n):
+        v = v0.copy()
+        v.withdrawal_credentials = i.to_bytes(32, "little")
+        vs.append(v)
+        # inside the hysteresis band around the 32-ETH effective balance
+        bal.append(32_000_000_000 + rng.randrange(1_000_000_000))
+        prev[i] = rng.randrange(8)
+        cur[i] = rng.randrange(8)
+        scores.append(rng.randrange(4))
+    base.validators = vs
+    base.balances = bal
+    base.previous_epoch_participation = prev
+    base.current_epoch_participation = cur
+    base.inactivity_scores = scores
+    base.slot = 3 * E.SLOTS_PER_EPOCH - 1
+    if resident:
+        from lighthouse_tpu.beacon_chain.chain import _make_persistent
+        from lighthouse_tpu.state_processing.registry_columns import (
+            registry_columns_for,
+        )
+
+        _make_persistent(base)
+        registry_columns_for(base).refresh(base)
+    return base, spec, E
 
 
 def phase_tree_hash(state) -> None:
@@ -513,9 +601,7 @@ def main(argv=None) -> int:
         phase_node()
         phase_bls()
         phase_kzg()
-        import bench
-
-        state, spec, E = bench._build_epoch_state(REGISTRY_SIZE, resident=True)
+        state, spec, E = _build_epoch_state(REGISTRY_SIZE, resident=True)
         phase_tree_hash(state)
         phase_epoch_sweep(state, spec, E)
     log(f"[total] {time.perf_counter() - t_start:.1f} s")

@@ -34,8 +34,8 @@ is free.
 
 Every violation increments ``sanitizer_violations_total{rule=...}``
 (eagerly registered; tests/conftest.py asserts the series) and raises
-`SanitizerError`. Sanitize mode is excluded from timed bench trials
-(bench.py refuses to record with the flag set; see BENCH_NOTES.md).
+`SanitizerError`. Sanitize mode adds a check to every guarded write, so
+no timing is taken with it on.
 """
 
 from __future__ import annotations

@@ -15,7 +15,7 @@ its max-cover runs as a flat array program (one gains vector, np.argmax
 per round, per-bucket coverage rows; a pick only dents its own bucket's
 gains, so nothing else recomputes). The pre-columnar pack walk is
 retained verbatim as `get_attestations_for_block_reference` — the
-differential oracle and the `op_pool_pack_ms` bench control.
+differential oracle.
 """
 
 from __future__ import annotations
@@ -257,8 +257,7 @@ class OperationPool:
         """The pre-columnar pack walk, retained verbatim: re-hashes every
         candidate's data root and re-decodes its bits, then recomputes the
         FULL gains list every round (the per-pool rescan the flat pack
-        replaced). Differential oracle + `op_pool_pack_ms` bench control —
-        do not optimize."""
+        replaced). Differential oracle — do not optimize."""
         E = self.E
         current = get_current_epoch(state, E)
         previous = get_previous_epoch(state, E)

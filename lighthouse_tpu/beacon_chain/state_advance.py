@@ -51,8 +51,8 @@ REGISTRY.counter(
 ).inc(0)
 
 # The block_production trace-root + child-stage histograms must exist at
-# zero: the block-production bench reads the stage breakdown eagerly and
-# the conftest guard asserts the series (same pattern as the fork-choice
+# zero: dashboards read the stage breakdown eagerly and the conftest
+# guard asserts the series (same pattern as the fork-choice
 # get_head stages).
 for _span_name in (
     "trace_span_seconds_block_production",

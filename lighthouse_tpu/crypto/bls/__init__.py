@@ -63,11 +63,9 @@ SECRET_KEY_BYTES_LEN = 32
 # Bits of randomness per batch-verify scalar (impls/blst.rs:14 RAND_BITS).
 RAND_BITS = 64
 
-# The ONE device-lane chunk default: both the node's `tpu` backend
-# (LIGHTHOUSE_TPU_BLS_CHUNK) and bench.py's BENCH_BLS_CHUNK read it, and
-# the device verifier pads every smaller batch up to it, so one set of
-# compiled kernels serves every batch; see BENCH_NOTES.md "Full-size BLS
-# shapes".
+# The ONE device-lane chunk: the node's `tpu` backend splits batches into
+# chunks of this many sets, and the device verifier pads every smaller
+# batch up to it, so one set of compiled kernels serves every batch.
 DEFAULT_DEVICE_CHUNK = 32
 
 INFINITY_PUBLIC_KEY = bytes([0xC0]) + bytes(47)
@@ -711,7 +709,7 @@ class _HostBackend:
         pool degrades); the final exponentiation runs once in the parent.
         Any worker exception is a verification failure, never a hang. The
         retained per-set loop lives on as `verify_signature_sets_serial`
-        (differential oracle + bench control)."""
+        (differential oracle)."""
         from ...parallel import host_pool  # lazy: no pool for single verifies
 
         sets = list(sets)
@@ -842,8 +840,7 @@ class _HostBackend:
         """The pre-MSM serial per-set loop (impls/blst.rs:35-117 shape):
         e(-g1, Σ rᵢ·sigᵢ) · ∏_m e(Σ_{i: mᵢ=m} rᵢ·aggpkᵢ, H(m)) == 1 with
         2N wNAF scalar muls and one Miller loop per distinct message. Kept
-        verbatim as the differential oracle for the MSM path and as the
-        bench's same-run `vs_baseline` control."""
+        verbatim as the differential oracle for the MSM path."""
         sets = list(sets)
         if not sets:
             return False
@@ -929,25 +926,20 @@ class _TpuBackend(_HostBackend):
     subgroup checks, committee aggregation, RLC ladders, SSWU hash-to-G2
     and the Miller loops on device; the host multiplies the Miller lanes
     and runs the one final exponentiation. Batches are processed in
-    bounded-shape chunks (LIGHTHOUSE_TPU_BLS_CHUNK, default
-    DEFAULT_DEVICE_CHUNK = 32 — the same value bench.py's BENCH_BLS_CHUNK
-    defaults to) so every chunk reuses one set of compiled kernels. A
+    bounded-shape chunks of DEFAULT_DEVICE_CHUNK sets, read at call time,
+    so every chunk reuses one set of compiled kernels. A
     device failure raises: choosing the host is a configuration, never a
     reaction to a failure."""
 
     name = "tpu"
 
     def verify_signature_sets(self, sets, rng=None) -> bool:
-        import os as _os
-
         sets = list(sets)
         if not sets:
             return super().verify_signature_sets(sets, rng)
         from ...ops.bls381_verify import verify_signature_sets_device_full
 
-        chunk = int(
-            _os.environ.get("LIGHTHOUSE_TPU_BLS_CHUNK", str(DEFAULT_DEVICE_CHUNK))
-        ) or len(sets)
+        chunk = DEFAULT_DEVICE_CHUNK
         for i in range(0, len(sets), chunk):
             if not verify_signature_sets_device_full(sets[i:i + chunk], rng):
                 return False

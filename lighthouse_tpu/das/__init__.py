@@ -42,8 +42,8 @@ REGISTRY.counter(
     "full extended-matrix reconstructions from >=50% columns",
 ).inc(0.0)
 # the batched verifier's stage spans (proofs.verify_cell_kzg_proof_batch)
-# — registered at import so the series exist at zero for the da_verify
-# bench's before/after deltas and the OBSERVABILITY.md dashboards
+# — registered at import so the series exist at zero for before/after
+# deltas and the OBSERVABILITY.md dashboards
 for _stage in ("da_verify", "da_derive", "da_msm", "da_pairing"):
     REGISTRY.histogram(
         # lint: allow(metric-hygiene) -- bounded by the stage tuple above

@@ -19,8 +19,8 @@ per-item challenge powers r_i, the n pairing equations
 sum into ONE equation whose two sides are Pippenger MSMs over the proof
 points (crypto/bls12_381/msm) sharded across the host fork pool, plus a
 single pairing check. The per-cell scalar path (`verify_cell_kzg_proof`)
-stays as the differential oracle — bench `da_verify` runs both and
-asserts verdict parity.
+stays as the differential oracle — the tests run both and assert
+verdict parity.
 
 Proof COMPUTATION has a dev-tau fast path: `TrustedSetup.insecure_dev`
 derives tau deterministically, and when the setup's [tau]G2 matches that
@@ -207,7 +207,7 @@ def verify_cell_kzg_proof(
     commitment: bytes, cell_index: int, cell_bytes: bytes, proof: bytes, kzg
 ) -> bool:
     """Per-cell scalar oracle: one full pairing check per cell. The
-    differential control for the batched lane (bench `da_verify`)."""
+    differential oracle for the batched lane."""
     cell_to_fr(cell_bytes)  # reject non-canonical elements up front
     z, y = _cell_opening(commitment, cell_index, cell_bytes, 2 * kzg.setup.n)
     ok = kzg.verify_kzg_proof(

@@ -2,7 +2,7 @@
 
 `proto_array` is the columnar (array-program) implementation;
 `proto_array_reference` retains the scalar walk as the differential
-oracle and bench control.
+oracle.
 """
 
 from .fork_choice import (

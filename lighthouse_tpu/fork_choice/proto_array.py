@@ -34,9 +34,8 @@ columnar attesting-index arrays: a drained GOSSIP_ATTESTATION batch
 updates votes in one vectorized write instead of ~16k dict operations.
 
 The pre-columnar scalar walk is retained verbatim in
-`proto_array_reference.py` (differential oracle + bench control, per the
-established reference-module pattern); `fork_choice_get_head_ms` in
-bench.py measures this module against it at 1M applied votes.
+`proto_array_reference.py` (the differential oracle, per the
+established reference-module pattern).
 """
 
 from __future__ import annotations
@@ -88,8 +87,8 @@ for _path in ("batch", "single"):
     _VOTES_APPLIED.inc(0, path=_path)
 
 # the get_head trace-root + child-stage histograms must exist at zero:
-# the fork_choice bench reads the stage breakdown eagerly and the
-# conftest guard asserts the series (same pattern as the epoch stages)
+# dashboards read the stage breakdown eagerly and the conftest guard
+# asserts the series (same pattern as the epoch stages)
 for _span_name in (
     "trace_span_seconds_fork_choice_get_head",
     "trace_span_seconds_delta_compute",

@@ -1,17 +1,13 @@
 # lint: allow-file(safe-arith) -- retained scalar oracle, kept verbatim as
-# the differential baseline and bench control for the columnar rewrite
+# the differential baseline for the columnar rewrite
 """Scalar proto-array fork choice — the retained differential oracle.
 
 This is the pre-columnar implementation of `proto_array.py`, kept
 verbatim (per the established reference-module pattern:
 `pairing_reference`, `epoch_reference`, `process_attestations_reference`)
-as:
-
-  * the differential oracle the columnar rewrite is fuzzed against
-    (tests/test_fork_choice_columnar.py — bit-identical head roots,
-    weights, and prune survivors across randomized vote churn), and
-  * the bench control `fork_choice_get_head_ms` reports `vs_baseline`
-    against (scalar oracle on a validator subsample, same run).
+as the differential oracle the columnar rewrite is fuzzed against
+(tests/test_fork_choice_columnar.py — bit-identical head roots, weights,
+and prune survivors across randomized vote churn).
 
 It walks Python `ProtoNode` objects and a per-validator
 `dict[int, VoteTracker]` on every `get_head` — exactly the scalar cost

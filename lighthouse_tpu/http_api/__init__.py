@@ -337,8 +337,7 @@ class BeaconApi:
     # resident RegistryColumns through the per-route response cache (the
     # HTTP layer sends them verbatim). The dict-returning methods below
     # them are the RETAINED PER-OBJECT ORACLES: same shapes, same fixed
-    # statuses, used by the differential suite and the bench control —
-    # never on the hot path.
+    # statuses, used by the differential suite — never on the hot path.
 
     def _parse_validator_query(self, st, cols, query):
         """Normalize a validators/balances request WITHOUT touching any
@@ -574,7 +573,7 @@ class BeaconApi:
             "finalized": False,
         }
 
-    # -- per-object oracles (differential baselines + bench controls) --------
+    # -- per-object oracles (differential baselines) ---------------------------
 
     def state_validators_reference(self, st, indices=None):
         """The retained per-validator object walk (spec shapes, real

@@ -26,7 +26,7 @@ registry touches only its page's rows.
 
 Every byte produced here is identical to `json.dumps(oracle, separators
 =(",", ":"))` of the per-object renderers — asserted by the differential
-suite and the `api_throughput` bench's riding check.
+suite.
 """
 
 from __future__ import annotations

@@ -21,9 +21,8 @@ behavior instead of growing without bound. Exported three ways:
     compatible JSON at `/lighthouse/profile[?root=<name>]` on BOTH the
     MetricsServer and the Beacon API (metrics/server.serve_lighthouse_path),
   * `profiler_samples_total{root=...}` / `profiler_overrun_total`
-    metrics, eagerly registered,
-  * `bench.py --profile` embeds the top-N hotspot stacks per root into
-    the bench JSON (`hotspots` key).
+    metrics, eagerly registered, and
+  * `top_stacks()`: the top-N hotspot stacks per root.
 
 Knobs: `LIGHTHOUSE_TPU_PROFILE=1` arms the sampler (OFF by default —
 disabled, this module never creates a thread), `LIGHTHOUSE_TPU_PROFILE_HZ`
@@ -328,7 +327,7 @@ class StackProfiler:
         }
 
     def top_stacks(self, n: int = 5) -> dict[str, list[dict]]:
-        """Top-N hotspot stacks per root (the bench `hotspots` shape)."""
+        """Top-N hotspot stacks per root."""
         return {
             r: [
                 {"stack": k, "samples": v}

@@ -63,8 +63,7 @@ def serve_lighthouse_path(path: str, query: str = "", chain=None):
                     {
                         "message": (
                             "profiler disabled — set LIGHTHOUSE_TPU_PROFILE=1 "
-                            "(sampler arms at server start) or run "
-                            "bench.py --profile"
+                            "(sampler arms at server start)"
                         )
                     }
                 ).encode(),

@@ -72,7 +72,7 @@ def set_sync_state(value: int):
 
 
 def _register_metrics():
-    """Eager registration: the bench JSON and /metrics consumers rely on
+    """Eager registration: /metrics consumers rely on
     every sync series existing at zero before the first fault."""
     for chain in ("range", "backfill"):
         REGISTRY.counter("sync_batch_downloads_total").inc(0, chain=chain)
@@ -98,8 +98,7 @@ _register_metrics()
 
 @dataclass
 class SyncConfig:
-    """Retry/backoff knobs (BENCH_NOTES.md "Sync subsystem" documents the
-    tuning rationale; tests shrink the time constants)."""
+    """Retry/backoff knobs (tests shrink the time constants)."""
 
     #: slots per batch = epochs_per_batch * SLOTS_PER_EPOCH
     #: (BLOCKS_BY_RANGE batch sizing, range_sync/chain.rs EPOCHS_PER_BATCH)
@@ -275,36 +274,3 @@ class SyncManager:
     def on_unknown_block_root(self, block_root: bytes) -> bool:
         """Gossip referenced a root we don't have (attestation head)."""
         return self.lookups.search_block(block_root)
-
-    # -- the old sequential loop (bench control / oracle) ------------------
-
-    def sequential_sync_with(self, peer) -> int:
-        """The pre-engine single-peer loop, verbatim semantics: one batch
-        at a time, no retries, no timeouts, first fault stops the sync.
-        Kept as the `sync_catchup` bench's vs_baseline control and as a
-        differential oracle for the engine."""
-        service = self.service
-        chain = service.chain
-        status = peer.client.status(service.local_status())
-        peer.status = status
-        imported_total = 0
-        batch = self.config.epochs_per_batch * chain.E.SLOTS_PER_EPOCH
-        while int(status.head_slot) > chain.head_state.slot:
-            start = chain.head_state.slot + 1
-            blocks = peer.client.blocks_by_range(
-                start, batch, service.decode_block
-            )
-            if not blocks:
-                break
-            self.ctx.couple_blob_sidecars(peer, blocks)
-            result = chain.process_chain_segment(blocks)
-            imported_total += result.imported
-            inc_counter("sync_blocks_imported_total", amount=result.imported)
-            if result.error is not None:
-                from .. import SCORE_INVALID_MESSAGE
-
-                service.peers.report(peer.peer_id, SCORE_INVALID_MESSAGE)
-                break
-            if result.imported == 0:
-                break
-        return imported_total

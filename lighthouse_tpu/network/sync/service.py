@@ -34,7 +34,7 @@ from ...utils.logging import get_logger
 
 log = get_logger("lighthouse_tpu.sync.service")
 
-# eager registration: dashboards and the gossip_soak bench read these
+# eager registration: dashboards read these
 # before the first run
 for _result in ("caught_up", "progress", "failed"):
     REGISTRY.counter(

@@ -6,17 +6,16 @@ headers are queued as they arrive (the service feeds gossip in), then
 surround votes in both directions, double proposals — emitting
 ready-to-pool `AttesterSlashing` / `ProposerSlashing` containers.
 
-Two engines behind one factory:
+Two engines, one of them behind the factory:
 
-  * `columnar.ColumnarSlasher` (default) — the reference's chunked
-    min/max-span arrays rebuilt as resident uint16 numpy columns on the
-    validator axis (`spans.py`), detecting a whole cycle's attestations
+  * `columnar.ColumnarSlasher` (what `Slasher(...)` builds) — the
+    reference's chunked min/max-span arrays rebuilt as resident uint16
+    numpy columns on the validator axis (`spans.py`), detecting a whole cycle's attestations
     as array programs; detection history and dirty span tiles persist
     through the KV columns (`SLASHER_*` incl. the `SLASHER_MIN_SPAN` /
     `SLASHER_MAX_SPAN` tile pair) in one atomic batch per cycle.
   * `reference.ReferenceSlasher` — the retained scalar per-validator
-    dict engine: differential oracle and bench control
-    (`LIGHTHOUSE_TPU_COLUMNAR_SLASHER=0` selects it node-wide).
+    dict engine: the differential oracle the tests build directly.
 
 History is bounded to `history_length` epochs and pruned as the epoch
 advances, exactly as the reference's chunked arrays bound their window.
@@ -24,38 +23,23 @@ advances, exactly as the reference's chunked arrays bound their window.
 
 from __future__ import annotations
 
-import os
-
 from ..metrics import REGISTRY
 from .reference import (  # noqa: F401 — canonical config + record shapes
     DEFAULT_HISTORY_LENGTH,
     SlasherConfig,
 )
 
-#: kill switch: "0" routes every `Slasher(...)` construction to the
-#: retained scalar engine (differential control / emergency fallback)
-COLUMNAR_SLASHER_ENV = "LIGHTHOUSE_TPU_COLUMNAR_SLASHER"
-
-
-def columnar_enabled() -> bool:
-    return os.environ.get(COLUMNAR_SLASHER_ENV, "1") != "0"
-
 
 def Slasher(E, config: SlasherConfig | None = None, store=None):
-    """Engine factory — the columnar subsystem unless the kill switch
-    selects the retained scalar reference."""
-    if columnar_enabled():
-        from .columnar import ColumnarSlasher
+    """Engine factory — the columnar subsystem."""
+    from .columnar import ColumnarSlasher
 
-        return ColumnarSlasher(E, config, store)
-    from .reference import ReferenceSlasher
-
-    return ReferenceSlasher(E, config, store)
+    return ColumnarSlasher(E, config, store)
 
 
 # -- eager metric registration (conftest-asserted) ---------------------------
-# Every slasher_* series must exist at zero: the slasher_ingest bench reads
-# counter deltas, and dashboards scrape the trace-stage histograms eagerly.
+# Every slasher_* series must exist at zero: dashboards read counter deltas
+# and scrape the trace-stage histograms eagerly.
 _FOUND_ATT = REGISTRY.counter(
     "slasher_attester_slashings_found",
     "attester slashings detected by process_queued",

@@ -2,11 +2,10 @@
 
 This is the pre-columnar `Slasher` verbatim — per-validator dict
 bookkeeping, linear surround scans over `records.values()` — kept as the
-differential oracle and bench control for `slasher/columnar.py` (the
-reference crate's test strategy: the chunked min/max-span arrays must
-emit exactly what a direct interval check emits, in the same order, with
-the same strings). `LIGHTHOUSE_TPU_COLUMNAR_SLASHER=0` selects this
-engine at the `Slasher` factory.
+differential oracle for `slasher/columnar.py` (the reference crate's test
+strategy: the chunked min/max-span arrays must emit exactly what a direct
+interval check emits, in the same order, with the same strings). Tests
+build it directly; the `Slasher` factory never returns it.
 
 Epoch/slot arithmetic here predates the safe-arith scope extension to
 slasher/ and is kept byte-for-byte as the oracle:

@@ -440,8 +440,7 @@ def _participation_array(field, column, n: int) -> np.ndarray:
     """Participation flags as a [n] uint8 array: the resident column when
     attached (zero-copy view), `np.frombuffer` for the plain-bytearray
     representation, and a one-shot `load_array` extraction for a
-    persistent list without columns (the LIGHTHOUSE_TPU_RESIDENT_COLUMNS=0
-    oracle path). Always read-only: the sweep consumers are pure readers,
+    persistent list without columns (the legacy snapshot path). Always read-only: the sweep consumers are pure readers,
     and flag writes go through the attestation pipeline's writers."""
     from ..analysis.sanitizer import freeze_view
 
@@ -467,9 +466,8 @@ class EpochArrays:
         lists (exact dirty indices to the hash caches).
       * **legacy snapshot** (no columns): the per-validator
         ``np.fromiter`` passes and ``tolist()`` writebacks of the r2-r5
-        era — kept verbatim as the per-validator oracle the bench's
-        vs_baseline control and the differential suite run against, and
-        as the fallback for plain-list states.
+        era — kept verbatim as the per-validator oracle the differential
+        suite runs against, and as the path plain-list states take.
     """
 
     def __init__(self, state, E, columns=None):
@@ -1049,7 +1047,7 @@ def process_epoch_altair(state, spec: ChainSpec, E, fork: ForkName):
     steady state, all sweeps as array programs, and only vectorized-diff
     writebacks into the lists. Plain-list states take the legacy
     per-validator snapshot path (the retained oracle). Each stage is
-    wrapped in an ``epoch_stage_*`` span for the bench breakdown."""
+    wrapped in an ``epoch_stage_*`` span for the stage breakdown."""
     from ..utils.tracing import span
     from .per_epoch import (
         process_effective_balance_updates,

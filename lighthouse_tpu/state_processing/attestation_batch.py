@@ -33,13 +33,10 @@ that seam as an array program over the resident registry columns
 
 The scalar loop is retained verbatim as `process_attestations_reference`
 (altair.process_attestation_altair per attestation): the differential
-oracle, the bench control, and the `LIGHTHOUSE_TPU_BATCH_ATTESTATIONS=0`
-kill switch all run it.
+oracle the tests run, and the path blocks below `_SMALL_BATCH_ROWS` take.
 """
 
 from __future__ import annotations
-
-import os
 
 import numpy as np
 
@@ -67,7 +64,7 @@ _BATCH_TOTAL = REGISTRY.counter(
     "attestation_batch_total",
     "block attestation batches processed, by path",
 )
-for _path in ("columnar", "scalar", "scalar_small"):
+for _path in ("columnar", "scalar_small"):
     _BATCH_TOTAL.inc(0, path=_path)
 REGISTRY.histogram(
     "trace_span_seconds_attestation_apply",
@@ -82,17 +79,8 @@ _BIG = np.int64(1 << 62)  # first-occurrence sentinel (no attestation)
 # two-member minimal-preset committees never amortize — the same
 # calibrated-dispatch discipline as utils/sha256_batch.hash_rows. Any
 # mainnet-shaped block (128 atts × ~450 attesters ≈ 57k rows) is three
-# orders of magnitude past it. Counted as path="scalar_small", distinct
-# from the kill switch's path="scalar" (the perf_smoke guard asserts the
-# latter stays zero on the happy path).
+# orders of magnitude past it. Counted as path="scalar_small".
 _SMALL_BATCH_ROWS = 256
-
-
-def batch_enabled() -> bool:
-    """LIGHTHOUSE_TPU_BATCH_ATTESTATIONS=0 kills the columnar pipeline
-    process-wide; the scalar reference loop runs instead (the oracle the
-    differential suite and the bench control exercise)."""
-    return os.environ.get("LIGHTHOUSE_TPU_BATCH_ATTESTATIONS") != "0"
 
 
 def process_attestations_reference(
@@ -112,12 +100,6 @@ def process_attestations(
 ):
     """Validate and apply ALL of a block's attestations (altair→electra)."""
     if not attestations:
-        return
-    if not batch_enabled():
-        _BATCH_TOTAL.inc(path="scalar")
-        process_attestations_reference(
-            state, attestations, spec, E, verify_signatures, ctxt, fork
-        )
         return
     if sum(len(a.aggregation_bits) for a in attestations) < _SMALL_BATCH_ROWS:
         _BATCH_TOTAL.inc(path="scalar_small")

@@ -4,15 +4,10 @@
 A deliberately scalar, spec-shaped translation of the epoch sweeps (one
 Python loop iteration per validator, exactly the consensus-specs
 pseudocode / the naive reading of the reference's single_pass.rs): no
-numpy, no resident columns, no snapshot arrays. It exists for two jobs:
-
-  * **differential testing** — tests/test_registry_columns.py drives the
-    resident-columns transition and this oracle over identical states
-    and asserts bit-identical results across forks and churn;
-  * **the bench control** — bench.py's `epoch_transition_{100k,1m}`
-    vs_baseline is this oracle on a same-run subsample, extrapolated:
-    the honest "what does per-validator Python cost at this scale"
-    number the columnar path is scored against.
+numpy, no resident columns, no snapshot arrays. It is the differential
+oracle: tests/test_registry_columns.py drives the resident-columns
+transition and this oracle over identical states and asserts
+bit-identical results across forks and churn.
 
 Keep it boring. Any cleverness added here erodes its value as an oracle.
 """

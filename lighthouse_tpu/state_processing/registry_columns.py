@@ -86,8 +86,8 @@ for _field in (
     _REBUILDS.inc(0, field=_field)
     _WRITEBACKS.inc(0, field=_field)
 
-# Per-stage spans of the epoch transition (bench.py reads the histograms
-# eagerly for its breakdown; registered at import so they exist at zero).
+# Per-stage spans of the epoch transition (registered at import so their
+# histograms exist at zero).
 EPOCH_STAGES = (
     "columns_refresh",
     "justification",
@@ -636,13 +636,8 @@ def registry_columns_for(state) -> RegistryColumns | None:
     plain list (the token protocol would catch it too, but detaching
     keeps the fallback decision in one place).
 
-    LIGHTHOUSE_TPU_RESIDENT_COLUMNS=0 disables residency process-wide —
-    the legacy per-validator snapshot path is the retained oracle the
-    bench's vs_baseline control and the differential suite run against."""
-    import os
-
-    if os.environ.get("LIGHTHOUSE_TPU_RESIDENT_COLUMNS") == "0":
-        return None
+    The legacy per-validator snapshot path is the differential oracle the
+    tests run against: they reach it with plain-list states."""
     fields = getattr(type(state), "_REGISTRY_COLUMN_FIELDS", None)
     if fields is None:
         return None

@@ -91,7 +91,7 @@ class BackgroundMigrator:
         self.chain = chain
         self.store = chain.store
         # restore-point spacing: smaller = cheaper reconstruction replay,
-        # larger = smaller cold DB (BENCH_NOTES.md "Storage lifecycle")
+        # larger = smaller cold DB
         self.slots_per_restore_point = int(
             slots_per_restore_point
             if slots_per_restore_point is not None
@@ -100,8 +100,8 @@ class BackgroundMigrator:
         if self.slots_per_restore_point <= 0:
             raise ValueError("slots_per_restore_point must be positive")
         self.processor = None  # wired by ClientBuilder; None = inline
-        # A/B seam: the store_soak bench and the differential
-        # reconstruction test run a never-pruned chain by flipping this
+        # A/B seam: the differential reconstruction test runs a
+        # never-pruned chain by flipping this
         self.enabled = True
         self._epoch_lock = threading.Lock()
         self._last_migrated_epoch = 0

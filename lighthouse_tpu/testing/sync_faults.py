@@ -8,8 +8,7 @@ keyed off a per-service BlocksByRange request counter so tests can write
 deterministic scripts ("truncate the first response, then behave").
 
 The injected faults mirror the adversary matrix the sync engine is built
-against (BENCH_NOTES.md "Sync subsystem" documents the expected handling
-for each row).
+against.
 
 This is the single-peer ancestor of the fleet-scale fault plane:
 testing/testnet.py generalizes these per-peer scripts into a

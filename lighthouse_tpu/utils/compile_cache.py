@@ -1,7 +1,7 @@
 """Shared JAX persistent-compile-cache configuration + observability.
 
 Pairing-class kernels take minutes to compile cold; every entry point
-(the node, tests, bench, chip_smoke.py) points at the same on-disk cache
+(the node, tests, benchmark/, chip_smoke.py) points at the same on-disk cache
 so compiles amortize across processes. Keep the settings here — the one
 place — and call `enable_compile_cache()` before kernels are traced.
 
@@ -32,7 +32,6 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 
 from ..metrics import REGISTRY
 
@@ -143,23 +142,3 @@ def program_builds() -> list:
     BUILD_LOG_SIZE: (time.perf_counter() at the end of the phase, phase,
     seconds, program name)."""
     return list(_BUILDS)
-
-
-@contextmanager
-def track_device_compile(kernel: str):
-    """Wrap a warmup/first invocation of a device kernel in a
-    `device_compile` span, so the compile shows up inside whatever trace
-    is active; the compile counters count it from JAX's own events."""
-    from .tracing import span
-
-    with span("device_compile", kernel=kernel):
-        yield
-
-
-def compile_cache_stats() -> dict:
-    """Counter snapshot for the bench JSON (`compile_cache` key)."""
-    return {
-        "hits": _HITS.value(),
-        "misses": _MISSES.value(),
-        "compile_seconds": round(_BUILD_SECONDS.value(phase="compile"), 2),
-    }

@@ -21,7 +21,7 @@ beats numpy lanes; portable builds without SHA extensions lose to them).
 `LIGHTHOUSE_TPU_SHA256_MODE` pins the choice (`hashlib` | `numpy` |
 `device` | `auto`); `device` routes through ops/sha256's batched XLA
 kernel and is opt-in only — per-shape compiles make it a footgun on
-hosts without a real accelerator (see BENCH_NOTES.md).
+hosts without a real accelerator.
 
 Rows are processed in `_CHUNK`-sized slices so the ~30 live [m] uint32
 lanes stay cache-resident instead of streaming 4 MB arrays per ufunc.
@@ -305,14 +305,6 @@ def _calibrate() -> str:
         t_n = time.perf_counter() - t0
         _calibrated = "numpy" if t_n < t_h else "hashlib"
     return _calibrated
-
-
-def batch_mode() -> str:
-    """The large-batch backend currently in effect (for bench reporting)."""
-    mode = os.environ.get("LIGHTHOUSE_TPU_SHA256_MODE", "auto")
-    if mode == "auto":
-        return _calibrated or "auto (uncalibrated)"
-    return mode
 
 
 def hash_rows(pairs: np.ndarray) -> np.ndarray:

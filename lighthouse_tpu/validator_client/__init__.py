@@ -27,9 +27,9 @@ root per slot with fetch/assemble/protect/sign/publish stage spans:
   equal to the sequential per-key calls.
 
 The per-key path is retained verbatim as the differential oracle —
-`LIGHTHOUSE_TPU_VC_BATCH=0` drops the whole pipeline back to it, and
-tests/test_vc_batch.py asserts bit-identical signatures, identical
-slashing-DB end state, and identical refusals between the two."""
+tests/test_vc_batch.py drives the per-key methods directly and asserts
+bit-identical signatures, identical slashing-DB end state, and identical
+refusals between the two."""
 
 from __future__ import annotations
 
@@ -50,13 +50,6 @@ from ..types.chain_spec import Domain, compute_signing_root
 from ..utils.sha256_batch import hash_messages
 from ..utils.tracing import span
 from .slashing_protection import NotSafe, SlashingDatabase
-
-
-def _batch_enabled() -> bool:
-    """Batch duty-pipeline kill switch, read at call time so operators
-    (and the differential tests) can flip LIGHTHOUSE_TPU_VC_BATCH=0
-    mid-process and fall back to the per-key oracle path."""
-    return os.environ.get("LIGHTHOUSE_TPU_VC_BATCH", "1") != "0"
 
 
 def _columns(state):
@@ -445,9 +438,7 @@ class DutiesService:
             return cached
         state = self.node.head_state()
         ours = self._our_indices(state)
-        duties = None
-        if _batch_enabled():
-            duties = self._attester_duties_bulk(epoch, ours)
+        duties = self._attester_duties_bulk(epoch, ours)
         if duties is None:
             duties = self._attester_duties_scan(state, epoch, ours)
         self._duty_cache[key] = duties
@@ -554,15 +545,6 @@ class AttestationService:
             target=t.Checkpoint(epoch=epoch, root=target_root),
         )
 
-    def attest(self, slot: int, head_root: bytes) -> list:
-        """One slot's attestation duty for every managed key. The batch
-        pipeline (default) runs the slot as array/batch programs under a
-        `vc_duty_cycle` trace root; LIGHTHOUSE_TPU_VC_BATCH=0 drops to
-        the retained per-key oracle path."""
-        if not _batch_enabled():
-            return self._attest_per_key(slot, head_root)
-        return self._attest_batch(slot, head_root)
-
     def _attest_per_key(self, slot: int, head_root: bytes) -> list:
         from ..state_processing import per_slot_processing
         from ..types.containers import build_types
@@ -600,11 +582,13 @@ class AttestationService:
         self._last_attested = (slot, state, bytes(head_root))
         return out
 
-    def _attest_batch(self, slot: int, head_root: bytes) -> list:
-        """The per-key loop above, restructured as one batch program:
-        fetch duties once, assemble ONE AttestationData per committee,
-        run slashing protection as one transaction, sign through the
-        grouped batch signer, publish in duty order. Output list, refusal
+    def attest(self, slot: int, head_root: bytes) -> list:
+        """One slot's attestation duty for every managed key, under a
+        `vc_duty_cycle` trace root: the per-key loop above (the retained
+        oracle), restructured as one batch program: fetch duties once,
+        assemble ONE AttestationData per committee, run slashing
+        protection as one transaction, sign through the grouped batch
+        signer, publish in duty order. Output list, refusal
         set, counters, and slashing-DB end state are identical to
         `_attest_per_key` (asserted differentially)."""
         from ..state_processing import per_slot_processing
@@ -666,16 +650,6 @@ class AttestationService:
         self._last_attested = (slot, state, bytes(head_root))
         return out
 
-    def aggregate_if_selected(self, slot: int) -> list:
-        """Second phase of the attestation duty (validator.md 2/3-slot
-        mark): each managed attester computes its selection proof; those
-        selected as aggregators fetch the pool's best aggregate for their
-        committee and publish a SignedAggregateAndProof
-        (attestation_service.rs aggregate production)."""
-        if not _batch_enabled():
-            return self._aggregate_per_key(slot)
-        return self._aggregate_batch(slot)
-
     def _aggregate_per_key(self, slot: int) -> list:
         from ..beacon_chain.attestation_verification import is_aggregator
         from ..types.containers import build_types
@@ -725,8 +699,15 @@ class AttestationService:
             inc_counter("vc_aggregates_published_total", amount=accepted)
         return published
 
-    def _aggregate_batch(self, slot: int) -> list:
-        """Batch selection proofs: every proof this slot signs the SAME
+    def aggregate_if_selected(self, slot: int) -> list:
+        """Second phase of the attestation duty (validator.md 2/3-slot
+        mark): each managed attester computes its selection proof; those
+        selected as aggregators fetch the pool's best aggregate for their
+        committee and publish a SignedAggregateAndProof
+        (attestation_service.rs aggregate production).
+        `_aggregate_per_key` is the retained per-key oracle.
+
+        Batch selection proofs: every proof this slot signs the SAME
         root (a function of the slot alone), so one fixed-base table
         covers the whole fleet. The few selected aggregators then follow
         the per-key aggregate fetch/sign/publish tail unchanged."""
@@ -870,7 +851,7 @@ class SyncCommitteeService:
         if committee is None:
             return  # phase0: no sync committees yet
         managed = set(self.store.pubkeys())
-        cols = _columns(state) if _batch_enabled() else None
+        cols = _columns(state)
         if cols is not None:
             # one dict probe per managed key; duplicate pubkeys resolve
             # to the FIRST index (pubkey_index semantics — real
@@ -891,11 +872,6 @@ class SyncCommitteeService:
                 continue  # one message per validator even with N positions
             seen.add(vi)
             self._members.append((vi, pk))
-
-    def sign_messages(self, slot: int, head_root: bytes) -> list:
-        if not _batch_enabled():
-            return self._sign_messages_per_key(slot, head_root)
-        return self._sign_messages_batch(slot, head_root)
 
     def _sign_messages_per_key(self, slot: int, head_root: bytes) -> list:
         from ..types.containers import build_types
@@ -922,8 +898,9 @@ class SyncCommitteeService:
             )
         return out
 
-    def _sign_messages_batch(self, slot: int, head_root: bytes) -> list:
-        """Every member signs the SAME head root under the same domain —
+    def sign_messages(self, slot: int, head_root: bytes) -> list:
+        """`_sign_messages_per_key` is the retained per-key oracle. Here
+        every member signs the SAME head root under the same domain —
         one signing root, one message group, one fixed-base table inside
         `bls.sign_batch` (the many-keys-one-message shape the batch
         signer exists for)."""
@@ -987,7 +964,7 @@ class PreparationService:
         state = self.node.head_state()
         managed = set(self.store.pubkeys())
         prep = {}
-        cols = _columns(state) if _batch_enabled() else None
+        cols = _columns(state)
         if cols is not None:
             idx = cols.pubkey_index()
             for pk in managed:

@@ -28,8 +28,8 @@ enable_compile_cache()
 
 def pytest_sessionstart(session):
     """Tier-1 guard: the BLS verification caches must export hit/miss
-    counters through the metrics registry (the bench JSON and /metrics
-    consumers rely on the series existing even at zero)."""
+    counters through the metrics registry (/metrics consumers rely on
+    the series existing even at zero)."""
     from lighthouse_tpu.analysis import sanitizer  # noqa: F401 — registers
     from lighthouse_tpu.beacon_chain import (  # noqa: F401 — registers
         attestation_verification,  # gossip observation-delay histograms
@@ -93,13 +93,13 @@ def pytest_sessionstart(session):
         "bls_cache_hits_total",
         "bls_cache_misses_total",
         # PR 4: the host fork-pool task counter and the batch-verify path
-        # counter must exist at zero (bench/asserts read them eagerly)
+        # counter must exist at zero (asserts read them eagerly)
         'bls_pool_tasks_total{mode="inline"}',
         'bls_pool_tasks_total{mode="fork"}',
         'bls_batch_verify_total{path="msm"}',
         'bls_batch_verify_total{path="serial"}',
         # PR 5: the sync engine's gauge/counter series must exist at zero
-        # — the sync_catchup bench and dashboards read them eagerly
+        # — dashboards read them eagerly
         "sync_state",
         'sync_batch_downloads_total{chain="range"}',
         'sync_batch_downloads_total{chain="backfill"}',
@@ -113,8 +113,8 @@ def pytest_sessionstart(session):
         "sync_lookups_failed_total",
         "sync_lookup_reprocess_drained_total",
         # PR 6: the resident-columns counters and the per-stage epoch
-        # spans must exist at zero — the epoch_transition benches and
-        # the perf_smoke zero-rebuild guard read them eagerly
+        # spans must exist at zero — the stage breakdown and the
+        # perf_smoke zero-rebuild guard read them eagerly
         'registry_columns_rebuilds_total{field="validators"}',
         'registry_columns_rebuilds_total{field="balances"}',
         'registry_columns_rebuilds_total{field="inactivity_scores"}',
@@ -131,10 +131,9 @@ def pytest_sessionstart(session):
         "trace_span_seconds_epoch_stage_final_updates",
         # PR 7: the columnar attestation pipeline's path counter, the
         # participation-column counters, and the apply span must exist at
-        # zero — the attestation_batch bench and the perf_smoke
-        # no-scalar-fallback guard read them eagerly
+        # zero — the perf_smoke columnar-path guard and dashboards read
+        # them eagerly
         'attestation_batch_total{path="columnar"}',
-        'attestation_batch_total{path="scalar"}',
         'attestation_batch_total{path="scalar_small"}',
         'registry_columns_rebuilds_total{field="previous_epoch_participation"}',
         'registry_columns_rebuilds_total{field="current_epoch_participation"}',
@@ -182,8 +181,8 @@ def pytest_sessionstart(session):
         "beacon_aggregate_gossip_slot_start_delay_seconds",
         # PR 10: the profiler's sample/overrun counters, the compile-cache
         # counters, the gossip mesh/peer-score series, and the per-method
-        # RPC latency histograms must exist at zero — /lighthouse/profile,
-        # bench --profile, and dashboards read them eagerly
+        # RPC latency histograms must exist at zero — /lighthouse/profile
+        # and dashboards read them eagerly
         'profiler_samples_total{root="block_import"}',
         'profiler_samples_total{root="sync_range_batch"}',
         'profiler_samples_total{root="other"}',
@@ -212,8 +211,8 @@ def pytest_sessionstart(session):
         "rpc_client_request_seconds_metadata",
         # PR 11: event-driven node — gossip outcome counters, processor
         # abandonment, the bounded reprocess queue, and the autonomous
-        # sync service must exist at zero (the gossip_soak bench and the
-        # storm sim read them eagerly)
+        # sync service must exist at zero (dashboards and the storm sim
+        # read them eagerly)
         "gossip_internal_error_total",
         "gossip_ignored_total",
         'beacon_processor_abandoned_total{kind="gossip_block"}',
@@ -232,8 +231,8 @@ def pytest_sessionstart(session):
         'beacon_processor_queue_depth_by_kind{kind="gossip_sync_committee"}',
         # PR 12: array-program fork choice — the vote-ingestion path
         # counter, the get_head trace root, and its stage spans must
-        # exist at zero (the fork_choice bench stage breakdown and the
-        # perf_smoke no-scalar-fallback guard read them eagerly)
+        # exist at zero (the get_head stage breakdown and the perf_smoke
+        # no-scalar-fallback guard read them eagerly)
         'fork_choice_votes_applied_total{path="batch"}',
         'fork_choice_votes_applied_total{path="single"}',
         'trace_collector_traces_total{root="fork_choice_get_head"}',
@@ -243,8 +242,8 @@ def pytest_sessionstart(session):
         "trace_span_seconds_best_child",
         # PR 13: columnar slasher — engine/scan/tile counters, the
         # slasher_process trace root and its stage spans, and the
-        # SLASHER_PROCESS processor lane series must exist at zero (the
-        # slasher_ingest bench reads counter deltas + stage spans eagerly)
+        # SLASHER_PROCESS processor lane series must exist at zero
+        # (dashboards read counter deltas + stage spans eagerly)
         "slasher_attester_slashings_found",
         "slasher_proposer_slashings_found",
         'slasher_slashings_found_total{kind="attester"}',
@@ -268,8 +267,7 @@ def pytest_sessionstart(session):
         # PR 14: the API serving tier — the zero-copy assembly counter,
         # the per-route response-cache counters, and the api_request
         # cache_lookup/assemble/serialize stage spans must exist at zero
-        # (the api_throughput bench reads counter deltas + stage spans
-        # eagerly)
+        # (dashboards read counter deltas + stage spans eagerly)
         'api_columnar_assembly_total{route="validators"}',
         'api_columnar_assembly_total{route="validator_balances"}',
         'api_columnar_assembly_total{route="committees"}',
@@ -286,8 +284,7 @@ def pytest_sessionstart(session):
         # PR 15: the testnet scenario harness — fault-plane verbs, frame
         # drop/delay accounting, oracle outcomes, and the peer-lifecycle
         # recovery counters the partition/heal scenarios assert — must
-        # exist at zero (the testnet_soak bench and scenario_smoke read
-        # them eagerly)
+        # exist at zero (scenario_smoke reads them eagerly)
         'testnet_fault_injections_total{kind="partition"}',
         'testnet_fault_injections_total{kind="heal"}',
         'testnet_fault_injections_total{kind="eclipse"}',
@@ -304,8 +301,8 @@ def pytest_sessionstart(session):
         "sync_fork_backtracks_total",
         # PR 16: the PeerDAS series — batched-vs-oracle cell verification,
         # sampling verdicts, reconstruction promotions — must exist at
-        # zero (the da_verify bench and the withholding scenario read
-        # them eagerly), plus the da_verify stage spans
+        # zero (the withholding scenario reads them eagerly), plus the
+        # da_verify stage spans
         'das_cells_verified_total{path="batched"}',
         'das_cells_verified_total{path="oracle"}',
         'das_sampling_results_total{verdict="success"}',
@@ -318,8 +315,8 @@ def pytest_sessionstart(session):
         # PR 17: the proposer-pipeline series — snapshot-cache accounting,
         # the block_production trace root's stage spans, and the
         # fork-choice deferral queue outcomes — must exist at zero (the
-        # block_production bench reads the stage breakdown eagerly and
-        # the fleet scenarios difference the deferral counters)
+        # block_production stage breakdown is read eagerly and the
+        # fleet scenarios difference the deferral counters)
         "state_advance_hits_total",
         "state_advance_misses_total",
         "state_advance_wasted_total",
@@ -331,8 +328,8 @@ def pytest_sessionstart(session):
         'fork_choice_deferred_attestations_total{outcome="applied"}',
         'fork_choice_deferred_attestations_total{outcome="dropped"}',
         # PR 18: the SSE fan-out tier + serving-worker pool series must
-        # exist at zero — the sse_fanout bench differences the delivery/
-        # drop counters eagerly, and the worker supervisor's respawn and
+        # exist at zero — dashboards difference the delivery/drop
+        # counters eagerly, and the worker supervisor's respawn and
         # forwarding accounting is asserted by the lifecycle tests before
         # any worker has ever forked
         "sse_subscribers",
@@ -350,9 +347,9 @@ def pytest_sessionstart(session):
         "api_worker_fan_drops_total",
         'api_worker_requests_forwarded_total{why="stale"}',
         'api_worker_requests_forwarded_total{why="proxy_route"}',
-        # PR 19: the batched VC duty pipeline — the vc_epoch_100k bench
-        # differences the publish/refusal counters and the sign-strategy
-        # split eagerly, and the vc_duty_cycle trace root + stage spans
+        # The batched VC duty pipeline — dashboards difference the
+        # publish/refusal counters and the sign-strategy split eagerly,
+        # and the vc_duty_cycle trace root + stage spans
         # must exist at zero before any duty runs
         "vc_attestations_published_total",
         "vc_blocks_published_total",
@@ -368,10 +365,9 @@ def pytest_sessionstart(session):
         "trace_span_seconds_vc_protect",
         "trace_span_seconds_vc_sign_batch",
         "trace_span_seconds_vc_publish",
-        # PR 20: the storage lifecycle subsystem — the store_soak bench
-        # differences the migration counters ON-vs-OFF, the health block
-        # mirrors store_split_slot, and the checkpoint_boot_s bench reads
-        # the boot counter eagerly (the MIGRATE_STORE queue-wait series
+        # The storage lifecycle subsystem — dashboards difference
+        # the migration counters, the health block mirrors
+        # store_split_slot, and the boot counter is read eagerly (the MIGRATE_STORE queue-wait series
         # is covered by the WorkType loop above)
         "store_migrations_total",
         "store_blocks_migrated_total",

@@ -33,8 +33,7 @@ def _dump(obj) -> bytes:
 
 
 class _StubChain:
-    """The minimum BeaconApi needs to serve state routes (the bench's
-    api_throughput fixture uses the same shape)."""
+    """The minimum BeaconApi needs to serve state routes."""
 
     def __init__(self, state, spec):
         self.head_state = state
@@ -246,22 +245,25 @@ def test_id_filter_string_ids_regression(stub_api):
     assert [e["index"] for e in doc["data"]] == ["3", "4"]
 
 
-def test_status_filter_on_oracle_path(monkeypatch):
+def test_status_filter_on_oracle_path():
     """A status= filter must work (not 500) when the state has no
     resident columns — the per-object fallback computes codes too."""
-    monkeypatch.setenv("LIGHTHOUSE_TPU_RESIDENT_COLUMNS", "0")
     state, spec = _build_state(altair=True)
     _diversify(state)
-    api = BeaconApi(_StubChain(state, spec))
+    # a plain-list registry: no resident columns, the per-object oracle
+    oracle = state.copy()
+    oracle.validators = list(state.validators)
+    assert registry_columns_for(oracle) is None
+    api = BeaconApi(_StubChain(oracle, spec))
     body, _ = api.serve_state_validators("head", {"status": ["exited_slashed"]})
     assert [e["index"] for e in json.loads(body)["data"]] == ["8"]
     # and the oracle fallback body is the same bytes the columnar path
     # produces for the same filter
-    monkeypatch.delenv("LIGHTHOUSE_TPU_RESIDENT_COLUMNS")
     api2 = BeaconApi(_StubChain(state, spec))
     body2, _ = api2.serve_state_validators(
         "head", {"status": ["exited_slashed"]}
     )
+    assert registry_columns_for(state) is not None
     assert body2 == body
 
 

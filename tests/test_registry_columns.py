@@ -8,7 +8,6 @@ per-validator path, under randomized participation, slashings, ejections,
 activation churn and `state.copy()` aliasing, across phase0/altair/electra.
 """
 
-import os
 import random
 from dataclasses import replace
 
@@ -188,15 +187,11 @@ def test_resident_epoch_matches_per_validator_oracle(fork, seed):
 
     process_epoch(subject, spec, E)
 
-    # comparator 1: the scalar per-validator spec loops (the bench's
-    # vs_baseline oracle)
+    # comparator 1: the scalar per-validator spec loops
     process_epoch_reference(scalar, spec, E)
-    # comparator 2: the legacy snapshot path (r05's shipped code)
-    os.environ["LIGHTHOUSE_TPU_RESIDENT_COLUMNS"] = "0"
-    try:
-        process_epoch(legacy, spec, E)
-    finally:
-        del os.environ["LIGHTHOUSE_TPU_RESIDENT_COLUMNS"]
+    # comparator 2: the legacy snapshot path, which plain-list states take
+    assert registry_columns_for(legacy) is None
+    process_epoch(legacy, spec, E)
 
     got = _state_fingerprint(subject)
     for name, other in (("scalar-oracle", scalar), ("legacy-snapshot", legacy)):
@@ -292,11 +287,7 @@ def test_phase0_vectorized_deltas_match_reference_oracle():
         a = state.copy()
         b = state.copy()
         process_slashings(a, E)
-        os.environ["LIGHTHOUSE_TPU_RESIDENT_COLUMNS"] = "0"
-        try:
-            process_slashings_reference(b, E)
-        finally:
-            del os.environ["LIGHTHOUSE_TPU_RESIDENT_COLUMNS"]
+        process_slashings_reference(b, E)
         assert list(a.balances) == list(b.balances)
 
 

@@ -81,7 +81,7 @@ def test_sanctioned_store_array_still_works(sanitize):
 
 
 def test_load_array_stays_writable_off_mode(monkeypatch):
-    """No behavior change with the sanitizer off (bench mode)."""
+    """No behavior change with the sanitizer off (production mode)."""
     monkeypatch.delenv(sanitizer.ENV_VAR, raising=False)
     lst = PersistentList(range(10))
     arr = lst.load_array()
@@ -106,10 +106,10 @@ def test_committee_slices_frozen_in_all_modes():
     assert committee.tolist() == list(committee)
 
 
-def test_epoch_arrays_views_frozen_in_all_modes(monkeypatch):
+def test_epoch_arrays_views_frozen_in_all_modes():
     from lighthouse_tpu.state_processing.altair import EpochArrays
 
-    monkeypatch.setenv("LIGHTHOUSE_TPU_RESIDENT_COLUMNS", "0")
+    # a persistent state with no columns given: the legacy snapshot path
     state, _spec = trc._base_state(ForkName.ALTAIR, 200, 9)
     _make_persistent(state)
     arrays = EpochArrays(state, E)
@@ -237,7 +237,7 @@ def test_sanitize_soak_block_import_epoch_roundtrip(fork, monkeypatch):
     the result bit-identical to the scalar oracle run WITHOUT the
     sanitizer: the guards must catch nothing (zero violations) and
     change nothing (fingerprint equality). This is how a CoW regression
-    gets caught before it reaches a 1M-validator bench."""
+    gets caught before it reaches a 1M-validator node."""
     import test_attestation_batch as tab
 
     from lighthouse_tpu.state_processing import attestation_batch
@@ -277,9 +277,8 @@ def test_sanitize_soak_block_import_epoch_roundtrip(fork, monkeypatch):
         oracle, atts, spec, E, False, ConsensusContext(oracle.slot), fork
     )
     oracle.slot = subject.slot
-    monkeypatch.setenv("LIGHTHOUSE_TPU_RESIDENT_COLUMNS", "0")
+    assert registry_columns_for(oracle) is None  # the legacy snapshot path
     process_epoch(oracle, spec, E)
-    monkeypatch.delenv("LIGHTHOUSE_TPU_RESIDENT_COLUMNS")
 
     got = trc._state_fingerprint(subject)
     want = trc._state_fingerprint(oracle)

@@ -206,14 +206,18 @@ def test_slasher_service_end_to_end():
     assert slashed == {3}
 
 
-def test_columnar_kill_switch(monkeypatch):
-    """LIGHTHOUSE_TPU_COLUMNAR_SLASHER=0 routes the factory to the
-    retained scalar engine; default is the columnar subsystem."""
+def test_columnar_kill_switch(tmp_path):
+    """The factory always builds the columnar subsystem; the retained
+    scalar oracle builds from the same arguments."""
+    from lighthouse_tpu.store import open_item_store
+
     assert isinstance(Slasher(E), ColumnarSlasher)
-    monkeypatch.setenv("LIGHTHOUSE_TPU_COLUMNAR_SLASHER", "0")
-    assert isinstance(Slasher(E), ReferenceSlasher)
-    monkeypatch.setenv("LIGHTHOUSE_TPU_COLUMNAR_SLASHER", "1")
-    assert isinstance(Slasher(E), ColumnarSlasher)
+    config = SlasherConfig(history_length=64)
+    store = open_item_store(str(tmp_path / "slasher-db"))
+    columnar = Slasher(E, config, store)
+    oracle = ReferenceSlasher(E, config, store)
+    assert isinstance(columnar, ColumnarSlasher)
+    assert columnar.config == oracle.config == config
 
 
 def test_persistence_restart_detects_double_vote(engine, tmp_path):

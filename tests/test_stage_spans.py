@@ -227,7 +227,7 @@ def test_device_verifier_records_each_stage_once(monkeypatch):
 
 def test_device_verifier_chunks_get_a_span_each(monkeypatch):
     _stand_in_kernels(monkeypatch)
-    monkeypatch.setenv("LIGHTHOUSE_TPU_BLS_CHUNK", "2")
+    monkeypatch.setattr(bls, "DEFAULT_DEVICE_CHUNK", 2)
     kp = bls.interop_keypairs(1)[0]
     msg = b"\x03" * 32
     sets = [bls.SignatureSet.single(kp.sk.sign(msg), kp.pk, msg)] * 3

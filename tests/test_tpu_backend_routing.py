@@ -33,7 +33,7 @@ def test_full_path_chunks_batches(backend, monkeypatch):
     monkeypatch.setattr(
         ops_full, "verify_signature_sets_device_full", fake_full
     )
-    monkeypatch.setenv("LIGHTHOUSE_TPU_BLS_CHUNK", "4")
+    monkeypatch.setattr(bls, "DEFAULT_DEVICE_CHUNK", 4)
     assert backend.verify_signature_sets(_sets(10)) is True
     assert calls == [4, 4, 2]  # bounded shapes, full coverage
 
@@ -48,7 +48,7 @@ def test_failing_chunk_fails_the_batch(backend, monkeypatch):
     monkeypatch.setattr(
         ops_full, "verify_signature_sets_device_full", fake_full
     )
-    monkeypatch.setenv("LIGHTHOUSE_TPU_BLS_CHUNK", "3")
+    monkeypatch.setattr(bls, "DEFAULT_DEVICE_CHUNK", 3)
     assert backend.verify_signature_sets(_sets(9)) is False
     assert len(calls) == 2  # short-circuits after the failing chunk
 

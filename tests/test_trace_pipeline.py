@@ -498,19 +498,6 @@ def test_sync_sim_populates_chain_segment_queue_waits():
     assert run.count > r0, "range-sync batches must record handler run time"
 
 
-def test_bench_histogram_percentiles_helper():
-    import bench
-
-    # 10 samples in the ≤0.25 bucket of a (0.1, 0.25, 0.5) histogram
-    buckets = (0.1, 0.25, 0.5)
-    counts = [0, 10, 0, 0]
-    p = bench._hist_percentiles(buckets, counts)
-    assert p["count"] == 10
-    assert 100.0 < p["p50_ms"] <= 250.0
-    assert p["p50_ms"] < p["p99_ms"] <= 250.0
-    assert bench._hist_percentiles(buckets, [0, 0, 0, 0]) is None
-
-
 # -- validator monitor satellite ---------------------------------------------
 
 

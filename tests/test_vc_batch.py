@@ -6,8 +6,8 @@ compressed bytes), `bls.sign_batch` vs per-key `sk.sign`, the epoch duty
 table vs the committee walk, the batch slashing-protection transaction
 vs sequential per-key checks (including hostile surround / lowball /
 double-vote mixes and crash-point atomicity), and the whole VC pipeline
-batch-vs-per-key under LIGHTHOUSE_TPU_VC_BATCH — identical chain roots,
-identical slashing-DB end state. Keymanager keystore routes are covered
+batch-vs-per-key (the per-key service methods swapped in) — identical
+chain roots, identical slashing-DB end state. Keymanager keystore routes are covered
 at scale (satellite: 1k in tier-1, 10k behind the slow mark)."""
 
 import random
@@ -30,7 +30,14 @@ from lighthouse_tpu.crypto.bls12_381 import (
 from lighthouse_tpu.crypto.keystore import Keystore
 from lighthouse_tpu.types.chain_spec import minimal_spec
 from lighthouse_tpu.types.eth_spec import MinimalEthSpec as E
-from lighthouse_tpu.validator_client import ValidatorClient, _columns
+import lighthouse_tpu.validator_client as vc_module
+from lighthouse_tpu.validator_client import (
+    AttestationService,
+    DutiesService,
+    SyncCommitteeService,
+    ValidatorClient,
+    _columns,
+)
 from lighthouse_tpu.validator_client.http_api import KeymanagerApi
 from lighthouse_tpu.validator_client.slashing_protection import (
     NotSafe,
@@ -154,7 +161,7 @@ def test_epoch_duty_table_matches_committee_walk():
 # --- duties service ---------------------------------------------------------
 
 
-def test_our_indices_pubkey_index_matches_scan(monkeypatch):
+def test_our_indices_pubkey_index_matches_scan():
     """Satellite: `_our_indices` resolves through the resident columns'
     pubkey_index(); column-less states keep the O(n) scan."""
     h, vc = _vc_setup()
@@ -164,22 +171,21 @@ def test_our_indices_pubkey_index_matches_scan(monkeypatch):
     via_columns = ds._our_indices(st)
     assert via_columns == ds._our_indices_scan(st)
     assert sorted(via_columns) == list(range(16))
-    # column-less fallback: disabling residency must not change results
-    monkeypatch.setenv("LIGHTHOUSE_TPU_RESIDENT_COLUMNS", "0")
-    assert _columns(st) is None
-    assert ds._our_indices(st) == via_columns
+    # column-less fallback: a plain-list registry must not change results
+    plain = st.copy()
+    plain.validators = list(st.validators)
+    assert _columns(plain) is None
+    assert ds._our_indices(plain) == via_columns
 
 
 def test_duties_bulk_fetch_matches_scan(monkeypatch):
     h, vc = _vc_setup()
     ds = vc.duties_service
     bulk = ds.attester_duties(0)
-    monkeypatch.setenv("LIGHTHOUSE_TPU_VC_BATCH", "0")
-    ds._duty_cache.clear()
-    scan = ds.attester_duties(0)
+    st = vc.node.head_state()
+    scan = ds._attester_duties_scan(st, 0, ds._our_indices(st))
     assert bulk == scan
     # pagination must not change the result set or its order
-    monkeypatch.delenv("LIGHTHOUSE_TPU_VC_BATCH", raising=False)
     monkeypatch.setenv("LIGHTHOUSE_TPU_VC_DUTIES_PAGE", "3")
     ds._duty_cache.clear()
     assert ds.attester_duties(0) == scan
@@ -221,16 +227,37 @@ def test_http_duties_route_matches_vc_bulk_fetch():
 # --- whole-pipeline differential -------------------------------------------
 
 
+def _route_vc_to_per_key(monkeypatch):
+    """Point the VC's public service methods at the retained per-key
+    oracle methods, and its registry lookups at the O(n) scans."""
+    monkeypatch.setattr(vc_module, "_columns", lambda state: None)
+    monkeypatch.setattr(
+        DutiesService, "_attester_duties_bulk", lambda self, epoch, ours: None
+    )
+    monkeypatch.setattr(AttestationService, "attest", AttestationService._attest_per_key)
+    monkeypatch.setattr(
+        AttestationService,
+        "aggregate_if_selected",
+        AttestationService._aggregate_per_key,
+    )
+    monkeypatch.setattr(
+        SyncCommitteeService,
+        "sign_messages",
+        SyncCommitteeService._sign_messages_per_key,
+    )
+
+
 def test_vc_batch_pipeline_matches_per_key_oracle(monkeypatch):
     """Tentpole oracle: drive two identical chains for 2 epochs, one VC
-    on the batch pipeline and one forced per-key via the kill switch.
+    on the batch pipeline and one on the per-key oracle methods.
     Chain head roots (covering every published block / attestation /
     sync message bit-for-bit), finality, and the slashing-DB end state
     must be identical."""
     spec = replace(minimal_spec(), altair_fork_epoch=0)
     results = {}
-    for mode in ("1", "0"):
-        monkeypatch.setenv("LIGHTHOUSE_TPU_VC_BATCH", mode)
+    for mode in ("batch", "per_key"):
+        if mode == "per_key":
+            _route_vc_to_per_key(monkeypatch)
         bls.set_backend("fake_crypto")
         h = BeaconChainHarness(spec, E, validator_count=16)
         vc = ValidatorClient(h.chain, h.keypairs, spec, E)
@@ -252,7 +279,7 @@ def test_vc_batch_pipeline_matches_per_key_oracle(monkeypatch):
             ).fetchall(),
         )
         results[mode] = (roots, dump, h.finalized_epoch)
-    assert results["1"] == results["0"]
+    assert results["batch"] == results["per_key"]
 
 
 def test_vc_duty_cycle_trace_root_recorded():
