@@ -14,13 +14,13 @@ programs a chunk, about one a stage, in launch order:
                                      the SHA-256 expand_message_xmd),
                                      launched first, so the device hashes
                                      while the host decodes the keys
-  2. per-set pubkey aggregation   — a loop over the padded committee axis
-                                     (G1, Fq lanes)
+  2. per-set pubkey aggregation   — a halving tree over the padded
+                                     committee axis (G1, Fq lanes)
   3. rᵢ scalar multiplications    — batched double-and-add ladders (bls381),
      the signature sum Σ rᵢ·sigᵢ  — a G2 halving tree, and
      G2 subgroup checks on sigs   — ψ(Q) == [x]Q, [|x|]Q as extra lanes of
                                      the G2 ladder (bls381_pairing)
-  4. per-message pubkey sums      — gather + the same committee loop
+  4. per-message pubkey sums      — gather + the same committee tree
   5. Jacobian→affine              — one shared Fermat inversion, then
   6. Miller loops                   one lane per pair (bls381_pairing)
 
@@ -30,8 +30,8 @@ subgroup verdicts, the Miller lanes). The host's remaining jobs: point
 decompression (bytes → ints, cached on the PublicKey/Signature wrappers),
 RLC scalar sampling, the product of the Miller lanes and the one final
 exponentiation, and padding every batch to a few shapes (the set axis to
-the device chunk, committees to a power-of-two bucket of AGG_LANES
-slices), so one set of compiled programs serves every batch — the
+the device chunk, committees to a power-of-two bucket of at least
+AGG_LANES keys), so one set of compiled programs serves every batch — the
 reference batches gossip work in fixed chunks of 64 for the same reason,
 beacon_processor/src/lib.rs:200.
 """
@@ -54,7 +54,6 @@ from .bls381 import (
     DevFq2,
     batch_g1_scalar_mul,
     batch_g2_scalar_mul,
-    fold_sum,
     fq_to_device,
     mont_mul,
     pt_add,
@@ -71,9 +70,8 @@ from .bls381_pairing import (
 )
 from .bls381_tower import f2_mul, f2_norm, f2_scale_inv, f2_sqr, fq_inv
 
-#: committee/group lanes summed per loop step: the committee axis is
-#: walked in fixed-width slices, so one compiled program serves every
-#: committee size in a bucket
+#: the smallest committee bucket: a set of one key pads to this many
+#: lanes, so one compiled program serves every single-key batch
 AGG_LANES = 16
 
 
@@ -82,20 +80,30 @@ AGG_LANES = 16
 # ---------------------------------------------------------------------------
 
 
-def _segment_sum(xs, ys, zs, slices):
-    """[n, k] padded G1 points (infinity pads, k a multiple of AGG_LANES)
-    → [n] sums over the first `slices` AGG_LANES-wide slices: a runtime
-    count, so every committee size up to k shares one program."""
-    acc = (xs[:, 0], ys[:, 0], jnp.zeros_like(zs[:, 0]))  # infinity
+def _segment_sum(xs, ys, zs, levels):
+    """[n, k] padded G1 points (infinity pads, k a power of two) → [n]
+    sums of the first 2^`levels` lanes, as a halving tree over the
+    committee axis: a loop over the levels whose body is one point add on
+    the [n, k/2] low half, where at level i lane j takes lane j + half
+    (half = 2^(levels-1-i)) and the lanes past the half add infinity, so
+    they keep their points. `levels` is a runtime count, so every
+    committee size up to k shares one program, and a row of m keys runs
+    ⌈log2 m⌉ levels. The sum is exact whatever the association: pt_add
+    takes doubling and infinity by selects."""
+    w = xs.shape[1] // 2
+    # committee axis first: the halves are then contiguous slices
+    pts = tuple(jnp.swapaxes(c, 0, 1) for c in (xs, ys, zs))
+    lane = jnp.arange(w)[:, None, None]
 
-    def body(j, acc):
-        sl = tuple(
-            lax.dynamic_slice_in_dim(c, j * AGG_LANES, AGG_LANES, axis=1)
-            for c in (xs, ys, zs)
-        )
-        return fold_sum(DevFq, acc, sl)
+    def level(i, pts):
+        half = jnp.left_shift(1, levels - 1 - i)
+        hx, hy, hz = (lax.dynamic_slice_in_dim(c, half, w) for c in pts)
+        hz = jnp.where(lane < half, hz, 0)  # past the half: infinity
+        out = pt_add(DevFq, tuple(c[:w] for c in pts), (hx, hy, hz))
+        return tuple(c.at[:w].set(o) for c, o in zip(pts, out))
 
-    return lax.fori_loop(0, slices, body, acc)
+    pts = lax.fori_loop(0, levels, level, pts)
+    return tuple(c[0] for c in pts)
 
 
 def _g2_sum(pt):
@@ -140,9 +148,9 @@ def _g2_affine_from_inv(x, y, z, zinv):
 
 
 @jax.jit
-def _aggregate(grid, slices):
+def _aggregate(grid, levels):
     """Per-set pubkey sums over the padded committee axis."""
-    return _segment_sum(*grid, slices)
+    return _segment_sum(*grid, levels)
 
 
 @jax.jit
@@ -164,15 +172,15 @@ def _ladders(agg, sigs, bits):
 
 
 @jax.jit
-def _message_groups(s_pk, idx, slices):
+def _message_groups(s_pk, idx, levels):
     """Per-message sums of the scaled pubkeys: one infinity lane appended
     (the gather's pad slot where no padded set lane serves), the gather by
-    the host's index rows, then the committee loop over them."""
+    the host's index rows, then the committee tree over them."""
     grid = (
         jnp.take(jnp.concatenate([c, jnp.zeros_like(c[-1:])]), idx, axis=0)
         for c in s_pk
     )
-    return _segment_sum(*grid, slices)
+    return _segment_sum(*grid, levels)
 
 
 _NEG_G1 = to_affine(FQ, pt_neg(FQ, G1_GEN))
@@ -209,9 +217,9 @@ def _bucket(n: int, floor: int) -> int:
     return b
 
 
-def _slices(k: int) -> np.int32:
-    """AGG_LANES slices that hold k lanes."""
-    return np.int32(-(-k // AGG_LANES))
+def _levels(k: int) -> np.int32:
+    """Halving-tree levels that sum k lanes: ⌈log2 k⌉."""
+    return np.int32((k - 1).bit_length())
 
 
 def _affine_int(pt):
@@ -315,7 +323,7 @@ def verify_signature_sets_device_full(sets, rng=None) -> bool:
     n = len(sets)
     # a few shapes for every chunk the node sends: the set axis pads to the
     # device chunk, messages pad to the set count, committees to a
-    # power-of-two bucket of AGG_LANES slices
+    # power-of-two bucket of at least AGG_LANES keys
     nb = _bucket(n, floor=bls.DEFAULT_DEVICE_CHUNK)
     kb = _bucket(max(len(s.pubkeys) for s in sets), floor=AGG_LANES)
     _BATCHES.inc()
@@ -368,15 +376,16 @@ def _verify_chunk(sets, rng, nb: int, kb: int, launch) -> bool:
         hm = launch(hash_to_g2_device, u)
 
     # --- per-set pubkey aggregation (padded committee axis) ---
-    with span("bls_device_aggregate"):
+    with span("bls_device_aggregate") as stage:
         try:
             pk_rows = [[_affine_int(pk.point()) for pk in s.pubkeys] for s in sets]
         except (bls.BlsError, ValueError):
             return False
         grid = [row + [None] * (kb - len(row)) for row in pk_rows]
         grid += [[None] * kb] * (nb - n)
-        slices = _slices(max(len(row) for row in pk_rows))
-        agg = launch(_aggregate, _g1_affine_grid(grid), slices)
+        levels = _levels(max(len(row) for row in pk_rows))
+        stage.fields["levels"] = int(levels)
+        agg = launch(_aggregate, _g1_affine_grid(grid), levels)
 
     # --- RLC scalar multiplications, the signature aggregate and the
     # signatures' G2 subgroup checks ---
@@ -386,15 +395,16 @@ def _verify_chunk(sets, rng, nb: int, kb: int, launch) -> bool:
         s_pk, agg_sig, in_sub = launch(_ladders, agg, sigs, bits)
 
     # --- per-message aggregation of scaled pubkeys (gather + sum) ---
-    with span("bls_device_message_groups"):
+    with span("bls_device_message_groups") as stage:
         # the gather's pad slot: a padded set lane (scalar 0 ladder →
         # infinity) where there is one, else the appended infinity lane
         pad_slot = nb - 1 if nb > n else nb
         idx = np.full((nb, nb), pad_slot, dtype=np.int32)
         for gi, m in enumerate(msgs):
             idx[gi, : len(groups[m])] = groups[m]
-        slices = _slices(max(len(v) for v in groups.values()))
-        msg_pk = launch(_message_groups, s_pk, idx, slices)
+        levels = _levels(max(len(v) for v in groups.values()))
+        stage.fields["levels"] = int(levels)
+        msg_pk = launch(_message_groups, s_pk, idx, levels)
 
     # --- the subgroup verdicts, read while the device sums the groups ---
     with span("bls_device_subgroup"):

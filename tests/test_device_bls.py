@@ -133,3 +133,8 @@ def test_device_verify_signature_sets(case):
     ]
     inner = [w for c in call.children for w in c.children]
     assert [w.name for w in inner] == ["bls_device_wait"] * 2 + ["bls_pairing_host_tail"]
+    # the committee trees' levels: ⌈log2⌉ of the longest key row and of the
+    # one message group (every set signs `msg`)
+    stages = {c.name: c for c in call.children}
+    assert stages["bls_device_aggregate"].fields == {"levels": 9 if keys == 512 else 0}
+    assert stages["bls_device_message_groups"].fields == {"levels": (len(sets) - 1).bit_length()}

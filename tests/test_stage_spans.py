@@ -131,13 +131,13 @@ def _stand_in_kernels(monkeypatch, in_subgroup=True):
     lanes = lambda: jnp.zeros((PAIRING_LANES, 48), jnp.int32)  # noqa: E731
     lanes2 = lambda: jnp.zeros((PAIRING_LANES, 2, 48), jnp.int32)  # noqa: E731
     for name, fn in {
-        "_aggregate": lambda grid, slices: tuple(c[:, 0] for c in grid),
+        "_aggregate": lambda grid, levels: tuple(c[:, 0] for c in grid),
         "_ladders": lambda agg, sigs, bits: (
             agg,
             (sigs[0][:1], sigs[1][:1], sigs[0][:1]),
             jnp.full(sigs[2].shape, in_subgroup),
         ),
-        "_message_groups": lambda s_pk, idx, slices: s_pk,
+        "_message_groups": lambda s_pk, idx, levels: s_pk,
         "hash_to_g2_device": lambda u: (u[:, 0], u[:, 1], u[:, 0]),
         "_pairing_inputs": lambda *a: (
             lanes(), lanes(), lanes2(), lanes2(), jnp.zeros(PAIRING_LANES, bool)
@@ -213,6 +213,9 @@ def test_device_verifier_records_each_stage_once(monkeypatch):
     assert call.fields == {"sets": 3, "lanes": 32, "keys": 16, "programs": len(CHUNK_PROGRAMS)}
     assert [c.name for c in call.children] == DEVICE_STAGES
     stages = {c.name: c for c in call.children}
+    # single keys need no tree level; the two sets of one message need one
+    assert stages["bls_device_aggregate"].fields == {"levels": 0}
+    assert stages["bls_device_message_groups"].fields == {"levels": 1}
     waits = [s for s in _walk(call) if s.name == "bls_device_wait"]
     assert len(waits) == 2
     assert [w.name for w in stages["bls_device_subgroup"].children] == ["bls_device_wait"]
